@@ -52,12 +52,14 @@ from repro.core.engine import (BACKENDS, GATES, DecaySpec, SpikeEngine,
 from repro.distributed.spike_mesh import (ensure_host_devices,
                                           make_spike_mesh, parse_mesh_spec)
 from repro.events import trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.frontend import AsyncSpikeFrontend
 from repro.serving.snn import SpikeServer
 
 # NOTE: repro.kernels.ops/ref import the Pallas TPU machinery, which
-# INITIALIZES the XLA backend at import time — that would lock in the
-# device count before --devices can force faked host devices. They are
+# INITIALIZES the XLA backend at import time — on CPU that would lock in
+# the device count before --devices can force faked host devices (on a
+# TPU host --devices counts real chips and nothing is faked). They are
 # imported inside main(), after ensure_host_devices().
 
 
@@ -705,8 +707,9 @@ def main(argv=None) -> None:
         raise SystemExit("--mesh requires --devices N (N > 1); without it "
                          "the sharded benches would silently not run")
 
-    # force the faked device count BEFORE the first jax backend touch
-    # (the Pallas kernel import below initializes it)
+    enable_compile_cache()
+    # on CPU, force the faked device count BEFORE the first jax backend
+    # touch (the Pallas kernel import below initializes it)
     if args.devices > 1:
         ensure_host_devices(args.devices)
     from repro.kernels import ops, ref

@@ -12,9 +12,11 @@ caching, and dispatches the inner accumulate+fire to a pluggable backend:
   ``"pallas"``      the event-gated Pallas kernel
                     (:func:`repro.kernels.ops.spike_timestep`): silent
                     source blocks skip both compute and weight traffic.
-                    Bit-exact vs ``"reference"``. Interpreted on CPU,
-                    compiled Mosaic on TPU.
-  ``"pallas-mxu"``  same kernel with the f32 MXU accumulate. Exact only
+                    Bit-exact vs ``"reference"`` for any int32 image (the
+                    MXU accumulates the image's four byte planes, each
+                    exact in bf16). Interpreted on CPU, compiled Mosaic
+                    on TPU.
+  ``"pallas-mxu"``  same kernel with one fp32 MXU accumulate. Exact only
                     while per-output partial sums stay below 2^24; the
                     bound is enforced AT ENGINE BUILD TIME from the weight
                     image (worst-case per-block column sums), so a program

@@ -39,7 +39,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core.engine import SpikeEngine
@@ -72,12 +71,16 @@ SNN_RULES = PartitionRules(
 
 
 def ensure_host_devices(n: int) -> None:
-    """Force ``n`` faked host-platform devices (CPU scale-out testing).
+    """Make ``n`` devices available: faked host devices on CPU, real
+    chips anywhere else.
 
-    Must run before JAX initializes its backends; an existing
-    device-count flag with a smaller count is rewritten. Raises if the
-    backend is already up with fewer devices (the env flag can no longer
-    take effect then).
+    On the CPU platform this forces ``n`` host-platform devices (CPU
+    scale-out testing); it must run before JAX initializes its backends,
+    and an existing device-count flag with a smaller count is rewritten.
+    On an accelerator the flag is withdrawn again (it would only fake CPU
+    devices) and ``n`` means ``n`` real chips. Raises when fewer than
+    ``n`` devices are up — on CPU because the backend initialized before
+    the flag could take effect.
     """
     import os
     import re
@@ -85,17 +88,32 @@ def ensure_host_devices(n: int) -> None:
     if n <= 1:
         return
     flag = f"--xla_force_host_platform_device_count={n}"
-    flags = os.environ.get("XLA_FLAGS", "")
+    before = os.environ.get("XLA_FLAGS")
+    flags = before or ""
     if "xla_force_host_platform_device_count" in flags:
         flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
                        flag, flags)
     else:
         flags = (flags + " " + flag).strip()
     os.environ["XLA_FLAGS"] = flags
-    if len(jax.devices()) < n:
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "cpu":
+        if before is None:
+            del os.environ["XLA_FLAGS"]
+        else:
+            os.environ["XLA_FLAGS"] = before
+        if len(devices) < n:
+            raise RuntimeError(
+                f"requested {n} devices but this host has {len(devices)} "
+                f"{platform} device(s); on an accelerator --devices counts "
+                f"real chips"
+            )
+        return
+    if len(devices) < n:
         raise RuntimeError(
             f"requested {n} devices but JAX is running with "
-            f"{len(jax.devices())}; the backend initialized before "
+            f"{len(devices)}; the backend initialized before "
             f"XLA_FLAGS={flag!r} could take effect — call "
             f"ensure_host_devices() before the first jax device use"
         )
@@ -204,7 +222,7 @@ class MeshSpikeEngine(SpikeEngine):
         self._w_spec = spec_for(("source", "neuron"), (sp, self._pp),
                                 mesh, SNN_RULES)
         # column-wise: each device materializes only its SRAM image slice
-        self._weights_sharded = jax.device_put(
+        self.weights_sharded = jax.device_put(
             jnp.asarray(w), NamedSharding(mesh, self._w_spec))
 
     @classmethod
@@ -251,7 +269,7 @@ class MeshSpikeEngine(SpikeEngine):
 
     # ------------------------------------------------------------------
     def _scan_weights(self):
-        return self._weights_sharded
+        return self.weights_sharded
 
     def _specs(self, batch_padded: int, steps: int):
         """PartitionSpecs for one padded (T, B, ...) dispatch."""
@@ -307,11 +325,11 @@ class MeshSpikeEngine(SpikeEngine):
             step = lambda c, x: self._exchange_step(weights_l, c, x)
             return jax.lax.scan(step, carry_l, ext_l)
 
-        final, spikes = shard_map(
+        final, spikes = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._w_spec, cspec, espec),
             out_specs=(cspec, rspec),
-            check_rep=False,
+            check_vma=False,
         )(weights, carry, ext_p)
         return {
             "spikes": spikes[:, :B, : self.n_phys],
@@ -335,11 +353,11 @@ class MeshSpikeEngine(SpikeEngine):
             step = lambda c, x: self._exchange_step(weights_l, c, x)
             return self._masked_chunk_scan(step, carry_l, ext_l, active_l)
 
-        final, spikes = shard_map(
+        final, spikes = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(self._w_spec, cspec, espec, aspec),
             out_specs=(cspec, rspec),
-            check_rep=False,
+            check_vma=False,
         )(weights, carry_p, ext_p, active_p)
         final = {
             "v": final["v"][:B, : self.n_phys],

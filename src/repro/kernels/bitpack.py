@@ -3,12 +3,11 @@
 The paper's spike packets carry single-bit events; our dense rasters spend
 an int32 per possible spike. This module is the packed wire format the
 kernel-side datapath uses instead: 32 sources per uint32 lane, so a
-1024-source axis is 32 lanes (128 bytes per example-step instead of 4 KiB)
-and an entire K-step external raster fits in VMEM next to the accumulator.
+1024-source axis is 32 lanes (128 bytes per example-step instead of 4 KiB).
 
-Lane layout (the contract ARCHITECTURE.md documents and the fused kernel
-depends on): source ``s`` lives in lane ``s // 32`` at bit ``s % 32``,
-little-endian within the lane::
+Lane layout (the contract ARCHITECTURE.md documents and the event gate
+and AER decode depend on): source ``s`` lives in lane ``s // 32`` at bit
+``s % 32``, little-endian within the lane::
 
     packed[..., l] = sum_{i=0}^{31} (dense[..., 32*l + i] != 0) << i
 
@@ -22,6 +21,10 @@ Activity reduction is ``jax.lax.population_count`` on the lanes: the
 per-(example, source-block) gate scalars of :mod:`repro.kernels.ops` and
 the AER ``total`` bookkeeping become popcounts over 4 lanes per 128-source
 block instead of 128-element integer sums.
+
+The fused kernel's own input is a second layout, :func:`pack_block_planes`:
+a kernel cannot pick a block's 4 lanes out of a row at a dynamic block id,
+so there each bit position of a 128-lane word is one source block instead.
 """
 
 from __future__ import annotations
@@ -35,6 +38,7 @@ __all__ = [
     "LANE_BITS",
     "block_activity",
     "count_spikes",
+    "pack_block_planes",
     "packed_lanes",
     "pack_spikes",
     "unpack_spikes",
@@ -97,6 +101,39 @@ def count_spikes(packed):
     packed = jnp.asarray(packed, jnp.uint32)
     counts = jax.lax.population_count(packed).astype(jnp.int32)
     return counts.sum(axis=-1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("block_src",))
+def pack_block_planes(dense, block_src: int):
+    """Pack ``(..., S)`` into ``(G, ..., block_src)`` int32 block-plane words.
+
+    The kernel-side layout of the fused timestep's external raster: bit
+    ``j`` of word ``[g, ..., l]`` is source ``(32*g + j) * block_src + l``
+    (``G = ceil(S / block_src / 32)``), so one word per lane position
+    covers 32 source blocks and block ``blk`` comes back dense with one
+    shift and mask, ``(words[blk // 32] >> blk % 32) & 1`` — no movement
+    across lanes. ``S`` must be a multiple of ``block_src``; bit 31 is the
+    int32 sign bit, which the ``& 1`` after an arithmetic shift discards.
+    """
+    dense = jnp.asarray(dense)
+    lead, S = dense.shape[:-1], dense.shape[-1]
+    if S % block_src:
+        raise ValueError(
+            f"{S} sources do not tile into {block_src}-source blocks"
+        )
+    n_blocks = S // block_src
+    groups = max(1, -(-n_blocks // LANE_BITS))
+    bits = (dense != 0).astype(jnp.uint32).reshape(*lead, n_blocks,
+                                                   block_src)
+    pad = groups * LANE_BITS - n_blocks
+    if pad:
+        bits = jnp.concatenate(
+            [bits, jnp.zeros((*lead, pad, block_src), jnp.uint32)], axis=-2)
+    bits = bits.reshape(*lead, groups, LANE_BITS, block_src)
+    shifts = jnp.arange(LANE_BITS, dtype=jnp.uint32)[:, None]
+    words = (bits << shifts).sum(axis=-2, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(jnp.moveaxis(words, -2, 0),
+                                        jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_src",))

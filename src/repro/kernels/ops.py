@@ -196,7 +196,8 @@ def spike_timestep_fused(ext, spikes_prev, weights, v, active, *,
 
     Byte-identical to K chained :func:`spike_timestep` calls under the
     masked-slot contract (inactive (step, example) pairs keep their carry
-    and emit zero spikes). External spikes travel bitpacked (32/u32 lane);
+    and emit zero spikes). External spikes travel bitpacked (one bit per
+    source block, see :func:`repro.kernels.bitpack.pack_block_planes`);
     each active external weight block is DMA'd ONCE for the whole window
     behind the accumulate, and the recurrent image is fetched once per
     window and applied per step — per-step weight traffic ~1/K of the
@@ -232,7 +233,7 @@ def spike_timestep_fused(ext, spikes_prev, weights, v, active, *,
         interpret=interpret,
     )
     v_out, spk_carry, raster = fn(
-        activity, packed, w_ext_p, w_rec_p, v_p, spk_p, act_p)
+        activity, ext_p, w_ext_p, w_rec_p, v_p, spk_p, act_p)
     return v_out[:B, :P], spk_carry[:B, :P], raster[:, :B, :P]
 
 

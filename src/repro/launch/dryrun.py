@@ -90,8 +90,6 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
             t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis()
-        if isinstance(ca, list):  # newer jax returns [dict], older a dict
-            ca = ca[0] if ca else {}
         hlo = compiled.as_text()
         # loop-aware totals: cost_analysis() counts while bodies ONCE, so
         # scanned layers/microbatches undercount by 32..832x (DESIGN.md §7;

@@ -45,6 +45,7 @@ from repro.distributed.spike_mesh import (ensure_host_devices,
 from repro.distributed.straggler import (StragglerDetector,
                                          observe_from_registry,
                                          rebalance_shards)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (FlightRecorder, MetricsRegistry, SLObjective,
                        SLOWatchdog, SpanTracer, set_registry)
 from repro.obs.tracing import profile_trace
@@ -729,6 +730,7 @@ def main(argv=None) -> None:
                          f"({sorted(qos_policy.classes)}); the burst "
                          f"tenant must be one the policy ranks")
 
+    enable_compile_cache()
     mesh = None
     if args.devices > 1:
         # before the first jax device use, so faked CPU devices can land

@@ -233,16 +233,11 @@ def moe_forward_ep(p: dict, x, cfg: TransformerConfig, *,
                 hs @ pl["shared_w_down"].astype(xl.dtype), "model")
         return out.reshape(Bl, S, d), aux
 
-    if hasattr(jax, "shard_map"):
-        smap, relax = jax.shard_map, {"check_vma": False}
-    else:  # pre-0.6 jax spells it jax.experimental.shard_map
-        from jax.experimental.shard_map import shard_map as smap
-        relax = {"check_rep": False}
-    fn = smap(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(batch_axes if len(batch_axes) > 1 else batch_axes[0]),
                   w_spec),
         out_specs=(P(batch_axes if len(batch_axes) > 1 else batch_axes[0]),
                    P()),
-        **relax)
+        check_vma=False)
     return fn(x, p)

@@ -132,6 +132,23 @@ def test_block_activity_matches_dense_block_sums(rng):
         act, dense.reshape(B, S // block, block).sum(axis=-1))
 
 
+@pytest.mark.parametrize("n_blocks", [1, 7, 32, 33])
+def test_block_planes_layout(rng, n_blocks):
+    """Bit j of plane word [g, ..., l] is source (32g + j)*block + l: one
+    shift and mask recovers any block (what the fused kernel does)."""
+    block = 128
+    dense = _raster(rng, (2, 3, n_blocks * block), 0.3)
+    planes = np.asarray(bitpack.pack_block_planes(dense, block))
+    assert planes.dtype == np.int32
+    assert planes.shape == (-(-n_blocks // 32), 2, 3, block)
+    for blk in range(n_blocks):
+        got = (planes[blk // 32] >> (blk % 32)) & 1
+        np.testing.assert_array_equal(
+            got, dense[..., blk * block:(blk + 1) * block])
+    with pytest.raises(ValueError, match="tile"):
+        bitpack.pack_block_planes(dense[..., :100], block)
+
+
 def test_block_activity_validation():
     packed = jnp.zeros((2, 4), jnp.uint32)
     with pytest.raises(ValueError, match="multiple"):

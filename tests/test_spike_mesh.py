@@ -12,6 +12,9 @@ the multi-device cases skip and the degenerate 1x1-mesh cases still
 exercise the shard_map path end to end.
 """
 
+import os
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -336,3 +339,35 @@ def test_async_frontend_degenerate_mesh_parity(rng):
 
 def test_async_frontend_sharded_parity(rng):
     _async_frontend_parity(rng, _mesh(2, 2))
+
+
+# --------------------------------------------------------------------------
+# ensure_host_devices: faked devices on CPU only
+# --------------------------------------------------------------------------
+
+def test_ensure_host_devices_on_cpu_sets_the_flag(monkeypatch):
+    from repro.distributed import spike_mesh
+
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
+    n = len(jax.devices()) + 1  # more than this process already has
+    with pytest.raises(RuntimeError, match="before the first jax device"):
+        spike_mesh.ensure_host_devices(n)
+    assert os.environ["XLA_FLAGS"] == (
+        f"--xla_force_host_platform_device_count={n}")
+
+
+def test_ensure_host_devices_on_an_accelerator_counts_real_chips(
+        monkeypatch):
+    """Off CPU nothing is faked: the flag is withdrawn again and --devices
+    N needs N real devices."""
+    from repro.distributed import spike_mesh
+
+    chips = [types.SimpleNamespace(platform="tpu")] * 4
+    monkeypatch.setattr(spike_mesh.jax, "devices", lambda: chips)
+    monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/dev/null")
+    spike_mesh.ensure_host_devices(4)
+    assert os.environ["XLA_FLAGS"] == "--xla_dump_to=/dev/null"
+    monkeypatch.delenv("XLA_FLAGS")
+    with pytest.raises(RuntimeError, match="real chips"):
+        spike_mesh.ensure_host_devices(8)
+    assert "XLA_FLAGS" not in os.environ
