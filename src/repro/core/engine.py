@@ -420,10 +420,13 @@ class SpikeEngine:
         return final, raster.reshape(nw * K, B, self.n_phys)[:T]
 
     def _chunk_impl(self, weights, carry, ext, active):
-        if self._use_fused:
-            return self._fused_scan(weights, carry, ext, active)
-        step = lambda c, x: self._step(weights, c, x)
-        return self._masked_chunk_scan(step, carry, ext, active)
+        # the scope prefixes every op's ``op_name`` in the HLO metadata a
+        # profile carries, telling the chunk step's ops from eager ones
+        with jax.named_scope("snn.step_chunk"):
+            if self._use_fused:
+                return self._fused_scan(weights, carry, ext, active)
+            step = lambda c, x: self._step(weights, c, x)
+            return self._masked_chunk_scan(step, carry, ext, active)
 
     def step_chunk(self, carry, ext, active=None):
         """Advance a slot batch over a chunk of timesteps, with masking.

@@ -205,6 +205,7 @@ def build_spike_timestep(
         grid_spec=grid_spec,
         out_shape=[out, out],
         interpret=interpret,
+        name="spike_timestep",
     )
 
     def fn(activity, sources, weights, v):
@@ -469,6 +470,7 @@ def build_spike_timestep_fused(
                                  jnp.int32),
         ],
         interpret=interpret,
+        name="spike_timestep_fused",
     )
     tiled = (nb, block_batch, n_phys)
 

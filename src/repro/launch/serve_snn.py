@@ -278,8 +278,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "retired) as JSONL to FILE")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace of the serving loop "
-                         "into DIR, with lifecycle spans mirrored as trace "
-                         "annotations")
+                         "into DIR; each round's phases (snn.pump, snn.feed "
+                         "and their parts) are named on the device timeline")
     ap.add_argument("--json-summary", nargs="?", const="-", default=None,
                     metavar="FILE",
                     help="also emit the structured run summary as one JSON "
@@ -754,7 +754,7 @@ def main(argv=None) -> None:
     # ring always holds the freshest spans with no second recording path
     recorder = None if args.flight is None else FlightRecorder(
         path=args.flight)
-    tracer = SpanTracer(annotate=args.profile is not None, sink=recorder)
+    tracer = SpanTracer(sink=recorder)
     set_registry(metrics)
     objectives = []
     if args.slo_p99_ms is not None:

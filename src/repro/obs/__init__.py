@@ -9,8 +9,9 @@ The serving stack's telemetry lives here, in two halves:
   every metric the serving stack emits.
 - :mod:`repro.obs.tracing` — a :class:`SpanTracer` recording typed
   stream-lifecycle spans (queued → admitted → chunk_step×N →
-  parked/migrated/redeployed → retired) with JSONL export and optional
-  ``jax.profiler`` trace annotations.
+  parked/migrated/redeployed → retired) with JSONL export, and the
+  ``HOT_SPANS`` catalogue of the served round's phases, recorded as
+  ``jax.profiler`` annotations on the device timeline's clock.
 
 On top of the raw record sits the analysis tier:
 

@@ -1,4 +1,4 @@
-"""Stream-lifecycle tracing: typed spans, JSONL export, profiler hooks.
+"""Stream-lifecycle tracing and the hot path's profiler spans.
 
 A :class:`SpanTracer` records what happened to each request/stream as a
 sequence of typed spans::
@@ -16,15 +16,16 @@ process-level spans like session deploys) plus free-form attributes.
 Export is JSONL — one span per line, stable keys — so traces stream to
 a file during a run and load with one ``json.loads`` per line.
 
-When built with ``annotate=True`` and ``jax.profiler`` is importable,
-duration spans also wrap their body in a
-``jax.profiler.TraceAnnotation``, so kernel time shows up under named
-lifecycle spans in a profiler trace captured via
-:func:`profile_trace` (the ``serve_snn --profile DIR`` path).
-
 Like the metrics registry, the tracer is injectable and clocked by an
 injectable callable; components take ``tracer=None`` (no tracing, no
 work) by default. Tracing reads the datapath and never changes it.
+
+Separately, the served round's phases are named on the profiler's own
+clock: :func:`hot_span` opens a ``jax.profiler.TraceAnnotation`` for one
+of the names catalogued in ``HOT_SPANS``. These are always on — with no
+profiler running each costs one annotation object — and a trace captured
+with :func:`profile_trace` (``serve_snn --profile DIR``) shows them on
+the same timeline as the device ops.
 """
 
 from __future__ import annotations
@@ -35,7 +36,10 @@ import json
 import threading
 import time
 
-__all__ = ["SPAN_KINDS", "Span", "SpanTracer", "profile_trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["HOT_SPANS", "SPAN_KINDS", "Span", "SpanTracer", "hot_span",
+           "profile_trace"]
 
 # The lifecycle vocabulary. Tracers accept only these kinds, so a typo
 # in an instrumentation site fails loudly instead of minting a new
@@ -54,6 +58,34 @@ SPAN_KINDS: tuple[str, ...] = (
     "restore",     # connector snapshot read (attrs: nbytes)
     "shard_step",  # one sharded dispatch (attrs: per-shard times, flags)
 )
+
+# The served round's phases, in the order one round opens them. Nesting:
+# snn.pump > {admit, gather, snn.feed > {assemble, dispatch, readback,
+# split}, retire}; feed's four leaves repeat per chunk of a long feed.
+HOT_SPANS: tuple[str, ...] = (
+    "snn.pump",           # AsyncSpikeFrontend.pump: one whole round
+    "snn.pump.admit",     # expiry, preemption, admission (attach)
+    "snn.pump.gather",    # each running request's next chunk, embedded
+    "snn.feed",           # SpikeServer.feed: one whole call
+    "snn.feed.assemble",  # one chunk's dense ext and active arrays
+    "snn.feed.dispatch",  # host-to-device copies + step_chunk (h2d_bytes)
+    "snn.feed.readback",  # the chunk's raster back to the host (d2h_bytes)
+    "snn.feed.split",     # per-stream rasters, concatenation, stats
+    "snn.pump.retire",    # detach (slot zeroing) + latency records
+)
+_HOT = frozenset(HOT_SPANS)
+
+# the annotation factory; tests swap in a recorder
+_annotation = TraceAnnotation
+
+
+def hot_span(name: str, **counts):
+    """A profiler annotation around one catalogued phase of the served
+    round. ``counts`` (byte counts) become the event's arguments."""
+    if name not in _HOT:
+        raise ValueError(
+            f"unknown hot span {name!r}; expected one of {HOT_SPANS}")
+    return _annotation(name, **counts)
 
 
 @dataclasses.dataclass
@@ -86,19 +118,13 @@ class SpanTracer:
 
     Args:
       clock: monotonic-seconds callable (injectable for determinism).
-      annotate: also wrap duration spans in
-        ``jax.profiler.TraceAnnotation`` when jax is importable, so a
-        captured profiler trace nests kernel time under lifecycle
-        spans. Off by default — annotation costs a little per span.
       sink: optional open text file; when set, each completed span is
         written through immediately (one JSON line) as well as kept in
         memory. Lets ``--trace FILE`` stream during long runs.
     """
 
-    def __init__(self, clock=time.perf_counter, *,
-                 annotate: bool = False, sink=None):
+    def __init__(self, clock=time.perf_counter, *, sink=None):
         self.clock = clock
-        self.annotate = annotate
         self._sink = sink
         self._lock = threading.Lock()
         self._spans: list[Span] = []
@@ -126,13 +152,8 @@ class SpanTracer:
         """
         self._check(kind)
         t0 = self.clock()
-        ann = self._annotation(kind, uid)
         try:
-            if ann is not None:
-                with ann:
-                    yield attrs
-            else:
-                yield attrs
+            yield attrs
         finally:
             self._record(Span(kind, uid, t0, self.clock(), attrs))
 
@@ -141,16 +162,6 @@ class SpanTracer:
             raise ValueError(
                 f"unknown span kind {kind!r}; expected one of {SPAN_KINDS}"
             )
-
-    def _annotation(self, kind: str, uid):
-        if not self.annotate:
-            return None
-        try:
-            from jax.profiler import TraceAnnotation
-        except Exception:  # pragma: no cover - jax always present here
-            return None
-        name = kind if uid is None else f"{kind}:{uid}"
-        return TraceAnnotation(name)
 
     # -- reading / export ---------------------------------------------
     @property
@@ -184,9 +195,8 @@ class SpanTracer:
 def profile_trace(log_dir: str | None):
     """``jax.profiler`` capture around a block (no-op when dir is None).
 
-    The ``serve_snn --profile DIR`` path: combined with a tracer built
-    with ``annotate=True``, the captured trace nests device/kernel time
-    under the lifecycle span names.
+    The ``serve_snn --profile DIR`` path: the captured trace shows the
+    ``HOT_SPANS`` phases of every served round on the device ops' clock.
     """
     if log_dir is None:
         yield

@@ -72,6 +72,7 @@ import time
 
 import numpy as np
 
+from repro.obs.tracing import hot_span
 from repro.serving.qos import QoSPolicy, WeightedFairQueue, choose_victim
 
 __all__ = [
@@ -608,133 +609,135 @@ class AsyncSpikeFrontend:
         streams -> retire finished streams. Returns the round summary
         ``{'admitted', 'retired', 'expired', 'steps', 'queue_depth'}``.
         """
-        with self._lock:
+        with hot_span("snn.pump"), self._lock:
             now = self.clock()
             summary = {"admitted": 0, "retired": 0, "expired": 0,
                        "evicted": 0, "steps": 0}
-            # 1. deadline expiry — queued requests are refused outright
-            # (a resumed one falls back to "parked": its carry is still
-            # in the connector and a later resume() may try again)
-            for req in [r for r in self._queue
-                        if r.deadline is not None and now > r.deadline]:
-                self._queue.remove(req)
-                if req.parked_key is not None:
-                    req.state = "parked"
-                    self._obs_event("parked", req)
-                else:
-                    req.state = "expired"
-                    self._count("expired_queued", req)
-                    self._obs_retired(req, "expired")
-                self._count("expired", req)
-                if self.slo is not None:
-                    self.slo.record_miss()
-                summary["expired"] += 1
-            # ... mid-stream streams are evicted like any other eviction:
-            # detach zeroes the slot carry, so the next occupant powers
-            # up clean (pinned by tests/test_serving_frontend.py).
-            # With a connector, the eviction SPILLS instead: the carry is
-            # parked under a frontend-namespaced key and the request goes
-            # to state "parked" — resume() continues it bit-clean.
-            for uid, req in [(u, r) for u, r in self._running.items()
-                             if r.deadline is not None
-                             and now > r.deadline]:
-                del self._running[uid]
-                if self.qos is not None:
-                    self._queue.note_released(req)
-                if self.connector is not None:
-                    req.parked_key = (self._spill_ns, req.rid)
-                    snap = self.server.snapshot_stream(uid)
-                    self.server.detach(uid, reason="parked")
-                    self.connector.insert(req.parked_key, snap)
-                    req.uid = None
-                    req.state = "parked"
-                    self._count("parked", req)
-                    self._obs_event("parked", req, steps_done=req.cursor)
-                else:
-                    self.server.detach(uid, reason="expired")
-                    req.state = "expired"
-                    req.finished_at = now
+            with hot_span("snn.pump.admit"):
+                # 1. deadline expiry — queued requests are refused outright
+                # (a resumed one falls back to "parked": its carry is still
+                # in the connector and a later resume() may try again)
+                for req in [r for r in self._queue
+                            if r.deadline is not None and now > r.deadline]:
+                    self._queue.remove(req)
+                    if req.parked_key is not None:
+                        req.state = "parked"
+                        self._obs_event("parked", req)
+                    else:
+                        req.state = "expired"
+                        self._count("expired_queued", req)
+                        self._obs_retired(req, "expired")
                     self._count("expired", req)
-                    self._count("expired_running", req)
-                    self._obs_retired(req, "expired")
-                if self.slo is not None:
-                    self.slo.record_miss()
-                summary["expired"] += 1
-            # 1b. SLO-aware preemption (QoS preempt only): every slot
-            # busy while an eligible queued request strictly outranks a
-            # running stream -> shed the lowest-priority running stream
-            # (newest first within it). The victim's carry is PARKED
-            # through the connector — never dropped — and it re-queues
-            # at the head of its class, continuing bit-clean once
-            # pressure clears. One eviction per round: takeover is
-            # gradual and the victim sequence stays a pure function of
-            # the op sequence.
-            if (self.qos is not None and self.qos.preempt
-                    and self._queue
-                    and self.server.scheduler.free_slots == 0):
-                top = self._queue.top_eligible_priority(now)
-                victim = (choose_victim(self.qos, self._running.values(),
-                                        below=top)
-                          if top is not None else None)
-                if victim is not None:
-                    uid = victim.uid
+                    if self.slo is not None:
+                        self.slo.record_miss()
+                    summary["expired"] += 1
+                # ... mid-stream streams are evicted like any other eviction:
+                # detach zeroes the slot carry, so the next occupant powers
+                # up clean (pinned by tests/test_serving_frontend.py).
+                # With a connector, the eviction SPILLS instead: the carry is
+                # parked under a frontend-namespaced key and the request goes
+                # to state "parked" — resume() continues it bit-clean.
+                for uid, req in [(u, r) for u, r in self._running.items()
+                                 if r.deadline is not None
+                                 and now > r.deadline]:
                     del self._running[uid]
-                    self._queue.note_released(victim)
-                    victim.parked_key = (self._spill_ns, victim.rid)
-                    snap = self.server.snapshot_stream(uid)
-                    self.server.detach(uid, reason="parked")
-                    self.connector.insert(victim.parked_key, snap)
-                    victim.uid = None
-                    self._count("evicted", victim)
-                    self._count("parked", victim)
-                    self._obs_event("parked", victim,
-                                    steps_done=victim.cursor,
-                                    preempted=True)
-                    victim.state = "queued"
-                    self._queue.appendleft(victim)
-                    self._obs_event("queued", victim,
-                                    steps=victim.steps_total,
-                                    stream_class=self._class_of(victim),
-                                    resumed=True)
-                    summary["evicted"] += 1
-            # 2. continuous-batching admission: queue head -> free slots
-            # (a resumed request re-attaches FROM its parked carry — the
-            # only admission that does not power up from zero). Under
-            # QoS the "head" is whatever the policy grants next: strict
-            # priority, then DRR inside the stratum, quota and token
-            # gated — None when every queued class is blocked.
-            while self._queue and self.server.scheduler.free_slots > 0:
-                if self.qos is not None:
-                    req = self._queue.pop_admissible(now)
-                    if req is None:
-                        break
-                else:
-                    req = self._queue.popleft()
-                resumed = req.parked_key is not None
-                if resumed:
-                    snap = self.connector.select(req.parked_key)
-                    req.uid = self.server.attach_stream(snap)
-                    self.connector.evict(req.parked_key)
-                    req.parked_key = None
-                    self._count("resumed", req)
-                    self._obs_event("resumed", req, server_uid=req.uid)
-                else:
-                    req.uid = self.server.attach()
-                self._obs_event("admitted", req,
-                                slot=self.server.slot_of(req.uid),
-                                server_uid=req.uid, resumed=resumed)
-                req.admitted_at = now
-                req.state = "running"
-                self._running[req.uid] = req
-                self._lat("queue_wait", req, now - req.submitted_at)
-                summary["admitted"] += 1
+                    if self.qos is not None:
+                        self._queue.note_released(req)
+                    if self.connector is not None:
+                        req.parked_key = (self._spill_ns, req.rid)
+                        snap = self.server.snapshot_stream(uid)
+                        self.server.detach(uid, reason="parked")
+                        self.connector.insert(req.parked_key, snap)
+                        req.uid = None
+                        req.state = "parked"
+                        self._count("parked", req)
+                        self._obs_event("parked", req, steps_done=req.cursor)
+                    else:
+                        self.server.detach(uid, reason="expired")
+                        req.state = "expired"
+                        req.finished_at = now
+                        self._count("expired", req)
+                        self._count("expired_running", req)
+                        self._obs_retired(req, "expired")
+                    if self.slo is not None:
+                        self.slo.record_miss()
+                    summary["expired"] += 1
+                # 1b. SLO-aware preemption (QoS preempt only): every slot
+                # busy while an eligible queued request strictly outranks a
+                # running stream -> shed the lowest-priority running stream
+                # (newest first within it). The victim's carry is PARKED
+                # through the connector — never dropped — and it re-queues
+                # at the head of its class, continuing bit-clean once
+                # pressure clears. One eviction per round: takeover is
+                # gradual and the victim sequence stays a pure function of
+                # the op sequence.
+                if (self.qos is not None and self.qos.preempt
+                        and self._queue
+                        and self.server.scheduler.free_slots == 0):
+                    top = self._queue.top_eligible_priority(now)
+                    victim = (choose_victim(self.qos, self._running.values(),
+                                            below=top)
+                              if top is not None else None)
+                    if victim is not None:
+                        uid = victim.uid
+                        del self._running[uid]
+                        self._queue.note_released(victim)
+                        victim.parked_key = (self._spill_ns, victim.rid)
+                        snap = self.server.snapshot_stream(uid)
+                        self.server.detach(uid, reason="parked")
+                        self.connector.insert(victim.parked_key, snap)
+                        victim.uid = None
+                        self._count("evicted", victim)
+                        self._count("parked", victim)
+                        self._obs_event("parked", victim,
+                                        steps_done=victim.cursor,
+                                        preempted=True)
+                        victim.state = "queued"
+                        self._queue.appendleft(victim)
+                        self._obs_event("queued", victim,
+                                        steps=victim.steps_total,
+                                        stream_class=self._class_of(victim),
+                                        resumed=True)
+                        summary["evicted"] += 1
+                # 2. continuous-batching admission: queue head -> free slots
+                # (a resumed request re-attaches FROM its parked carry — the
+                # only admission that does not power up from zero). Under
+                # QoS the "head" is whatever the policy grants next: strict
+                # priority, then DRR inside the stratum, quota and token
+                # gated — None when every queued class is blocked.
+                while self._queue and self.server.scheduler.free_slots > 0:
+                    if self.qos is not None:
+                        req = self._queue.pop_admissible(now)
+                        if req is None:
+                            break
+                    else:
+                        req = self._queue.popleft()
+                    resumed = req.parked_key is not None
+                    if resumed:
+                        snap = self.connector.select(req.parked_key)
+                        req.uid = self.server.attach_stream(snap)
+                        self.connector.evict(req.parked_key)
+                        req.parked_key = None
+                        self._count("resumed", req)
+                        self._obs_event("resumed", req, server_uid=req.uid)
+                    else:
+                        req.uid = self.server.attach()
+                    self._obs_event("admitted", req,
+                                    slot=self.server.slot_of(req.uid),
+                                    server_uid=req.uid, resumed=resumed)
+                    req.admitted_at = now
+                    req.state = "running"
+                    self._running[req.uid] = req
+                    self._lat("queue_wait", req, now - req.submitted_at)
+                    summary["admitted"] += 1
             # 3. one service quantum for every running stream, batched
-            inputs = {}
-            for uid, req in self._running.items():
-                piece = req.chunk[req.cursor:
-                                  req.cursor + self.server.chunk_steps]
-                inputs[uid] = (req.view.embed(piece)
-                               if req.view is not None else piece)
+            with hot_span("snn.pump.gather"):
+                inputs = {}
+                for uid, req in self._running.items():
+                    piece = req.chunk[req.cursor:
+                                      req.cursor + self.server.chunk_steps]
+                    inputs[uid] = (req.view.embed(piece)
+                                   if req.view is not None else piece)
             if inputs:
                 out = self.server.feed(inputs)
                 for uid, res in out.items():
@@ -743,22 +746,23 @@ class AsyncSpikeFrontend:
                     req.cursor += res["spikes"].shape[0]
                     summary["steps"] += res["spikes"].shape[0]
             # 4. retire finished streams (slots free for the next round)
-            now = self.clock()
-            for uid in [u for u, r in self._running.items()
-                        if r.cursor >= r.steps_total]:
-                req = self._running.pop(uid)
-                if self.qos is not None:
-                    self._queue.note_released(req)
-                self.server.detach(uid, reason="done")
-                req.state = "done"
-                req.finished_at = now
-                self._count("done", req)
-                self._lat("service", req, now - req.admitted_at)
-                self._lat("total", req, now - req.submitted_at)
-                self._obs_retired(req, "done")
-                if self.slo is not None:
-                    self.slo.record_done(now - req.submitted_at)
-                summary["retired"] += 1
+            with hot_span("snn.pump.retire"):
+                now = self.clock()
+                for uid in [u for u, r in self._running.items()
+                            if r.cursor >= r.steps_total]:
+                    req = self._running.pop(uid)
+                    if self.qos is not None:
+                        self._queue.note_released(req)
+                    self.server.detach(uid, reason="done")
+                    req.state = "done"
+                    req.finished_at = now
+                    self._count("done", req)
+                    self._lat("service", req, now - req.admitted_at)
+                    self._lat("total", req, now - req.submitted_at)
+                    self._obs_retired(req, "done")
+                    if self.slo is not None:
+                        self.slo.record_done(now - req.submitted_at)
+                    summary["retired"] += 1
             self.rounds += 1
             self.depth_samples.append(len(self._queue))
             if self.registry is not None:

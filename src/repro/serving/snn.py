@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engine import SpikeEngine
+from repro.obs.tracing import hot_span
 
 __all__ = ["SlotScheduler", "SpikeServer", "ModelStream", "StreamStats"]
 
@@ -591,6 +592,10 @@ class SpikeServer:
         """
         if not inputs:
             return {}
+        with hot_span("snn.feed"):
+            return self._feed(inputs)
+
+    def _feed(self, inputs: dict) -> dict:
         out: dict = {}
         chunks: dict = {}
         n_phys = self.engine.n_phys
@@ -616,36 +621,45 @@ class SpikeServer:
 
         T_max = max(arr.shape[0] for _, arr in chunks.values())
         n_in = self.engine.n_inputs
-        pieces: dict = {uid: [] for uid in chunks}
+        rasters = []                                  # (T, n_slots, n_phys)
         obs = self.metrics is not None or self.tracer is not None
         for t0 in range(0, T_max, self.chunk_steps):
-            ext = np.zeros((self.chunk_steps, self.n_slots, n_in), np.int32)
-            active = np.zeros((self.chunk_steps, self.n_slots), np.int32)
-            for uid, (slot, arr) in chunks.items():
-                n = min(self.chunk_steps, arr.shape[0] - t0)
-                if n <= 0:
-                    continue
-                ext[:n, slot] = arr[t0:t0 + n]
-                active[:n, slot] = 1
+            with hot_span("snn.feed.assemble"):
+                ext = np.zeros((self.chunk_steps, self.n_slots, n_in),
+                               np.int32)
+                active = np.zeros((self.chunk_steps, self.n_slots), np.int32)
+                for uid, (slot, arr) in chunks.items():
+                    n = min(self.chunk_steps, arr.shape[0] - t0)
+                    if n <= 0:
+                        continue
+                    ext[:n, slot] = arr[t0:t0 + n]
+                    active[:n, slot] = 1
             t_chunk = self._obs_clock()() if obs else 0.0
-            self.carry, spikes = self.engine.step_chunk(
-                self.carry, jnp.asarray(ext), jnp.asarray(active))
-            spikes = np.asarray(spikes)
+            with hot_span("snn.feed.dispatch",
+                          h2d_bytes=ext.nbytes + active.nbytes):
+                self.carry, spikes = self.engine.step_chunk(
+                    self.carry, jnp.asarray(ext), jnp.asarray(active))
+            with hot_span("snn.feed.readback", d2h_bytes=spikes.nbytes):
+                spikes = np.asarray(spikes)
             self.total_steps += int(active.sum())
+            # telemetry stays outside the phases: its cost is its own
             if obs:
                 self._obs_feed_chunk(t_chunk, active, spikes, ext,
                                      chunks, t0)
-            for uid, (slot, arr) in chunks.items():
-                n = min(self.chunk_steps, arr.shape[0] - t0)
-                if n > 0:
-                    pieces[uid].append(spikes[:n, slot])
+            rasters.append(spikes)
 
-        for uid, (slot, arr) in chunks.items():
-            raster = np.concatenate(pieces[uid], axis=0)
-            st = self.streams[uid]
-            st.steps += raster.shape[0]
-            st.spike_count += int(raster.sum())
-            out[uid] = {"spikes": raster, "counts": raster.sum(axis=0)}
+        cs = self.chunk_steps
+        with hot_span("snn.feed.split"):
+            for uid, (slot, arr) in chunks.items():
+                T = arr.shape[0]
+                raster = np.concatenate(
+                    [r[:min(cs, T - t0), slot]
+                     for t0, r in zip(range(0, T, cs), rasters)], axis=0)
+                st = self.streams[uid]
+                st.steps += raster.shape[0]
+                st.spike_count += int(raster.sum())
+                out[uid] = {"spikes": raster,
+                            "counts": raster.sum(axis=0)}
         return out
 
     def feed_events(self, inputs: dict, *, out_capacity: int | None = None,
