@@ -116,6 +116,11 @@ METRIC_SPECS: dict[str, MetricSpec] = _specs(
     MetricSpec("snn_server_weight_blocks_dense_total", "counter",
                "128-source weight blocks an ungated dense fetch would "
                "have moved across the same active steps."),
+    MetricSpec("snn_server_slot_resets_total", "counter",
+               "Slot carries zeroed on eviction (detach / detach_many)."),
+    MetricSpec("snn_server_slot_reset_dispatches_total", "counter",
+               "Jitted slot-zeroing dispatches: one per detach_many call "
+               "that frees a slot, however many it frees."),
     # -- AsyncSpikeFrontend: request lifecycle ------------------------
     MetricSpec("snn_frontend_requests_total", "counter",
                "Requests by terminal-or-transition outcome: submitted, "

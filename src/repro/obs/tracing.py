@@ -71,7 +71,7 @@ HOT_SPANS: tuple[str, ...] = (
     "snn.feed.dispatch",  # host-to-device copies + step_chunk (h2d_bytes)
     "snn.feed.readback",  # the chunk's raster back to the host (d2h_bytes)
     "snn.feed.split",     # per-stream rasters, concatenation, stats
-    "snn.pump.retire",    # detach (slot zeroing) + latency records
+    "snn.pump.retire",    # detach_many, one zeroing dispatch (zeroed)
 )
 _HOT = frozenset(HOT_SPANS)
 
@@ -81,7 +81,8 @@ _annotation = TraceAnnotation
 
 def hot_span(name: str, **counts):
     """A profiler annotation around one catalogued phase of the served
-    round. ``counts`` (byte counts) become the event's arguments."""
+    round. ``counts`` (byte or slot counts) become the event's
+    arguments."""
     if name not in _HOT:
         raise ValueError(
             f"unknown hot span {name!r}; expected one of {HOT_SPANS}")

@@ -745,15 +745,17 @@ class AsyncSpikeFrontend:
                     req.pieces.append(res["spikes"])
                     req.cursor += res["spikes"].shape[0]
                     summary["steps"] += res["spikes"].shape[0]
-            # 4. retire finished streams (slots free for the next round)
-            with hot_span("snn.pump.retire"):
+            # 4. retire finished streams (slots free for the next round),
+            # every freed slot zeroed by one dispatch
+            done = [u for u, r in self._running.items()
+                    if r.cursor >= r.steps_total]
+            with hot_span("snn.pump.retire", zeroed=len(done)):
                 now = self.clock()
-                for uid in [u for u, r in self._running.items()
-                            if r.cursor >= r.steps_total]:
+                self.server.detach_many(done, reason="done")
+                for uid in done:
                     req = self._running.pop(uid)
                     if self.qos is not None:
                         self._queue.note_released(req)
-                    self.server.detach(uid, reason="done")
                     req.state = "done"
                     req.finished_at = now
                     self._count("done", req)

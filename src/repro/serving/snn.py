@@ -37,6 +37,7 @@ import dataclasses
 import itertools
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,6 +49,14 @@ __all__ = ["SlotScheduler", "SpikeServer", "ModelStream", "StreamStats"]
 # Source-block granularity the measured-traffic counters account at —
 # the kernels' block_src (one weight block per 128 source rows).
 _OBS_BLOCK_SRC = 128
+
+
+@jax.jit
+def _zero_slots(carry: dict, mask) -> dict:
+    """Every carry row whose ``mask`` entry is set, zeroed; the others
+    untouched. One device dispatch however many slots are freed; the
+    mask's shape is the slot count, so a server compiles this once."""
+    return {k: jnp.where(mask[:, None], 0, x) for k, x in carry.items()}
 
 
 class SlotScheduler:
@@ -184,7 +193,9 @@ class SpikeServer:
     timesteps padded with inactive steps, so ONE XLA program (per engine)
     serves arbitrary ragged traffic. Slot carries persist across calls;
     :meth:`detach` zeroes the evicted slot so re-attachment starts from
-    the unified power-on state (V = 0, no prior spikes).
+    the unified power-on state (V = 0, no prior spikes);
+    :meth:`detach_many` zeroes every slot one call frees in a single
+    jitted dispatch.
 
     ``mesh`` scales the server out over devices: the engine is re-hosted
     as a :class:`~repro.distributed.spike_mesh.MeshSpikeEngine` (neuron
@@ -410,25 +421,46 @@ class SpikeServer:
         instead, for callers that park the carry in a connector (spill,
         migration, rolling drain) so the timeline continues through the
         later restore instead of ending here."""
-        st = self.streams.pop(uid)
-        self._obs_detached(uid, st, reason)
-        if self.scheduler.slot_of(uid) is None:
-            self.scheduler.cancel(uid)
-            self._obs_occupancy()
-            return st
-        slot, admitted = self.scheduler.release(uid)
-        self.carry = {
-            "v": self.carry["v"].at[slot].set(0),
-            "spikes": self.carry["spikes"].at[slot].set(0),
-        }
-        if self._prev_host is not None:
-            self._prev_host[slot] = 0
-        if admitted is not None:
-            self.streams[admitted].admitted_at = time.perf_counter()
-            if self.tracer is not None:
-                self.tracer.event("admitted", admitted, slot=slot)
-        self._obs_occupancy()
-        return st
+        return self.detach_many([uid], reason=reason)[0]
+
+    def detach_many(self, uids, *, reason: str = "detached"
+                    ) -> list[StreamStats]:
+        """Evict several streams: :meth:`detach` for each uid in order,
+        with every slot they free zeroed by ONE jitted dispatch before
+        this returns (so before any later feed, snapshot or restore reads
+        the carry). Waiters are admitted into the freed slots in the same
+        FIFO order a detach loop would give; a uid still waiting for a
+        slot is just withdrawn. An empty list dispatches nothing."""
+        stats = []
+        freed = np.zeros(self.n_slots, bool)
+        try:
+            for uid in uids:
+                st = self.streams.pop(uid)
+                self._obs_detached(uid, st, reason)
+                stats.append(st)
+                if self.scheduler.slot_of(uid) is None:
+                    self.scheduler.cancel(uid)
+                    continue
+                slot, admitted = self.scheduler.release(uid)
+                freed[slot] = True
+                if self._prev_host is not None:
+                    self._prev_host[slot] = 0
+                if admitted is not None:
+                    self.streams[admitted].admitted_at = time.perf_counter()
+                    if self.tracer is not None:
+                        self.tracer.event("admitted", admitted, slot=slot)
+        finally:
+            # a uid that raises leaves the slots freed before it zeroed
+            if freed.any():
+                self.carry = _zero_slots(self.carry, freed)
+                if self.metrics is not None:
+                    self.metrics.counter(
+                        "snn_server_slot_resets_total").inc(int(freed.sum()))
+                    self.metrics.counter(
+                        "snn_server_slot_reset_dispatches_total").inc()
+            if stats:
+                self._obs_occupancy()
+        return stats
 
     def _obs_detached(self, uid, st: "StreamStats", reason: str) -> None:
         if self.tracer is None:

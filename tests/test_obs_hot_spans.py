@@ -93,7 +93,7 @@ def test_uncatalogued_hot_span_raises(recorder, name):
 def test_pump_round_emits_every_hot_span_nested(recorder):
     """One round that admits, serves one chunk and retires: every phase
     once, in catalogue order, under the span the catalogue gives, with
-    the chunk's host-device bytes."""
+    the chunk's host-device bytes and the number of slots retired."""
     server = SpikeServer(_engine(), n_slots=SLOTS, chunk_steps=CHUNK)
     fe = AsyncSpikeFrontend(server, queue_capacity=4)
     for r in _rasters([CHUNK, CHUNK]):
@@ -110,8 +110,10 @@ def test_pump_round_emits_every_hot_span_nested(recorder):
     assert args["snn.feed.dispatch"] == {
         "h2d_bytes": ext.nbytes + active.nbytes}
     assert args["snn.feed.readback"] == {"d2h_bytes": raster.nbytes}
+    assert args["snn.pump.retire"] == {"zeroed": SLOTS}
     assert all(not a for n, a in args.items()
-               if n not in ("snn.feed.dispatch", "snn.feed.readback"))
+               if n not in ("snn.feed.dispatch", "snn.feed.readback",
+                            "snn.pump.retire"))
 
 
 def test_feed_phases_repeat_per_chunk(recorder):

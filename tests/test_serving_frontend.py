@@ -187,6 +187,34 @@ def test_deadline_expiry_queued_vs_mid_stream(rng):
     np.testing.assert_array_equal(c.result()["spikes"], want)
 
 
+@pytest.mark.parametrize("n_done", [1, 3, 4])
+def test_pump_round_zeroes_its_retirees_in_one_dispatch(rng, n_done):
+    """A round that retires n_done of 4 streams zeroes their slots with
+    ONE dispatch (the registry's reset counters say so), and the queued
+    requests that take those slots power up from zero."""
+    from repro.obs import MetricsRegistry
+
+    engine = _engine(rng)
+    reg = MetricsRegistry()
+    server = SpikeServer(engine, n_slots=4, chunk_steps=2, metrics=reg)
+    fe = AsyncSpikeFrontend(server, queue_capacity=8)
+    lengths = [2] * n_done + [6] * (4 - n_done) + [5, 3]
+    rasters = _rasters(rng, lengths, engine.n_inputs)
+    handles = [fe.submit(r) for r in rasters]
+    resets = reg.counter("snn_server_slot_resets_total")
+    dispatches = reg.counter("snn_server_slot_reset_dispatches_total")
+    assert fe.pump()["retired"] == n_done
+    assert (resets.value, dispatches.value) == (n_done, 1)
+    rounds = 1
+    while not fe.idle:
+        retired = fe.pump()["retired"]
+        rounds += bool(retired)
+    assert (resets.value, dispatches.value) == (len(lengths), rounds)
+    for h, r in zip(handles, rasters):
+        want = np.asarray(engine.run(r[:, None, :])["spikes"])[:, 0]
+        np.testing.assert_array_equal(h.result()["spikes"], want)
+
+
 # --------------------------------------------------------------------------
 # Backpressure policies
 # --------------------------------------------------------------------------

@@ -177,6 +177,78 @@ def test_admission_queue_fifo_and_feed_guard(rng):
     assert server.slot_of(c) == 0
 
 
+# one call of detach_many on a server whose slots 0-3 hold a-d (fed, so
+# their carries are live) while e then f wait: the uids it evicts, in order
+DETACH_MANY_CASES = {
+    "empty": [],
+    "one": ["b"],
+    "slotted": ["c", "a"],                # e -> c's slot, f -> a's
+    "waiting_mix": ["d", "f", "b"],       # f only waits: withdrawn
+    "admitted_then_evicted": ["a", "e"],  # e takes a's slot, then leaves
+    "all": ["a", "b", "c", "d", "e", "f"],
+    "unknown_uid_raises": ["b", "zz", "c"],  # b's slot still zeroed
+}
+
+
+def _busy_server(engine, rng):
+    server = SpikeServer(engine, n_slots=4, chunk_steps=3)
+    for uid in "abcdef":
+        server.attach(uid)
+    server.feed({uid: (rng.random((5, 10)) < 0.5).astype(np.int32)
+                 for uid in "abcd"})
+    return server
+
+
+@pytest.mark.parametrize("case", list(DETACH_MANY_CASES))
+def test_detach_many_equals_sequential_detach(rng, monkeypatch, case):
+    """One detach_many is a detach loop with one zeroing dispatch: the
+    same carry bytes, slots, FIFO admissions and stats; freed slots read
+    zero, the rest are untouched, and the streams admitted into freed
+    slots run exactly as from power-on. An empty call dispatches nothing."""
+    from repro.serving import snn
+
+    uids = DETACH_MANY_CASES[case]
+    # an unknown uid raises where a detach loop would: after those before it
+    done = uids[:uids.index("zz")] if "zz" in uids else uids
+    engine = _engine(rng)
+    seq, one = _busy_server(engine, np.random.default_rng(7)), \
+        _busy_server(engine, np.random.default_rng(7))
+    before = {k: np.asarray(x) for k, x in one.carry.items()}
+    freed = {one.slot_of(u) for u in done} - {None}
+    want_stats = [seq.detach(u) for u in done]
+    calls = []
+    zero = snn._zero_slots
+    monkeypatch.setattr(snn, "_zero_slots",
+                        lambda c, m: calls.append(m.sum()) or zero(c, m))
+    if done is uids:
+        got_stats = one.detach_many(uids)
+        assert [st.uid for st in got_stats] == [st.uid for st in want_stats]
+    else:
+        with pytest.raises(KeyError):
+            one.detach_many(uids)
+    assert calls == ([len(freed)] if freed else [])
+    assert one.scheduler.active == seq.scheduler.active
+    assert one.scheduler.waiting == seq.scheduler.waiting
+    assert one.scheduler.free_slot_ids == seq.scheduler.free_slot_ids
+    kept = [s for s in range(4) if s not in freed]
+    for k in ("v", "spikes"):
+        got = np.asarray(one.carry[k])
+        assert got.dtype == before[k].dtype == np.int32
+        assert got.tobytes() == np.asarray(seq.carry[k]).tobytes()
+        np.testing.assert_array_equal(got[sorted(freed)], 0)
+        assert got[kept].tobytes() == before[k][kept].tobytes()
+    # every slot now holding a stream that was never fed powers up clean
+    while one.scheduler.free_slots:
+        one.attach()
+    fresh = {uid: _raster(rng, 7, 10)[:, 0]
+             for uid, slot in one.scheduler.active.items() if slot in freed}
+    if fresh:
+        out = one.feed(fresh)
+    for uid, raster in fresh.items():
+        want = np.asarray(engine.run(raster[:, None, :])["spikes"])[:, 0]
+        np.testing.assert_array_equal(out[uid]["spikes"], want)
+
+
 def test_zero_length_chunk_is_per_stream_noop(rng):
     """T=0 chunks (an idle stream this round) return an empty raster and
     leave the carry untouched — mixed calls still serve the live streams."""

@@ -341,6 +341,39 @@ def test_async_frontend_sharded_parity(rng):
     _async_frontend_parity(rng, _mesh(2, 2))
 
 
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
+def test_mesh_server_detach_keeps_carry_sharding(rng, shape):
+    """Evicting from a sharded server zeroes the freed slots in place:
+    the carry keeps its sharding and its bytes match the single-device
+    server's, and the next occupants power up from zero."""
+    mesh = _mesh(*shape)
+    single, sharded = _engine_pair(rng, mesh=mesh)
+    noise = {u: (rng.random((4, single.n_inputs)) < 0.4).astype(np.int32)
+             for u in range(4)}
+    fresh = (rng.random((5, single.n_inputs)) < 0.35).astype(np.int32)
+    want = np.asarray(single.run(fresh[:, None, :])["spikes"])[:, 0]
+    carries = []
+    for engine in (single, sharded):
+        server = SpikeServer(engine, n_slots=4, chunk_steps=3)
+        for u in range(4):
+            server.attach(u)
+        server.feed(noise)
+        shardings = {k: x.sharding for k, x in server.carry.items()}
+        server.detach(1)
+        server.detach_many([3, 0])
+        for k, x in server.carry.items():
+            assert x.sharding.is_equivalent_to(shardings[k], x.ndim), k
+            np.testing.assert_array_equal(np.asarray(x)[[0, 1, 3]], 0)
+        carries.append({k: np.asarray(x) for k, x in server.carry.items()})
+        for u in ("x", "y", "z"):
+            server.attach(u)
+        out = server.feed({u: fresh for u in ("x", "y", "z")})
+        for u in ("x", "y", "z"):
+            np.testing.assert_array_equal(out[u]["spikes"], want)
+    for k in ("v", "spikes"):
+        assert carries[0][k].tobytes() == carries[1][k].tobytes()
+
+
 # --------------------------------------------------------------------------
 # ensure_host_devices: faked devices on CPU only
 # --------------------------------------------------------------------------
