@@ -7,10 +7,12 @@ place on the same inputs.
         --seeds 1,2,3
 
 One process runs every seed: set-up, warm-up and a window at the cell's
-own load, then the program's served rasters and the control's rasters of
-the same inputs are compared with the exact reference. Not part of a
-benchmark run; ``tests/bench/test_perfbench_reference.py`` keeps the
-control at a size a test run holds.
+own load, then the program's served streams and the control's streams of
+the same inputs are compared with the exact reference, on every check the
+cell's configuration lists (raster bits, and final potentials where
+listed). Not part of a benchmark run;
+``tests/bench/test_perfbench_reference.py`` keeps the control at a size a
+test run holds.
 """
 
 import time
@@ -40,17 +42,23 @@ def readings(cell, seed: int, seconds: float) -> dict:
     net = dep.net
     del dep, driver
     gc.collect()
-    exact = reference.Reference(net, cell.config)
-    control = reference.Reference(net, cell.config, "bf16")
+    compare = cell.config["checks"]
+    exact = reference.model(ROOT, net, cell.config)
+    control = reference.model(ROOT, net, cell.config, "bf16")
     as_control = []
-    for c, spikes in zip(checks, control.answers(checks)):
+    for c, (spikes, v) in zip(checks, reference.answers(control, checks)):
         raster = np.zeros_like(np.asarray(c.served))
         raster[:, :control.n_neurons] = spikes
-        as_control.append(reference.Check(ext=c.ext, served=raster))
+        pot = None
+        if c.potentials is not None:
+            pot = np.zeros_like(np.asarray(c.potentials))
+            pot[:control.n_neurons] = v
+        as_control.append(reference.Check(ext=c.ext, served=raster,
+                                          potentials=pot))
     return {"streams": len(checks),
             "timesteps": int(sum(c.ext.shape[0] for c in checks)),
-            "program": reference.mismatches(exact, checks),
-            "control": reference.mismatches(exact, as_control)}
+            "program": reference.mismatches(exact, checks, compare),
+            "control": reference.mismatches(exact, as_control, compare)}
 
 
 def main(argv) -> int:

@@ -6,7 +6,7 @@ from __future__ import annotations
 import dataclasses
 import time
 
-from bench import load
+from bench import load, reference
 from bench.reference import Network
 
 
@@ -23,7 +23,7 @@ def network(root, config: dict, seed: int) -> Network:
     ``networks/<kind>.py`` builder."""
     spec = config["network"]
     builder = load.module(root, "networks", spec["kind"])
-    return builder.build(spec, config["lif"], seed)
+    return builder.build(spec, config["neuron"], seed)
 
 
 def deploy(root, config: dict, seed: int, frontend: dict | None,
@@ -38,7 +38,6 @@ def deploy(root, config: dict, seed: int, frontend: dict | None,
     t = time.perf_counter()
     from repro.core.cerebra_h import CerebraHConfig
     from repro.core.fixedpoint import FixedPointFormat
-    from repro.core.lif import LIFParams
     from repro.core.mapping import ClusterGeometry
     from repro.core.network import SNNetwork
     from repro.core.session import AcceleratorSession
@@ -50,8 +49,8 @@ def deploy(root, config: dict, seed: int, frontend: dict | None,
     fmt = FixedPointFormat(int_bits=fx["int_bits"], frac_bits=fx["frac_bits"])
     program_net = SNNetwork(
         n_inputs=net.n_inputs, n_neurons=net.n_neurons, weights=net.weights,
-        params=LIFParams(decay_rate=net.decay_rate, threshold=net.threshold,
-                         reset_mode=net.reset, fmt=fmt),
+        params=reference.neuron_module(root, net).program_params(
+            net.neuron, fmt),
         output_slice=net.output_slice)
     accel = CerebraHConfig(geometry=ClusterGeometry(**hw["geometry"]),
                            fmt=fmt, row_mode=hw["row_mode"])

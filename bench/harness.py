@@ -21,7 +21,8 @@ import time
 from bench import deploy, load, peaks, reference, xtrace
 
 # the numbers compared, each with its limit: an exact comparison
-LIMITS = {"mismatched_spikes": 0, "unanswered": 0}
+LIMITS = {"mismatched_spikes": 0, "mismatched_potentials": 0,
+          "unanswered": 0}
 
 
 def log(msg: str) -> None:
@@ -64,6 +65,7 @@ class Observation:
     """What a per-layer metric reader may read: the cell, its driver's
     records, and the traced window's reduction."""
 
+    root: pathlib.Path         # the checkout, to find other readers by name
     cell: Cell
     net: reference.Network
     driver: object
@@ -201,7 +203,8 @@ def main(argv, *, root, started: float, require_tpu: bool = True) -> int:
 
     t = time.perf_counter()
     numbers = reference.mismatches(
-        reference.Reference(net, cell.config), checks)
+        reference.model(root, net, cell.config), checks,
+        cell.config["checks"])
     numbers["unanswered"] = unanswered
     log(f"reference over {len(checks)} streams, "
         f"{sum(c.ext.shape[0] for c in checks)} timesteps: "
@@ -214,8 +217,9 @@ def main(argv, *, root, started: float, require_tpu: bool = True) -> int:
         tr = xtrace.load(str(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
         lo, hi = tr.window()
-        obs = Observation(cell=cell, net=net, driver=driver, trace=tr,
-                          work=work, lo=lo, hi=hi, device_kind=dev["kind"])
+        obs = Observation(root=root, cell=cell, net=net, driver=driver,
+                          trace=tr, work=work, lo=lo, hi=hi,
+                          device_kind=dev["kind"])
         dev["busy_s"] = obs.busy_s
         dev["window_s"] = obs.window_s
         for m in cell.per_layer:

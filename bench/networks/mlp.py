@@ -12,7 +12,7 @@ from bench import seeds
 from bench.reference import Network
 
 
-def build(spec: dict, lif: dict, seed: int) -> Network:
+def build(spec: dict, neuron: dict, seed: int) -> Network:
     import jax
 
     sizes = [int(s) for s in spec["layer_sizes"]]
@@ -34,5 +34,4 @@ def build(spec: dict, lif: dict, seed: int) -> Network:
         src, dst = n_in + dst, dst + b
     return Network(weights=w, n_inputs=n_in, n_neurons=n_neurons,
                    output_slice=(n_neurons - sizes[-1], n_neurons),
-                   decay_rate=lif["decay_rate"], threshold=lif["threshold"],
-                   reset=lif["reset"])
+                   neuron=neuron)

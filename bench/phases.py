@@ -174,9 +174,9 @@ def run(root, workload: str, seed: int, seconds: float, keep=None,
     dispatch = spans_in(program, "snn.feed.dispatch", lo, hi)
     readback = spans_in(program, "snn.feed.readback", lo, hi)
     # the harness's own readings of the same window, for comparison
-    obs = harness.Observation(cell=cell, net=dep.net, driver=driver,
-                              trace=tr, work=None, lo=lo, hi=hi,
-                              device_kind=dev.device_kind)
+    obs = harness.Observation(root=root, cell=cell, net=dep.net,
+                              driver=driver, trace=tr, work=None, lo=lo,
+                              hi=hi, device_kind=dev.device_kind)
     out = {
         "workload": workload, "seed": seed, "device": dev.device_kind,
         "rounds": driver.rounds, "window_s": obs.window_s,

@@ -1,18 +1,13 @@
-"""The plain reference: the fixed-point LIF network a configuration states,
-written from its description alone, and the comparison that decides
-``correct``.
+"""The network a configuration states, its plain reference, and the
+comparison that decides ``correct``.
 
-It imports nothing of the program and takes nothing the program made: it
-quantizes the float weights the benchmark generated, applies the decay,
-threshold and reset the configuration states, and steps every stream in
-NumPy. Sums run in float64 over 0/1 sources, which is exact while
-``|sum| < 2**53``; the membrane add wraps at 32 bits as the hardware's
-adders do.
-
-``precision="bf16"`` is the control: the same network with every weight
-rounded to bfloat16 before the accumulate, the single-pass MXU shortcut
-that a faster kernel would be tempted to take. It must fail the
-comparison.
+The reference of a network is found by its configuration's neuron kind:
+``neurons/<kind>.py`` holds the kind's NumPy reference (``Reference``),
+which imports nothing of the program, and its translation into the
+program's deployment (``program_params``). A configuration lists under
+``checks`` what the comparison holds the served streams to: ``spikes``
+(every raster bit) and ``potentials`` (every membrane potential after the
+stream's last step).
 """
 
 from __future__ import annotations
@@ -21,21 +16,22 @@ import dataclasses
 
 import numpy as np
 
-PRECISIONS = ("exact", "bf16")
+from bench import load
+
+CHECKS = ("spikes", "potentials")
 
 
 @dataclasses.dataclass(frozen=True)
 class Network:
     """A network as the benchmark generated it: float weights over
-    ``n_inputs`` external sources then ``n_neurons`` neurons."""
+    ``n_inputs`` external sources then ``n_neurons`` neurons, of the
+    configuration's ``neuron`` model."""
 
     weights: np.ndarray           # (n_inputs + n_neurons, n_neurons) float32
     n_inputs: int
     n_neurons: int
     output_slice: tuple[int, int]
-    decay_rate: float             # as the source states it
-    threshold: float
-    reset: str                    # "zero" | "subtract" | "hold"
+    neuron: dict                  # the configuration's ``neuron`` section
 
     @property
     def n_synapses(self) -> int:
@@ -49,116 +45,77 @@ def quantize(weights, int_bits: int, frac_bits: int) -> np.ndarray:
     return np.clip(r, lo, hi).astype(np.int64)
 
 
-def hardware_decay(rate: float, supported) -> float:
-    """The supported decay rate nearest to ``rate`` (first on a tie)."""
-    return float(min(supported, key=lambda r: abs(r - rate)))
+def neuron_module(root, net: Network):
+    """``neurons/<kind>.py`` of the network's neuron model."""
+    return load.module(root, "neurons", net.neuron["kind"])
 
 
-def _wrap32(x):
-    return ((x + (1 << 31)) % (1 << 32)) - (1 << 31)
+def model(root, net: Network, config: dict, precision: str = "exact"):
+    """The plain reference of ``net`` in ``precision`` (``"exact"``, or a
+    lower one the neuron kind offers as the control)."""
+    return neuron_module(root, net).Reference(net, config, precision)
 
 
-def _decay(v, rate: float):
-    """Arithmetic-shift decay of int64-held int32 potentials."""
-    if rate == 0.125:
-        return v - (v >> 3)
-    if rate == 0.25:
-        return v - (v >> 2)
-    if rate == 0.5:
-        return v - (v >> 1)
-    if rate == 0.75:
-        return v >> 2
-    raise ValueError(f"no shift decay for rate {rate}")
-
-
-def _bf16(x: np.ndarray) -> np.ndarray:
-    import ml_dtypes
-
-    return np.asarray(x, np.float32).astype(ml_dtypes.bfloat16).astype(
-        np.float64)
-
-
-class Reference:
-    """Steps streams of one network from the power-on state (V = 0, no
-    prior spikes)."""
-
-    def __init__(self, net: Network, config: dict, precision: str = "exact"):
-        if precision not in PRECISIONS:
-            raise ValueError(f"precision must be one of {PRECISIONS}")
-        fx = config["fixed_point"]
-        scale = 1 << fx["frac_bits"]
-        wq = quantize(net.weights, fx["int_bits"], fx["frac_bits"])
-        self.w = (wq.astype(np.float64) if precision == "exact"
-                  else _bf16(wq))
-        self.n_inputs = net.n_inputs
-        self.n_neurons = net.n_neurons
-        self.rate = hardware_decay(net.decay_rate,
-                                   config["hardware"]["decay_rates"])
-        self.threshold = int(round(net.threshold * scale))
-        self.reset = net.reset
-
-    def run(self, ext: np.ndarray) -> np.ndarray:
-        """(B, T, n_inputs) 0/1 -> (B, T, n_neurons) uint8 spikes."""
-        B, T, _ = ext.shape
-        v = np.zeros((B, self.n_neurons), np.int64)
-        prev = np.zeros((B, self.n_neurons), np.float64)
-        out = np.zeros((B, T, self.n_neurons), np.uint8)
-        src = np.zeros((B, self.n_inputs + self.n_neurons), np.float64)
-        for t in range(T):
-            src[:, :self.n_inputs] = ext[:, t]
-            src[:, self.n_inputs:] = prev
-            syn = np.rint(src @ self.w).astype(np.int64)
-            v = _wrap32(_decay(v, self.rate) + syn)
-            spikes = v >= self.threshold
-            if self.reset == "zero":
-                v = np.where(spikes, 0, v)
-            elif self.reset == "subtract":
-                v = _wrap32(v - spikes * self.threshold)
-            out[:, t] = spikes
-            prev = spikes.astype(np.float64)
-        return out
-
-    def answers(self, checks, block: int = 64) -> list:
-        """The reference's raster (T, n_neurons) for every check's inputs,
-        checks of equal length run as one batch, ``block`` at a time."""
-        out = [None] * len(checks)
-        by_len: dict[int, list[int]] = {}
-        for i, c in enumerate(checks):
-            by_len.setdefault(c.ext.shape[0], []).append(i)
-        for idx in by_len.values():
-            for j in range(0, len(idx), block):
-                part = idx[j:j + block]
-                spikes = self.run(np.stack([checks[i].ext for i in part]))
-                for k, i in enumerate(part):
-                    out[i] = spikes[k]
-        return out
+def answers(ref, checks, block: int = 64) -> list:
+    """The reference's ``(raster (T, n_neurons), potentials (n_neurons,))``
+    for every check's inputs, checks of equal length run as one batch,
+    ``block`` at a time."""
+    out = [None] * len(checks)
+    by_len: dict[int, list[int]] = {}
+    for i, c in enumerate(checks):
+        by_len.setdefault(c.ext.shape[0], []).append(i)
+    for idx in by_len.values():
+        for j in range(0, len(idx), block):
+            part = idx[j:j + block]
+            spikes, v = ref.run(np.stack([checks[i].ext for i in part]))
+            for k, i in enumerate(part):
+                out[i] = (spikes[k], v[k])
+    return out
 
 
 @dataclasses.dataclass
 class Check:
-    """One answer to check: the external spikes a stream was sent and the
-    physical raster it got back (None if it never came)."""
+    """One answer to check: the external spikes a stream was sent, the
+    physical raster it got back (None if it never came), and, where the
+    configuration checks them, the stream's physical membrane potentials
+    after its last step."""
 
     ext: np.ndarray                 # (T, n_inputs)
     served: np.ndarray | None       # (T', n_phys)
+    potentials: np.ndarray | None = None   # (n_phys,)
 
 
-def mismatches(ref: Reference, checks) -> dict:
-    """Spike bits where the served rasters differ from ``ref``.
+def mismatches(ref, checks, compare) -> dict:
+    """The numbers compared: for each of ``compare`` (a configuration's
+    ``checks``), the bits or potentials where the served streams differ
+    from ``ref``, and the streams that never answered.
 
-    A served raster holds the model's neurons at physical slots
+    A served stream holds the model's neurons at physical slots
     ``0..n_neurons-1`` (the configuration deploys from cluster 0) and
-    nothing elsewhere: a spike outside the model counts as a mismatch, and
-    so does every step of a raster that is short or long.
+    nothing elsewhere: a spike or a nonzero potential outside the model
+    counts as a mismatch, so does every step of a raster that is short or
+    long, and every neuron of an answer that carries no potentials.
     """
+    unknown = set(compare) - set(CHECKS)
+    if unknown:
+        raise ValueError(f"unknown checks {sorted(unknown)}; known {CHECKS}")
     answered = [c for c in checks if c.served is not None]
-    bad, N = 0, ref.n_neurons
-    for c, want in zip(answered, ref.answers(answered)):
+    spikes = potentials = 0
+    N = ref.n_neurons
+    for c, (want, v) in zip(answered, answers(ref, answered)):
         got = np.asarray(c.served) != 0
         T = want.shape[0]
         n = min(T, got.shape[0])
-        bad += int(np.count_nonzero(got[:n, :N] != want[:n]))
-        bad += int(np.count_nonzero(got[:n, N:]))
-        bad += abs(T - got.shape[0]) * N
-    return {"mismatched_spikes": bad,
-            "unanswered": len(checks) - len(answered)}
+        spikes += int(np.count_nonzero(got[:n, :N] != want[:n]))
+        spikes += int(np.count_nonzero(got[:n, N:]))
+        spikes += abs(T - got.shape[0]) * N
+        if c.potentials is None:
+            potentials += N
+            continue
+        pot = np.asarray(c.potentials, np.int64)
+        potentials += int(np.count_nonzero(pot[:N] != v))
+        potentials += int(np.count_nonzero(pot[N:]))
+    numbers = {"spikes": spikes, "potentials": potentials}
+    out = {f"mismatched_{k}": numbers[k] for k in compare}
+    out["unanswered"] = len(checks) - len(answered)
+    return out

@@ -42,9 +42,15 @@ def _break(fault: str) -> None:
         t, b = flat // act.shape[1], flat % act.shape[1]
         return new, spikes.at[t, b, 0].set(1 - spikes[t, b, 0])
 
+    def altered_potential(self, carry, ext, active=None):
+        # one membrane potential of the first slot moved by one LSB
+        new, spikes = step(self, carry, ext, active)
+        return dict(new, v=new["v"].at[0, 0].add(1)), spikes
+
     SpikeEngine.step_chunk = {"stale-state": stale_state,
                               "half-batch": half_batch,
-                              "altered-answer": altered_answer}[fault]
+                              "altered-answer": altered_answer,
+                              "altered-potential": altered_potential}[fault]
 
 
 def main() -> int:

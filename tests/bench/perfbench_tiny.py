@@ -27,18 +27,27 @@ CONFIGS = {
                  {"network": {"kind": "mlp", "layer_sizes": [784, 16, 4],
                               "std_scale": 3.0, "weight_clip": 1.0},
                   "stimulus": {"kind": "digits", "pool": 8}}),
+    "tiny-pid": ("snapv-pid-36", {}),
 }
 TRAFFIC = {
     "tiny-closed": {"driver": "requests", "clients": 6, "lengths": [8, 16],
                     "sample_rate": 1.0, "frontend": FRONTEND},
     "tiny-mixed": {"driver": "requests", "clients": 9, "lengths": [8, 24],
                    "sample_rate": 1.0, "frontend": FRONTEND},
+    # every plant sampled, so a fault in any slot shows
+    "tiny-fleet": {"driver": "fleet", "plants": 4, "period_ms": 10,
+                   "tick_steps": 24, "warm_ticks": 2, "sample_plants": 4,
+                   "plant": {"gain": 0.8, "dt": 1.0, "u_max": 0.25},
+                   "encoder": {"err_scale": 0.5},
+                   "setpoints": {"every_ticks": 6, "low": -1.0,
+                                 "high": 1.0}},
 }
-# tiny cell -> (config, traffic, its end-to-end metric); the cell also
-# reports every per-layer metric that moves that metric
+# tiny cell -> (config, traffic, its end-to-end metrics); the cell also
+# reports every per-layer metric that moves one of them
 CELLS = {
-    "tiny-closed": ("tiny-mlp", "tiny-closed", "timesteps_per_s"),
-    "tiny-mixed": ("tiny-mlp", "tiny-mixed", "timesteps_per_s"),
+    "tiny-closed": ("tiny-mlp", "tiny-closed", ("timesteps_per_s",)),
+    "tiny-mixed": ("tiny-mlp", "tiny-mixed", ("timesteps_per_s",)),
+    "tiny-fleet": ("tiny-pid", "tiny-fleet", ("tick_p95_ms", "loop_rate_hz")),
 }
 
 
@@ -65,10 +74,10 @@ def make_root(tmp) -> pathlib.Path:
                                  "traffic": traffic, "chips": 1,
                                  "why": "CPU test"})
         for m in man["end_to_end"]:
-            if m["name"] == e2e:
+            if m["name"] in e2e:
                 m["workloads"].append(name)
         for m in man["per_layer"]:
-            if m["moves"] == e2e:
+            if m["moves"] in e2e:
                 m["workloads"].append(name)
     write_manifest(root, man)
     return root
