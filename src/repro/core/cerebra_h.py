@@ -48,6 +48,7 @@ __all__ = [
     "CerebraHProgram",
     "compile_network",
     "make_engine",
+    "syn_decay_spec",
     "cost_model",
     "run",
 ]
@@ -85,6 +86,8 @@ class CerebraHProgram:
     decay_rate: float             # snapped to hardware-supported rate
     capacity_report: dict
     comm_profile: dict
+    # synaptic-current decay, snapped like decay_rate; None = one-state LIF
+    syn_decay_rate: float | None = None
     # per-program engine cache: one compiled scan per backend
     _engines: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
@@ -124,6 +127,8 @@ def compile_network(
     # deployment-time snapping of the trained decay to a hardware rate —
     # one of the two quantization effects the accuracy study measures.
     decay_rate = fxp.nearest_shift_decay(net.params.decay_rate)
+    syn_decay_rate = (None if not net.params.has_current
+                      else fxp.nearest_shift_decay(net.params.syn_decay_rate))
 
     lo, hi = net.output_slice
     return CerebraHProgram(
@@ -139,6 +144,7 @@ def compile_network(
         decay_rate=decay_rate,
         capacity_report=capacity,
         comm_profile=comm,
+        syn_decay_rate=syn_decay_rate,
     )
 
 
@@ -147,7 +153,8 @@ def make_engine(program: CerebraHProgram,
     """The program's SpikeEngine for ``backend`` (built once, then cached).
 
     The blocked SRAM image (S, C, n) flattens to the engine's (S, P) weight
-    matrix; the H generation decays with the arithmetic-shift PDU.
+    matrix; the H generation decays with the arithmetic-shift PDU (the
+    synaptic current too, for current-based neurons).
     """
     engine = program._engines.get(backend)
     if engine is None:
@@ -156,12 +163,20 @@ def make_engine(program: CerebraHProgram,
             Wb.reshape(Wb.shape[0], -1),
             program.n_inputs,
             decay=DecaySpec.shift(program.decay_rate),
+            syn_decay=syn_decay_spec(program),
             threshold_raw=program.params.threshold_raw,
             reset_mode=program.params.reset_mode,
             backend=backend,
         )
         program._engines[backend] = engine
     return engine
+
+
+def syn_decay_spec(program: CerebraHProgram) -> DecaySpec | None:
+    """The shift PDU of the program's synaptic current (None: no current)."""
+    if program.syn_decay_rate is None:
+        return None
+    return DecaySpec.shift(program.syn_decay_rate)
 
 
 def cost_model(program: CerebraHProgram, ext_spikes, spikes) -> dict:

@@ -68,6 +68,8 @@ class CerebraSProgram:
     fanout: np.ndarray             # (n_sources,) int — bus events per spike
     output_slice: tuple[int, int]
     decay_raw: int                 # fixed-point retain factor for the PDU
+    # the synaptic current's retain factor; None = one-state LIF
+    syn_decay_raw: int | None = None
     # per-program engine cache: one compiled scan per backend
     _engines: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
@@ -108,6 +110,8 @@ def compile_network(
     # Cerebra-S keeps the fixed-point multiplier: the retain factor itself is
     # quantized to Q16.16 but otherwise arbitrary.
     decay_raw = int(round(net.params.beta * config.fmt.scale))
+    syn_decay_raw = (None if not net.params.has_current else int(round(
+        (1.0 - net.params.syn_decay_rate) * config.fmt.scale)))
     return CerebraSProgram(
         config=config,
         params=net.params,
@@ -117,6 +121,7 @@ def compile_network(
         fanout=np.count_nonzero(W, axis=1),
         output_slice=net.output_slice,
         decay_raw=decay_raw,
+        syn_decay_raw=syn_decay_raw,
     )
 
 
@@ -134,6 +139,8 @@ def make_engine(program: CerebraSProgram,
             program.weights_raw,
             program.n_inputs,
             decay=DecaySpec.mul(program.decay_raw),
+            syn_decay=(None if program.syn_decay_raw is None
+                       else DecaySpec.mul(program.syn_decay_raw)),
             threshold_raw=program.params.threshold_raw,
             reset_mode=program.params.reset_mode,
             backend=backend,

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import fixedpoint as fxp
-from repro.core.lif import fire_reset, lif_init
+from repro.core.lif import lif_init
 
 __all__ = [
     "BACKENDS",
@@ -104,12 +104,10 @@ class DecaySpec:
             )
         return cls(kind="mul", raw=int(raw))
 
-    def apply(self, v):
-        if self.kind == "shift":
-            return fxp.shift_decay(v, self.rate)
-        if self.kind == "mul":
-            return fxp.fx_mul(v, jnp.int32(self.raw))
-        raise ValueError(f"unknown decay kind {self.kind!r}")
+    @property
+    def triple(self) -> tuple:
+        """``(kind, rate, raw)``: the form the kernels take it in."""
+        return (self.kind, self.rate, self.raw)
 
 
 def mxu_partial_sum_bound(weights_raw: np.ndarray,
@@ -162,6 +160,15 @@ class SpikeEngine:
         syn_t     = sources_t @ W_raw                         # backend
         v_t, spikes_t = fire_reset(decay(v_{t-1}) + syn_t)    # shared LIF
 
+    With ``syn_decay`` the neurons are current-based: each carries a
+    synaptic current ``i`` beside ``v``, and the epilogue becomes
+
+        i_t = syn_decay(i_{t-1}) + syn_t
+        v_t, spikes_t = fire_reset(decay(v_{t-1}) + i_t)
+
+    (:func:`repro.core.lif.cuba_step_fixed`). The carry then holds
+    ``{'v', 'i', 'spikes'}``; without it, ``{'v', 'spikes'}`` as ever.
+
     Construction validates the backend (including the pallas-mxu 2^24
     exactness bound); :meth:`run` jit-compiles the whole scan once per
     engine and reuses it across calls (per-program jit caching).
@@ -179,6 +186,7 @@ class SpikeEngine:
         interpret: bool | None = None,
         gate: str = "batch-tile",
         fuse_steps: int = 1,
+        syn_decay: DecaySpec | None = None,
     ):
         if backend not in BACKENDS:
             raise ValueError(
@@ -229,6 +237,7 @@ class SpikeEngine:
         self.n_phys = int(n_phys)
         self.n_sources = int(n_sources)
         self.decay = decay
+        self.syn_decay = syn_decay
         self.threshold_raw = int(threshold_raw)
         self.reset_mode = str(reset_mode)
         self.backend = backend
@@ -260,18 +269,33 @@ class SpikeEngine:
 
         return MeshSpikeEngine.from_engine(self, mesh)
 
+    @property
+    def has_current(self) -> bool:
+        """True for current-based neurons (a synaptic current ``i`` in
+        the carry)."""
+        return self.syn_decay is not None
+
+    @property
+    def carry_keys(self) -> tuple[str, ...]:
+        """The states of a slot's carry, in :meth:`init_carry`'s order."""
+        return ("v", "spikes", "i") if self.has_current else ("v", "spikes")
+
+    def _program(self) -> dict:
+        """The constructor arguments that fix this engine's program."""
+        return dict(decay=self.decay, syn_decay=self.syn_decay,
+                    threshold_raw=self.threshold_raw,
+                    reset_mode=self.reset_mode, backend=self.backend,
+                    interpret=self.interpret, gate=self.gate,
+                    fuse_steps=self.fuse_steps)
+
     def with_gate(self, gate: str) -> "SpikeEngine":
         """This engine's program re-hosted under another event-gate
         granularity (bit-identical outputs; only skipped-zero work
         differs). Returns ``self`` when the gate already matches."""
         if gate == self.gate:
             return self
-        return SpikeEngine(
-            self.weights_raw, self.n_inputs, decay=self.decay,
-            threshold_raw=self.threshold_raw, reset_mode=self.reset_mode,
-            backend=self.backend, interpret=self.interpret, gate=gate,
-            fuse_steps=self.fuse_steps,
-        )
+        return SpikeEngine(self.weights_raw, self.n_inputs,
+                           **dict(self._program(), gate=gate))
 
     def with_fuse_steps(self, fuse_steps: int) -> "SpikeEngine":
         """This engine's program re-hosted under another K-step fusion
@@ -279,12 +303,8 @@ class SpikeEngine:
         traffic differ). Returns ``self`` when K already matches."""
         if int(fuse_steps) == self.fuse_steps:
             return self
-        return SpikeEngine(
-            self.weights_raw, self.n_inputs, decay=self.decay,
-            threshold_raw=self.threshold_raw, reset_mode=self.reset_mode,
-            backend=self.backend, interpret=self.interpret, gate=self.gate,
-            fuse_steps=fuse_steps,
-        )
+        return SpikeEngine(self.weights_raw, self.n_inputs,
+                           **dict(self._program(), fuse_steps=fuse_steps))
 
     # ------------------------------------------------------------------
     def init_carry(self, batch: int) -> dict:
@@ -293,12 +313,16 @@ class SpikeEngine:
         Both Cerebra generations power up with cleared membrane SRAM; this
         is the single definition (via :func:`repro.core.lif.lif_init`)
         that ``cerebra_s.run`` and ``cerebra_h.run`` previously duplicated
-        inconsistently.
+        inconsistently. A current-based engine adds the synaptic current
+        ``'i'``, zero at power-on too.
         """
-        return {
+        carry = {
             "v": lif_init((batch, self.n_phys), fixed=True)["v"],
             "spikes": jnp.zeros((batch, self.n_phys), jnp.int32),
         }
+        if self.has_current:
+            carry["i"] = jnp.zeros((batch, self.n_phys), jnp.int32)
+        return carry
 
     # ------------------------------------------------------------------
     def _step(self, weights, carry, ext_t):
@@ -306,6 +330,11 @@ class SpikeEngine:
         sources = jnp.concatenate(
             [ext_t.astype(jnp.int32), carry["spikes"]], axis=-1
         )  # (B, S)
+        # deferred: breaks the core <-> kernels import cycle
+        from repro.kernels import ops
+        from repro.kernels.epilogue import decay_and_fire
+
+        currents = (carry["i"],) if self.has_current else ()
         if self.backend == "reference":
             syn = jax.lax.dot_general(
                 sources,
@@ -313,28 +342,35 @@ class SpikeEngine:
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
-            v_new = self.decay.apply(carry["v"]) + syn
-            v_out, spikes = fire_reset(
-                v_new, jnp.int32(self.threshold_raw), self.reset_mode
-            )
+            v_out, spikes, *currents = decay_and_fire(
+                carry["v"], syn, i=carry.get("i"), **self._epilogue)
         else:
-            from repro.kernels import ops  # deferred: breaks import cycle
-
-            v_out, spikes = ops.spike_timestep(
+            v_out, spikes, *currents = ops.spike_timestep(
                 sources,
                 weights,
                 carry["v"],
-                decay_kind=self.decay.kind,
-                decay_rate=self.decay.rate,
-                decay_raw=self.decay.raw,
-                threshold_raw=self.threshold_raw,
-                reset_mode=self.reset_mode,
+                *currents,
+                **self._epilogue,
                 use_mxu=(self.backend == "pallas-mxu"),
                 block_batch=(1 if self.gate == "per-example"
                              else _GATE_TILE_BATCH),
                 interpret=self.interpret,
             )
-        return {"v": v_out, "spikes": spikes}, spikes
+        return dict(zip(self.carry_keys, (v_out, spikes, *currents))), spikes
+
+    @property
+    def _epilogue(self) -> dict:
+        """The LIF epilogue's static arguments, as every backend takes
+        them (``syn_decay`` None for one-state neurons)."""
+        return dict(
+            decay_kind=self.decay.kind,
+            decay_rate=self.decay.rate,
+            decay_raw=self.decay.raw,
+            threshold_raw=self.threshold_raw,
+            reset_mode=self.reset_mode,
+            syn_decay=(None if self.syn_decay is None
+                       else self.syn_decay.triple),
+        )
 
     def step(self, carry, ext_t):
         """Public single-step entry (closed-loop / streaming callers).
@@ -365,10 +401,7 @@ class SpikeEngine:
             ext_t, act_t = xs
             new, spikes = step_fn(c, ext_t)
             keep = act_t[:, None] != 0
-            c_out = {
-                "v": jnp.where(keep, new["v"], c["v"]),
-                "spikes": jnp.where(keep, new["spikes"], c["spikes"]),
-            }
+            c_out = {k: jnp.where(keep, new[k], c[k]) for k in c}
             return c_out, jnp.where(keep, spikes, 0)
 
         return jax.lax.scan(body, carry, (ext, active))
@@ -390,20 +423,18 @@ class SpikeEngine:
         (K,B,P) emitted raster)."""
         from repro.kernels import ops  # deferred: breaks import cycle
 
-        v_out, spk_carry, raster = ops.spike_timestep_fused(
-            ext_w, carry["spikes"], weights, carry["v"], act_w,
+        currents = (carry["i"],) if self.has_current else ()
+        v_out, spk_carry, raster, *currents = ops.spike_timestep_fused(
+            ext_w, carry["spikes"], weights, carry["v"], act_w, *currents,
             n_inputs=self.n_inputs,
-            decay_kind=self.decay.kind,
-            decay_rate=self.decay.rate,
-            decay_raw=self.decay.raw,
-            threshold_raw=self.threshold_raw,
-            reset_mode=self.reset_mode,
+            **self._epilogue,
             use_mxu=(self.backend == "pallas-mxu"),
             block_batch=(1 if self.gate == "per-example"
                          else _GATE_TILE_BATCH),
             interpret=self.interpret,
         )
-        return {"v": v_out, "spikes": spk_carry}, raster
+        new = dict(zip(self.carry_keys, (v_out, spk_carry, *currents)))
+        return new, raster
 
     def _fused_scan(self, weights, carry, ext, active):
         K = self.fuse_steps
@@ -432,7 +463,8 @@ class SpikeEngine:
         """Advance a slot batch over a chunk of timesteps, with masking.
 
         Args:
-          carry: {'v': (B, n_phys), 'spikes': (B, n_phys)} int32 slot state.
+          carry: {'v': (B, n_phys), 'spikes': (B, n_phys)} int32 slot state
+            (and 'i', the synaptic current, for a current-based engine).
           ext: (T, B, n_inputs) external spikes; rows of inactive slots are
             ignored (conventionally zero).
           active: (T, B) mask; slot b consumes step t iff active[t, b] != 0.
@@ -472,7 +504,10 @@ class SpikeEngine:
         else:
             step = lambda c, x: self._step(weights, c, x)
             final, spikes = jax.lax.scan(step, carry, ext_spikes)
-        return {"spikes": spikes, "v_final": final["v"]}
+        out = {"spikes": spikes, "v_final": final["v"]}
+        if self.has_current:
+            out["i_final"] = final["i"]
+        return out
 
     def run(self, ext_spikes, *, events_capacity: int | None = None,
             events_policy: str = "error") -> dict:
@@ -489,6 +524,8 @@ class SpikeEngine:
         Returns:
           {'spikes': (T, B, n_phys) int32 raster,
            'v_final': (B, n_phys) int32 membrane state after step T,
+           'i_final': (B, n_phys) int32 synaptic current after step T
+             (current-based engines only),
            'events': AERStream of 'spikes' (only with events_capacity)}.
 
         Exactness: every backend returns bit-identical rasters (the

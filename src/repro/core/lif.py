@@ -9,6 +9,17 @@ Two parallel implementations, mirroring the paper's evaluation methodology:
   shift-based decay restricted to the four hardware rates, three reset
   modes). This plays the role of the RTL simulation.
 
+A neuron with current-based synapses (``LIFParams.syn_decay_rate`` set)
+carries a second state, the synaptic current ``i``, with a leak of its
+own: :func:`cuba_step_fixed` and :func:`cuba_step_float` are its hardware
+model and software reference. The current enters the membrane in the step
+it is accumulated, so the epilogue stays "accumulate -> decay -> add ->
+compare"::
+
+    i_t        = decay_syn(i_{t-1}) + acc_t
+    u_t        = decay_mem(v_{t-1}) + i_t
+    (v_t, s_t) = fire_reset(u_t, threshold, reset)
+
 Both are pure functions over explicit state so they compose with
 ``jax.lax.scan`` over timesteps and with ``vmap``/``pjit`` over batch and
 population axes.
@@ -40,6 +51,8 @@ __all__ = [
     "fire_reset",
     "lif_step_float",
     "lif_step_fixed",
+    "cuba_step_float",
+    "cuba_step_fixed",
     "surrogate_spike",
 ]
 
@@ -55,11 +68,18 @@ class LIFParams:
     threshold: float = 1.0
     reset_mode: ResetMode = "zero"
     fmt: fxp.FixedPointFormat = fxp.Q16_16
+    # fraction of the synaptic current removed / step; None is the
+    # one-state LIF (no current: the accumulate adds to the membrane)
+    syn_decay_rate: float | None = None
 
     @property
     def beta(self) -> float:
         """Retain factor (snnTorch convention)."""
         return 1.0 - self.decay_rate
+
+    @property
+    def has_current(self) -> bool:
+        return self.syn_decay_rate is not None
 
     @property
     def threshold_raw(self) -> int:
@@ -140,6 +160,45 @@ def lif_step_fixed(state, syn_input_raw, params: LIFParams):
     v_out, spikes = fire_reset(v_new, jnp.int32(params.threshold_raw),
                                params.reset_mode)
     return {"v": v_out}, spikes
+
+
+def cuba_step_float(state, syn_input, params: LIFParams):
+    """Software-reference current-based LIF step (float32).
+
+    Args:
+      state: {'v': (..., N), 'i': (..., N)} float32 membrane potential and
+        synaptic current from the previous step.
+      syn_input: (..., N) float32 accumulated synaptic input this step.
+    Returns:
+      (new_state, spikes float32 in {0,1})
+    """
+    i = state["i"] * (1.0 - params.syn_decay_rate) + syn_input
+    v_out, spikes = fire_reset(state["v"] * params.beta + i,
+                               jnp.float32(params.threshold),
+                               params.reset_mode)
+    return {"v": v_out, "i": i}, spikes
+
+
+def cuba_step_fixed(state, syn_input_raw, params: LIFParams):
+    """Hardware-model current-based LIF step (bit-exact int32, shift decays).
+
+    The plain reference of the two-state neuron: straight int32 ``jnp``,
+    no kernel, no masking, no batching of its own.
+
+    Args:
+      state: {'v': (..., N), 'i': (..., N)} int32 raw fixed point.
+      syn_input_raw: (..., N) int32 accumulated weights this step.
+      params: LIFParams; ``decay_rate`` and ``syn_decay_rate`` must be
+        hardware rates.
+    Returns:
+      (new_state, spikes int32 in {0,1})
+    """
+    # hardware adders wrap; jnp int32 adds wrap too
+    i = fxp.shift_decay(state["i"], params.syn_decay_rate) + syn_input_raw
+    u = fxp.shift_decay(state["v"], params.decay_rate) + i
+    v_out, spikes = fire_reset(u, jnp.int32(params.threshold_raw),
+                               params.reset_mode)
+    return {"v": v_out, "i": i}, spikes
 
 
 # --------------------------------------------------------------------------

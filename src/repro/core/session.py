@@ -12,8 +12,9 @@ model occupies a contiguous physical cluster range; weights of different
 models occupy disjoint SRAM rows; and ``run_all`` advances every resident
 model in ONE fused SpikeEngine scan over the shared physical array —
 external sources concatenated, one weight image, per-model decoded outputs.
-Models sharing a LIF configuration (decay / threshold / reset — the
-hardware's global config registers) fuse into a single scan; models with
+Models sharing a LIF configuration (decay / threshold / reset, and the
+synaptic current's decay of a current-based neuron — the hardware's
+global config registers) fuse into a single scan; models with
 different configurations form separate fused groups, mirroring the ASIC's
 per-configuration register banks. Isolation (a model's outputs are
 bit-identical to a solo deployment) is verified by tests/test_session.py.
@@ -209,9 +210,11 @@ class AcceleratorSession:
     # ------------------------------------------------------------------
     @staticmethod
     def _lif_signature(program: cerebra_h.CerebraHProgram):
-        """The global accelerator config a fused step must share."""
+        """The global accelerator config a fused step must share: a LIF
+        model (no synaptic current, ``None`` last) and a current-based
+        one never fuse into one engine."""
         return (program.decay_rate, program.params.threshold_raw,
-                program.params.reset_mode)
+                program.params.reset_mode, program.syn_decay_rate)
 
     def _fused_engine(self, members: list[DeployedModel]) -> SpikeEngine:
         """One physical-array engine over the union of members' programs.
@@ -222,6 +225,7 @@ class AcceleratorSession:
         IS the union SRAM image the hardware holds.
         """
         sig = self._lif_signature(members[0].program)
+        decay_rate, threshold_raw, reset_mode, _ = sig
         key = (tuple(m.name for m in members), sig, self.backend, self.mesh,
                self.fuse_steps)
         engine = self._fused_engines.get(key)
@@ -238,11 +242,11 @@ class AcceleratorSession:
             W = W.at[off:off + n_in].set(flat[:n_in])
             W = W.at[n_ext:].add(flat[n_in:])
             off += n_in
-        decay_rate, threshold_raw, reset_mode = sig
         engine = SpikeEngine(
             W,
             n_ext,
             decay=DecaySpec.shift(decay_rate),
+            syn_decay=cerebra_h.syn_decay_spec(members[0].program),
             threshold_raw=threshold_raw,
             reset_mode=reset_mode,
             backend=self.backend,

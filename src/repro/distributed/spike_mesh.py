@@ -195,11 +195,12 @@ class MeshSpikeEngine(SpikeEngine):
     def __init__(self, weights_raw, n_inputs: int, *, mesh: Mesh,
                  decay, threshold_raw: int, reset_mode: str,
                  backend: str = "reference", interpret: bool | None = None,
-                 gate: str = "batch-tile", fuse_steps: int = 1):
+                 gate: str = "batch-tile", fuse_steps: int = 1,
+                 syn_decay=None):
         super().__init__(
             weights_raw, n_inputs, decay=decay, threshold_raw=threshold_raw,
             reset_mode=reset_mode, backend=backend, interpret=interpret,
-            gate=gate, fuse_steps=fuse_steps,
+            gate=gate, fuse_steps=fuse_steps, syn_decay=syn_decay,
         )
         missing = {NEURON_AXIS, BATCH_AXIS} - set(mesh.axis_names)
         if missing:
@@ -229,26 +230,17 @@ class MeshSpikeEngine(SpikeEngine):
     def from_engine(cls, engine: SpikeEngine, mesh: Mesh
                     ) -> "MeshSpikeEngine":
         """Re-host an existing engine's program on a mesh (same semantics)."""
-        return cls(
-            engine.weights_raw, engine.n_inputs, mesh=mesh,
-            decay=engine.decay, threshold_raw=engine.threshold_raw,
-            reset_mode=engine.reset_mode, backend=engine.backend,
-            interpret=engine.interpret, gate=engine.gate,
-            fuse_steps=engine.fuse_steps,
-        )
+        return cls(engine.weights_raw, engine.n_inputs, mesh=mesh,
+                   **engine._program())
 
     def with_gate(self, gate: str) -> "MeshSpikeEngine":
         """Gate re-host that KEEPS the mesh (the base implementation would
         silently fall back to a single-device engine)."""
         if gate == self.gate:
             return self
-        return MeshSpikeEngine(
-            self.weights_raw, self.n_inputs, mesh=self.mesh,
-            decay=self.decay, threshold_raw=self.threshold_raw,
-            reset_mode=self.reset_mode, backend=self.backend,
-            interpret=self.interpret, gate=gate,
-            fuse_steps=self.fuse_steps,
-        )
+        return MeshSpikeEngine(self.weights_raw, self.n_inputs,
+                               mesh=self.mesh,
+                               **dict(self._program(), gate=gate))
 
     def with_fuse_steps(self, fuse_steps: int) -> "MeshSpikeEngine":
         """Fusion re-host that KEEPS the mesh (the base implementation
@@ -257,11 +249,7 @@ class MeshSpikeEngine(SpikeEngine):
             return self
         return MeshSpikeEngine(
             self.weights_raw, self.n_inputs, mesh=self.mesh,
-            decay=self.decay, threshold_raw=self.threshold_raw,
-            reset_mode=self.reset_mode, backend=self.backend,
-            interpret=self.interpret, gate=self.gate,
-            fuse_steps=fuse_steps,
-        )
+            **dict(self._program(), fuse_steps=fuse_steps))
 
     @property
     def device_count(self) -> int:
@@ -283,7 +271,8 @@ class MeshSpikeEngine(SpikeEngine):
                           self.mesh, SNN_RULES)
         active = spec_for(("time", "batch"), (steps, batch_padded),
                           self.mesh, SNN_RULES)
-        cdict = {"v": carry, "spikes": carry}
+        # every carry state is column-sharded like v (the engine's keys)
+        cdict = dict.fromkeys(self.carry_keys, carry)
         return cdict, ext, raster, active
 
     def step(self, carry, ext_t):
@@ -305,20 +294,15 @@ class MeshSpikeEngine(SpikeEngine):
         spikes_full = jax.lax.all_gather(
             carry_local["spikes"], NEURON_AXIS, axis=1, tiled=True)
         return self._step(
-            weights_local,
-            {"v": carry_local["v"], "spikes": spikes_full},
-            ext_t,
-        )
+            weights_local, dict(carry_local, spikes=spikes_full), ext_t)
 
     # ------------------------------------------------------------------
     def _run_impl(self, weights, ext_spikes):
         T, B = ext_spikes.shape[0], ext_spikes.shape[1]
         bp = _pad_up(B, self._kb)
         ext_p = jnp.pad(ext_spikes, ((0, 0), (0, bp - B), (0, 0)))
-        carry = {
-            "v": jnp.zeros((bp, self._pp), jnp.int32),
-            "spikes": jnp.zeros((bp, self._pp), jnp.int32),
-        }
+        carry = {k: jnp.zeros((bp, self._pp), jnp.int32)
+                 for k in self.carry_keys}
         cspec, espec, rspec, _ = self._specs(bp, T)
 
         def local(weights_l, carry_l, ext_l):
@@ -331,10 +315,13 @@ class MeshSpikeEngine(SpikeEngine):
             out_specs=(cspec, rspec),
             check_vma=False,
         )(weights, carry, ext_p)
-        return {
+        out = {
             "spikes": spikes[:, :B, : self.n_phys],
             "v_final": final["v"][:B, : self.n_phys],
         }
+        if self.has_current:
+            out["i_final"] = final["i"][:B, : self.n_phys]
+        return out
 
     # ------------------------------------------------------------------
     def _chunk_impl(self, weights, carry, ext, active):
@@ -343,10 +330,7 @@ class MeshSpikeEngine(SpikeEngine):
         ext_p = jnp.pad(ext, ((0, 0), (0, bp - B), (0, 0)))
         active_p = jnp.pad(active, ((0, 0), (0, bp - B)))  # pad slots idle
         pad2 = ((0, bp - B), (0, self._pp - self.n_phys))
-        carry_p = {
-            "v": jnp.pad(carry["v"], pad2),
-            "spikes": jnp.pad(carry["spikes"], pad2),
-        }
+        carry_p = {k: jnp.pad(x, pad2) for k, x in carry.items()}
         cspec, espec, rspec, aspec = self._specs(bp, T)
 
         def local(weights_l, carry_l, ext_l, active_l):
@@ -359,8 +343,5 @@ class MeshSpikeEngine(SpikeEngine):
             out_specs=(cspec, rspec),
             check_vma=False,
         )(weights, carry_p, ext_p, active_p)
-        final = {
-            "v": final["v"][:B, : self.n_phys],
-            "spikes": final["spikes"][:B, : self.n_phys],
-        }
+        final = {k: x[:B, : self.n_phys] for k, x in final.items()}
         return final, spikes[:, :B, : self.n_phys]

@@ -18,7 +18,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["DECAY_KINDS", "SHIFT_RATES", "validate_decay", "decay_and_fire"]
+__all__ = ["DECAY_KINDS", "SHIFT_RATES", "validate_decay", "apply_decay",
+           "decay_and_fire"]
 
 # "shift" — Cerebra-H arithmetic-shift decay, rate in {.125,.25,.5,.75}.
 # "mul"   — Cerebra-S truncating fixed-point multiply by a raw Q16.16
@@ -56,23 +57,42 @@ def validate_decay(decay_kind: str, decay_rate: float, decay_raw: int):
         )
 
 
+def apply_decay(x, decay_kind: str, decay_rate: float, decay_raw: int):
+    """One Potential-Decay Unit on raw int32 state: the shift PDU at
+    ``decay_rate`` (``'shift'``) or the truncating multiply by the Q16.16
+    retain factor ``decay_raw`` (``'mul'``)."""
+    from repro.core import fixedpoint as fxp
+
+    if decay_kind == "shift":
+        return fxp.shift_decay(x, decay_rate)
+    if decay_kind == "mul":
+        return fxp.fx_mul(x, jnp.int32(decay_raw))
+    raise ValueError(
+        f"unknown decay kind {decay_kind!r}; expected one of {DECAY_KINDS}"
+    )
+
+
 def decay_and_fire(v, acc, *, decay_kind: str, decay_rate: float,
-                   decay_raw: int, threshold_raw: int, reset_mode: str):
+                   decay_raw: int, threshold_raw: int, reset_mode: str,
+                   i=None, syn_decay: tuple | None = None):
     """Decay previous potential, integrate, fire, reset. All int32.
 
     Pure jnp ops only (shifts, bitwise, wrapping adds) so it traces inside
     Pallas kernel bodies and inside plain jitted scan bodies alike.
     Returns (v_out, spikes) int32.
+
+    With a synaptic current ``i`` and its decay ``syn_decay`` (a ``(kind,
+    rate, raw)`` triple, as for the membrane), the accumulate adds to the
+    decayed current and the membrane integrates the new current:
+    returns (v_out, spikes, i_out).
     """
-    from repro.core import fixedpoint as fxp
     from repro.core.lif import fire_reset
 
-    if decay_kind == "shift":
-        v_decayed = fxp.shift_decay(v, decay_rate)
-    elif decay_kind == "mul":
-        v_decayed = fxp.fx_mul(v, jnp.int32(decay_raw))
-    else:
-        raise ValueError(
-            f"unknown decay kind {decay_kind!r}; expected one of {DECAY_KINDS}"
-        )
-    return fire_reset(v_decayed + acc, jnp.int32(threshold_raw), reset_mode)
+    if i is not None:
+        acc = apply_decay(i, *syn_decay) + acc
+    v_out, spikes = fire_reset(
+        apply_decay(v, decay_kind, decay_rate, decay_raw) + acc,
+        jnp.int32(threshold_raw), reset_mode)
+    if i is None:
+        return v_out, spikes
+    return v_out, spikes, acc

@@ -74,12 +74,13 @@ def lif_step(v, syn, *, decay_rate: float, threshold_raw: int,
 @functools.partial(
     jax.jit,
     static_argnames=("decay_rate", "threshold_raw", "reset_mode",
-                     "decay_kind", "decay_raw",
+                     "decay_kind", "decay_raw", "syn_decay",
                      "use_mxu", "block_batch", "block_src", "interpret"),
 )
-def spike_timestep(sources, weights, v, *, decay_rate: float = 0.0,
+def spike_timestep(sources, weights, v, i=None, *, decay_rate: float = 0.0,
                    threshold_raw: int, reset_mode: str = "zero",
                    decay_kind: str = "shift", decay_raw: int = 0,
+                   syn_decay: tuple | None = None,
                    use_mxu: bool = False, block_batch: int = 8,
                    block_src: int = 128, interpret: bool | None = None):
     """One fused, event-gated accelerator timestep.
@@ -90,6 +91,10 @@ def spike_timestep(sources, weights, v, *, decay_rate: float = 0.0,
     ``decay_kind='shift'`` (default) applies the Cerebra-H shift decay of
     ``decay_rate``; ``decay_kind='mul'`` applies the Cerebra-S fixed-point
     multiply by the raw Q16.16 retain factor ``decay_raw``.
+
+    The current-based neuron passes its synaptic current ``i`` (B, P)
+    and ``syn_decay`` (a ``(kind, rate, raw)`` triple) and gets
+    ``(v_out, spikes_out, i_out)`` back.
 
     ``use_mxu=False`` (default) is bit-exact. ``use_mxu=True`` runs the
     accumulate on the MXU in f32 — exact only while per-output partial sums
@@ -103,7 +108,8 @@ def spike_timestep(sources, weights, v, *, decay_rate: float = 0.0,
     sources = sources.astype(jnp.int32)
     src_p = _pad_to(_pad_to(sources, 0, block_batch), 1, block_src)
     w_p = _pad_to(_pad_to(weights, 0, block_src), 1, 128)
-    v_p = _pad_to(_pad_to(v, 0, block_batch), 1, 128)
+    carries = [_pad_to(_pad_to(x, 0, block_batch), 1, 128)
+               for x in ((v,) if i is None else (v, i))]
     Bp, Sp = src_p.shape
     Pp = w_p.shape[1]
     nb, ns = Bp // block_batch, Sp // block_src
@@ -135,12 +141,18 @@ def spike_timestep(sources, weights, v, *, decay_rate: float = 0.0,
         block_src=block_src,
         use_mxu=use_mxu,
         interpret=interpret,
+        syn_decay=syn_decay,
     )
-    v_out, spikes = fn(activity, src_p, w_p, v_p)
-    return v_out[:B, :P], spikes[:B, :P]
+    return tuple(x[:B, :P] for x in fn(activity, src_p, w_p, *carries))
 
 
 # --------------------------------------------------------------------------
+def _pad_carry(x, block_batch, block_src):
+    """A (B, P) carry padded to the fused kernel's (Bp, Pp)."""
+    x = _pad_to(_pad_to(x, 0, block_batch), 1, 128)
+    return _pad_to(x, 1, block_src)
+
+
 def _fused_pad(ext, spikes_prev, weights, v, active, *, n_inputs,
                block_batch, block_src):
     """Pad every fused-kernel operand to its block multiples.
@@ -157,10 +169,8 @@ def _fused_pad(ext, spikes_prev, weights, v, active, *, n_inputs,
     w_rec = weights[n_inputs:]
     ext_p = _pad_to(_pad_to(ext.astype(jnp.int32), 1, block_batch),
                     2, block_src)
-    v_p = _pad_to(_pad_to(v, 0, block_batch), 1, 128)
-    v_p = _pad_to(v_p, 1, block_src)
-    spk_p = _pad_to(_pad_to(spikes_prev, 0, block_batch), 1, 128)
-    spk_p = _pad_to(spk_p, 1, block_src)
+    v_p = _pad_carry(v, block_batch, block_src)
+    spk_p = _pad_carry(spikes_prev, block_batch, block_src)
     act_p = _pad_to(active.astype(jnp.int32), 1, block_batch)
     Pp = v_p.shape[1]
     w_ext_p = _pad_to(_pad_to(w_ext, 0, block_src), 1, 128)
@@ -177,13 +187,14 @@ def _fused_pad(ext, spikes_prev, weights, v, active, *, n_inputs,
 @functools.partial(
     jax.jit,
     static_argnames=("n_inputs", "decay_rate", "threshold_raw",
-                     "reset_mode", "decay_kind", "decay_raw",
+                     "reset_mode", "decay_kind", "decay_raw", "syn_decay",
                      "use_mxu", "block_batch", "block_src", "interpret"),
 )
-def spike_timestep_fused(ext, spikes_prev, weights, v, active, *,
+def spike_timestep_fused(ext, spikes_prev, weights, v, active, i=None, *,
                          n_inputs: int, decay_rate: float = 0.0,
                          threshold_raw: int, reset_mode: str = "zero",
                          decay_kind: str = "shift", decay_raw: int = 0,
+                         syn_decay: tuple | None = None,
                          use_mxu: bool = False, block_batch: int = 8,
                          block_src: int = 128,
                          interpret: bool | None = None):
@@ -204,6 +215,10 @@ def spike_timestep_fused(ext, spikes_prev, weights, v, active, *,
     single-step kernel. The ``use_mxu`` 2^24 exactness bound is unchanged
     by K (the window stacks along the dot's batch axis, never its
     reduction axis); see :func:`repro.core.engine.mxu_partial_sum_bound`.
+
+    The current-based neuron passes its synaptic current ``i`` (B, P) and
+    ``syn_decay`` (a ``(kind, rate, raw)`` triple): the kernel is then
+    ``spike_timestep_fused_syn`` and ``i_out`` is returned last.
     """
     interpret = on_cpu() if interpret is None else interpret
     K = ext.shape[0]
@@ -231,10 +246,13 @@ def spike_timestep_fused(ext, spikes_prev, weights, v, active, *,
         block_src=block_src,
         use_mxu=use_mxu,
         interpret=interpret,
+        syn_decay=syn_decay,
     )
-    v_out, spk_carry, raster = fn(
-        activity, ext_p, w_ext_p, w_rec_p, v_p, spk_p, act_p)
-    return v_out[:B, :P], spk_carry[:B, :P], raster[:, :B, :P]
+    i_p = () if i is None else (_pad_carry(i, block_batch, block_src),)
+    v_out, spk_carry, raster, *i_out = fn(
+        activity, ext_p, w_ext_p, w_rec_p, v_p, spk_p, act_p, *i_p)
+    return (v_out[:B, :P], spk_carry[:B, :P], raster[:, :B, :P],
+            *(x[:B, :P] for x in i_out))
 
 
 def ext_gate_activity(ext, *, block_batch: int = 8, block_src: int = 128,

@@ -52,7 +52,9 @@ from repro.kernels.epilogue import decay_and_fire, validate_decay
 __all__ = [
     "spike_timestep_kernel",
     "build_spike_timestep",
+    "spike_timestep_syn_kernel",
     "spike_timestep_fused_kernel",
+    "spike_timestep_fused_syn_kernel",
     "build_spike_timestep_fused",
 ]
 
@@ -97,24 +99,12 @@ def _exact_dot(src, w, use_mxu: bool):
     return acc
 
 
-def spike_timestep_kernel(
-    act_ref,      # scalar-prefetch: (nb, ns) int32 block activity
-    fetch_ref,    # scalar-prefetch: (nb, ns) int32 weight block to hold
-    src_ref,      # (1, Bb, Sb) int32 spikes
-    w_ref,        # (Sb, P) int32 SRAM image block
-    v_ref,        # (1, Bb, P) int32 membrane potential
-    vout_ref,     # (1, Bb, P) int32
-    spk_ref,      # (1, Bb, P) int32
-    acc_ref,      # scratch (Bb, P) int32
-    *,
-    decay_kind: str,
-    decay_rate: float,
-    decay_raw: int,
-    threshold_raw: int,
-    reset_mode: str,
-    use_mxu: bool,
-):
-    del fetch_ref  # consumed by the weight index map only
+def _timestep_body(act_ref, src_ref, w_ref, v_ref, i_ref, vout_ref,
+                   spk_ref, iout_ref, acc_ref, *, decay_kind: str,
+                   decay_rate: float, decay_raw: int, threshold_raw: int,
+                   reset_mode: str, use_mxu: bool, syn_decay):
+    """The single-step body; ``i_ref``/``iout_ref`` are the synaptic
+    current's carry (None for the one-state neuron)."""
     b = pl.program_id(0)
     s = pl.program_id(1)
     ns = pl.num_programs(1)
@@ -129,14 +119,46 @@ def spike_timestep_kernel(
 
     @pl.when(s == ns - 1)  # LIF epilogue once accumulation is complete
     def _fire():
-        vout, spikes = decay_and_fire(
+        out = decay_and_fire(
             v_ref[0], acc_ref[...],
             decay_kind=decay_kind, decay_rate=decay_rate,
             decay_raw=decay_raw, threshold_raw=threshold_raw,
             reset_mode=reset_mode,
+            i=None if i_ref is None else i_ref[0], syn_decay=syn_decay,
         )
-        vout_ref[0] = vout
-        spk_ref[0] = spikes
+        vout_ref[0] = out[0]
+        spk_ref[0] = out[1]
+        if iout_ref is not None:
+            iout_ref[0] = out[2]
+
+
+def spike_timestep_kernel(
+    act_ref,      # scalar-prefetch: (nb, ns) int32 block activity
+    fetch_ref,    # scalar-prefetch: (nb, ns) int32 weight block to hold
+    src_ref,      # (1, Bb, Sb) int32 spikes
+    w_ref,        # (Sb, P) int32 SRAM image block
+    v_ref,        # (1, Bb, P) int32 membrane potential
+    vout_ref,     # (1, Bb, P) int32
+    spk_ref,      # (1, Bb, P) int32
+    acc_ref,      # scratch (Bb, P) int32
+    **params,
+):
+    del fetch_ref  # consumed by the weight index map only
+    _timestep_body(act_ref, src_ref, w_ref, v_ref, None, vout_ref, spk_ref,
+                   None, acc_ref, syn_decay=None, **params)
+
+
+def spike_timestep_syn_kernel(
+    act_ref, fetch_ref, src_ref, w_ref, v_ref,
+    i_ref,        # (1, Bb, P) int32 synaptic current
+    vout_ref, spk_ref,
+    iout_ref,     # (1, Bb, P) int32
+    acc_ref, *, syn_decay, **params,
+):
+    """:func:`spike_timestep_kernel` for the current-based neuron."""
+    del fetch_ref
+    _timestep_body(act_ref, src_ref, w_ref, v_ref, i_ref, vout_ref, spk_ref,
+                   iout_ref, acc_ref, syn_decay=syn_decay, **params)
 
 
 def build_spike_timestep(
@@ -153,12 +175,18 @@ def build_spike_timestep(
     block_src: int = 128,
     use_mxu: bool = False,
     interpret: bool = False,
+    syn_decay: tuple | None = None,
 ):
     """Build fn(activity, sources, weights, v) -> (v_out, spikes).
 
     ``decay_kind='shift'`` uses the Cerebra-H shift decay (``decay_rate``);
     ``decay_kind='mul'`` uses the Cerebra-S fixed-point multiply by the raw
     Q16.16 retain factor ``decay_raw``.
+
+    ``syn_decay`` (a ``(kind, rate, raw)`` triple) builds the
+    current-based neuron's variant instead, named
+    ``spike_timestep_syn``: fn(activity, sources, weights, v, i)
+    -> (v_out, spikes, i_out).
 
     Shapes (pre-padded by ops.py):
       activity: (batch//block_batch, n_sources//block_src) int32
@@ -167,6 +195,9 @@ def build_spike_timestep(
       v:        (batch, n_phys) int32
     """
     validate_decay(decay_kind, decay_rate, decay_raw)
+    syn = syn_decay is not None
+    if syn:
+        validate_decay(*syn_decay)
     if batch % block_batch or n_sources % block_src:
         raise ValueError("shapes must be pre-padded to block multiples")
     if n_phys % 128:
@@ -174,7 +205,8 @@ def build_spike_timestep(
     nb = batch // block_batch
     ns = n_sources // block_src
     kernel = functools.partial(
-        spike_timestep_kernel,
+        spike_timestep_syn_kernel if syn else spike_timestep_kernel,
+        **({"syn_decay": syn_decay} if syn else {}),
         decay_kind=decay_kind,
         decay_rate=decay_rate,
         decay_raw=decay_raw,
@@ -183,6 +215,8 @@ def build_spike_timestep(
         use_mxu=use_mxu,
     )
     tile = (1, block_batch, n_phys)
+    tile_spec = pl.BlockSpec(tile, lambda b, s, act, fetch: (b, 0, 0))
+    n_carry = 2 if syn else 1  # v, and the current's carry
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb, ns),
@@ -191,29 +225,25 @@ def build_spike_timestep(
                          lambda b, s, act, fetch: (b, 0, s)),
             pl.BlockSpec((block_src, n_phys),
                          lambda b, s, act, fetch: (fetch[b, s], 0)),
-            pl.BlockSpec(tile, lambda b, s, act, fetch: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(tile, lambda b, s, act, fetch: (b, 0, 0)),
-            pl.BlockSpec(tile, lambda b, s, act, fetch: (b, 0, 0)),
-        ],
+        ] + [tile_spec] * n_carry,
+        out_specs=[tile_spec] * (n_carry + 1),
         scratch_shapes=[pltpu.VMEM((block_batch, n_phys), jnp.int32)],
     )
     out = jax.ShapeDtypeStruct((nb, block_batch, n_phys), jnp.int32)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[out, out],
+        out_shape=[out] * (n_carry + 1),
         interpret=interpret,
-        name="spike_timestep",
+        name="spike_timestep_syn" if syn else "spike_timestep",
     )
 
-    def fn(activity, sources, weights, v):
-        v_out, spikes = call(
+    def fn(activity, sources, weights, v, *i):
+        outs = call(
             activity, _fetch_blocks(activity),
             sources.reshape(nb, block_batch, n_sources), weights,
-            v.reshape(nb, block_batch, n_phys))
-        return v_out.reshape(batch, n_phys), spikes.reshape(batch, n_phys)
+            *(x.reshape(nb, block_batch, n_phys) for x in (v, *i)))
+        return tuple(x.reshape(batch, n_phys) for x in outs)
 
     return fn
 
@@ -277,6 +307,32 @@ def spike_timestep_fused_kernel(
     acc_ref,      # scratch VMEM (K, Bb, P) int32 external accumulator
     sched_ref,    # scratch SMEM (ns_ext,) int32 active-block schedule
     sem,          # DMA semaphores (2,)
+    **params,
+):
+    _fused_body(act_ref, ext_ref, wext_ref, wrec_ref, v_ref, spk0_ref,
+                active_ref, None, vout_ref, spkc_ref, rast_ref, None, wbuf,
+                acc_ref, sched_ref, sem, syn_decay=None, **params)
+
+
+def spike_timestep_fused_syn_kernel(
+    act_ref, ext_ref, wext_ref, wrec_ref, v_ref, spk0_ref, active_ref,
+    i_ref,        # (1, Bb, P) int32 synaptic current at window entry
+    vout_ref, spkc_ref, rast_ref,
+    iout_ref,     # (1, Bb, P) int32 synaptic current at window exit
+    wbuf, acc_ref, sched_ref, sem, *, syn_decay, **params,
+):
+    """:func:`spike_timestep_fused_kernel` for the current-based neuron:
+    the current is one more carry, held across the K steps as ``v`` is."""
+    _fused_body(act_ref, ext_ref, wext_ref, wrec_ref, v_ref, spk0_ref,
+                active_ref, i_ref, vout_ref, spkc_ref, rast_ref, iout_ref,
+                wbuf, acc_ref, sched_ref, sem, syn_decay=syn_decay,
+                **params)
+
+
+def _fused_body(
+    act_ref, ext_ref, wext_ref, wrec_ref, v_ref, spk0_ref, active_ref,
+    i_ref, vout_ref, spkc_ref, rast_ref, iout_ref, wbuf, acc_ref,
+    sched_ref, sem,
     *,
     fuse_steps: int,
     block_src: int,
@@ -286,6 +342,7 @@ def spike_timestep_fused_kernel(
     threshold_raw: int,
     reset_mode: str,
     use_mxu: bool,
+    syn_decay,
 ):
     b = pl.program_id(0)
     K = fuse_steps
@@ -344,9 +401,12 @@ def spike_timestep_fused_kernel(
     jax.lax.fori_loop(0, n_active, _consume, 0)
 
     # ---- phase C: K per-step recurrences + LIF epilogues on the resident
-    # recurrent image. vout/spkc double as the in-flight carry registers.
+    # recurrent image. vout/spkc (and iout) double as the in-flight carry
+    # registers.
     vout_ref[...] = v_ref[...]
     spkc_ref[...] = spk0_ref[...]
+    if iout_ref is not None:
+        iout_ref[...] = i_ref[...]
 
     def _step(k, _):
         spk_prev = spkc_ref[0]
@@ -358,16 +418,20 @@ def spike_timestep_fused_kernel(
             syn = syn + _exact_dot(
                 spk_prev[:, c:c + block_src],
                 wrec_ref[c:c + block_src, :], use_mxu)
-        v_new, s_new = decay_and_fire(
+        out = decay_and_fire(
             vout_ref[0], syn,
             decay_kind=decay_kind, decay_rate=decay_rate,
             decay_raw=decay_raw, threshold_raw=threshold_raw,
             reset_mode=reset_mode,
+            i=None if iout_ref is None else iout_ref[0], syn_decay=syn_decay,
         )
+        v_new, s_new = out[0], out[1]
         # masked-slot contract (== SpikeEngine._masked_chunk_scan): an
         # inactive (step, example) keeps its carry and emits zero spikes.
         keep = active_ref[0, k] != 0  # (Bb, 1)
         vout_ref[0] = jnp.where(keep, v_new, vout_ref[0])
+        if iout_ref is not None:
+            iout_ref[0] = jnp.where(keep, out[2], iout_ref[0])
         rast_ref[k, 0] = jnp.where(keep, s_new, 0)
         spkc_ref[0] = jnp.where(keep, s_new, spk_prev)
         return 0
@@ -390,6 +454,7 @@ def build_spike_timestep_fused(
     block_src: int = 128,
     use_mxu: bool = False,
     interpret: bool = False,
+    syn_decay: tuple | None = None,
 ):
     """Build the K-step fused timestep:
     ``fn(activity, ext, w_ext, w_rec, v, spikes_prev, active)
@@ -405,8 +470,16 @@ def build_spike_timestep_fused(
       active:     (fuse_steps, batch) int32 per-(step, example) mask
     Returns v/spikes carries at window exit plus the
     (fuse_steps, batch, n_phys) emitted raster.
+
+    ``syn_decay`` (a ``(kind, rate, raw)`` triple) builds the
+    current-based neuron's variant, named ``spike_timestep_fused_syn``:
+    the synaptic current ``i`` (batch, n_phys) is one more carry, operand
+    after ``active`` and output after the raster.
     """
     validate_decay(decay_kind, decay_rate, decay_raw)
+    syn = syn_decay is not None
+    if syn:
+        validate_decay(*syn_decay)
     if fuse_steps < 1:
         raise ValueError(f"fuse_steps must be >= 1, got {fuse_steps}")
     if batch % block_batch or n_ext % block_src:
@@ -420,7 +493,9 @@ def build_spike_timestep_fused(
     ns_ext = n_ext // block_src
     groups = -(-ns_ext // LANE_BITS)
     kernel = functools.partial(
-        spike_timestep_fused_kernel,
+        spike_timestep_fused_syn_kernel if syn
+        else spike_timestep_fused_kernel,
+        **({"syn_decay": syn_decay} if syn else {}),
         fuse_steps=fuse_steps,
         block_src=block_src,
         decay_kind=decay_kind,
@@ -431,6 +506,9 @@ def build_spike_timestep_fused(
         use_mxu=use_mxu,
     )
     tile = (1, block_batch, n_phys)
+    # the current's carry, in and out, tiled as v is
+    syn_specs = ([pl.BlockSpec(tile, lambda b, act: (b, 0, 0))]
+                 if syn else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
@@ -445,13 +523,13 @@ def build_spike_timestep_fused(
             pl.BlockSpec(tile, lambda b, act: (b, 0, 0)),
             pl.BlockSpec((1, fuse_steps, block_batch, 1),
                          lambda b, act: (b, 0, 0, 0)),
-        ],
+        ] + syn_specs,
         out_specs=[
             pl.BlockSpec(tile, lambda b, act: (b, 0, 0)),
             pl.BlockSpec(tile, lambda b, act: (b, 0, 0)),
             pl.BlockSpec((fuse_steps, 1, block_batch, n_phys),
                          lambda b, act: (0, b, 0, 0)),
-        ],
+        ] + syn_specs,
         scratch_shapes=[
             pltpu.VMEM((2, block_src, n_phys), jnp.int32),
             pltpu.VMEM((fuse_steps, block_batch, n_phys), jnp.int32),
@@ -468,13 +546,13 @@ def build_spike_timestep_fused(
             carry,
             jax.ShapeDtypeStruct((fuse_steps, nb, block_batch, n_phys),
                                  jnp.int32),
-        ],
+        ] + [carry] * len(syn_specs),
         interpret=interpret,
-        name="spike_timestep_fused",
+        name="spike_timestep_fused_syn" if syn else "spike_timestep_fused",
     )
     tiled = (nb, block_batch, n_phys)
 
-    def fn(activity, ext, w_ext, w_rec, v, spikes_prev, active):
+    def fn(activity, ext, w_ext, w_rec, v, spikes_prev, active, *i):
         # (G, K, batch, Sb) words -> per tile, rows step-major
         planes = (pack_block_planes(ext, block_src)
                   .reshape(groups, fuse_steps, nb, block_batch, block_src)
@@ -482,11 +560,13 @@ def build_spike_timestep_fused(
                   .reshape(groups, nb, fuse_steps * block_batch, block_src))
         act = (active.reshape(fuse_steps, nb, block_batch)
                .transpose(1, 0, 2)[..., None])
-        v_out, spk_carry, raster = call(
+        v_out, spk_carry, raster, *i_out = call(
             activity, planes, w_ext, w_rec, v.reshape(tiled),
-            spikes_prev.reshape(tiled), act)
+            spikes_prev.reshape(tiled), act,
+            *(x.reshape(tiled) for x in i))
         return (v_out.reshape(batch, n_phys),
                 spk_carry.reshape(batch, n_phys),
-                raster.reshape(fuse_steps, batch, n_phys))
+                raster.reshape(fuse_steps, batch, n_phys),
+                *(x.reshape(batch, n_phys) for x in i_out))
 
     return fn

@@ -93,6 +93,10 @@ METRIC_SPECS: dict[str, MetricSpec] = _specs(
                "Slots currently bound to attached streams."),
     MetricSpec("snn_server_slots_total", "gauge",
                "Configured slot count of the server (n_slots)."),
+    MetricSpec("snn_server_carry_bytes", "gauge",
+               "Device bytes of the server's slot carry, per neuron "
+               "state (v, spikes, and i for current-based neurons); set "
+               "when the server is built.", labels=("state",)),
     MetricSpec("snn_server_steps_total", "counter",
                "Active (slot, timestep) pairs consumed — masked-out "
                "slot steps are not counted."),

@@ -84,9 +84,14 @@ def slot_params_of(engine) -> dict:
     future. Everything else — backend, gate, ``fuse_steps``, mesh, input
     width, co-residents — is a *hosting* choice the engine's byte-identity
     contracts already quotient out, and is deliberately absent here.
+
+    A current-based engine adds its synaptic current's decay
+    (``syn_decay_kind``, ``syn_decay_rate``, ``syn_decay_raw``); a LIF
+    engine's params, and every snapshot written before the current
+    existed, lack them, and read as LIF.
     """
     decay = engine.decay
-    return {
+    params = {
         "n_phys": int(engine.n_phys),
         "decay_kind": str(decay.kind),
         "decay_rate": float(decay.rate),
@@ -94,6 +99,16 @@ def slot_params_of(engine) -> dict:
         "threshold_raw": int(engine.threshold_raw),
         "reset_mode": str(engine.reset_mode),
     }
+    syn = engine.syn_decay
+    if syn is not None:
+        params.update(syn_decay_kind=str(syn.kind),
+                      syn_decay_rate=float(syn.rate),
+                      syn_decay_raw=int(syn.raw))
+    return params
+
+
+#: slot-param fields of the synaptic current, absent for LIF neurons
+_SYN_FIELDS = ("syn_decay_kind", "syn_decay_rate", "syn_decay_raw")
 
 
 def _key_token(stream_id) -> str:
@@ -107,7 +122,8 @@ class CarrySnapshot:
     """One stream's portable state: carry + counters + compatibility key.
 
     ``arrays`` holds the slot carry — ``'v'`` (membrane potentials) and
-    ``'spikes'`` (last emitted spike vector), each ``(n_phys,)`` int32
+    ``'spikes'`` (last emitted spike vector), and ``'i'`` (the synaptic
+    current) for a current-based engine, each ``(n_phys,)`` int32
     under the carry contract (the wire format itself is generic over
     dtype/shape; :meth:`check_compatible` enforces the contract at
     restore). ``meta`` carries the stream's counters (``steps``,
@@ -213,16 +229,27 @@ class CarrySnapshot:
         """Raise ``ValueError`` naming the first field on which this
         snapshot cannot restore into a slot with ``params`` (see
         :func:`slot_params_of`), or on a carry array with the wrong
-        dtype/shape for the target."""
+        dtype/shape for the target. A LIF snapshot cannot restore onto a
+        current-based slot, nor the reverse: the synaptic current's
+        decay is compared like the others, and the carry holds ``'i'``
+        exactly when the target has a current."""
         for field in ("n_phys", "decay_kind", "decay_rate", "decay_raw",
-                      "threshold_raw", "reset_mode"):
-            if self.slot_params.get(field) != params[field]:
+                      "threshold_raw", "reset_mode") + _SYN_FIELDS:
+            if self.slot_params.get(field) != params.get(field):
                 raise ValueError(
                     f"carry snapshot for stream {self.stream_id!r} is "
                     f"incompatible: {field}="
-                    f"{self.slot_params.get(field)!r} != {params[field]!r}")
+                    f"{self.slot_params.get(field)!r} != "
+                    f"{params.get(field)!r}")
         n_phys = params["n_phys"]
-        for name in ("v", "spikes"):
+        names = ("v", "spikes")
+        if params.get("syn_decay_kind") is not None:
+            names += ("i",)
+        if "i" in self.arrays and "i" not in names:
+            raise ValueError(
+                f"carry snapshot for stream {self.stream_id!r} carries "
+                f"a synaptic current, but the target has none")
+        for name in names:
             arr = self.arrays.get(name)
             if arr is None:
                 raise ValueError(

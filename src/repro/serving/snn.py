@@ -11,7 +11,8 @@ the continuous-batching idiom):
     batch slots: FIFO waiting queue, FIFO slot reuse, no double
     assignment. Pure bookkeeping; property-tested.
   * :class:`SpikeServer` — owns the persistent slot carry
-    ``{v, spikes}`` (via ``SpikeEngine.init_carry``), chunked
+    ``{v, spikes}``, plus the synaptic current ``i`` of a current-based
+    engine (via ``SpikeEngine.init_carry``), chunked
     :meth:`~SpikeServer.feed` (push N timesteps of external spikes per
     stream, get the spike raster / counts back), carry zeroing on
     eviction, and a closed-loop mode where the decoded output of step t
@@ -266,6 +267,9 @@ class SpikeServer:
                 (self.n_slots, engine.n_phys), np.int32)
             metrics.gauge("snn_server_slots_total").set(self.n_slots)
             metrics.gauge("snn_server_slots_occupied").set(0)
+            for state, x in self.carry.items():
+                metrics.gauge("snn_server_carry_bytes").labels(
+                    state=state).set(int(x.size) * x.dtype.itemsize)
 
     # -- observability ----------------------------------------------------
     def _obs_clock(self):
@@ -497,10 +501,10 @@ class SpikeServer:
         return CarrySnapshot(
             stream_id=uid,
             slot_params=self.slot_params(),
-            arrays={
-                "v": np.asarray(self.carry["v"][slot], np.int32),
-                "spikes": np.asarray(self.carry["spikes"][slot], np.int32),
-            },
+            # every state of the carry: v and spikes, and the synaptic
+            # current i of a current-based engine
+            arrays={k: np.asarray(x[slot], np.int32)
+                    for k, x in self.carry.items()},
             meta={"steps": int(st.steps),
                   "spike_count": int(st.spike_count)},
         )
@@ -559,12 +563,8 @@ class SpikeServer:
             slot = self.scheduler.submit(uid)
         else:
             slot = self.scheduler.submit_at(uid, slot)
-        self.carry = {
-            "v": self.carry["v"].at[slot].set(
-                jnp.asarray(snap.arrays["v"])),
-            "spikes": self.carry["spikes"].at[slot].set(
-                jnp.asarray(snap.arrays["spikes"])),
-        }
+        self.carry = {k: x.at[slot].set(jnp.asarray(snap.arrays[k]))
+                      for k, x in self.carry.items()}
         self.streams[uid] = StreamStats(
             uid=uid,
             steps=int(snap.meta.get("steps", 0)),

@@ -13,6 +13,8 @@ one process may load the TPU library at a time, and a worker that loads
 it while collecting would give the workers different tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +89,46 @@ def test_spike_timestep_fused_compiles_for_v5e(one_chip, use_mxu,
         _shape(one_chip, (S, P)), _shape(one_chip, (B, P)),
         _shape(one_chip, (K, B))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+SYN = ("shift", 0.75, 0)  # the current-based neuron's synaptic decay
+N_IN_SHD = 700
+
+
+@pytest.mark.parametrize("use_mxu,block_batch", KERNELS, ids=KERNEL_IDS)
+def test_spike_timestep_syn_compiles_for_v5e(one_chip, use_mxu, block_batch):
+    """K = 1 for the current-based neuron: the current in and out."""
+    def step(src, w, v, i):
+        return ops.spike_timestep(
+            src, w, v, i, decay_rate=0.5, threshold_raw=THRESH,
+            syn_decay=SYN, use_mxu=use_mxu, block_batch=block_batch,
+            interpret=False)
+
+    s = N_IN_SHD + P
+    compiled = jax.jit(step).lower(
+        _shape(one_chip, (B, s)), _shape(one_chip, (s, P)),
+        _shape(one_chip, (B, P)), _shape(one_chip, (B, P))).compile()
+    assert re.search(r"%spike_timestep_syn(\.\d+)? = ", compiled.as_text())
+
+
+@pytest.mark.parametrize("use_mxu,block_batch", KERNELS, ids=KERNEL_IDS)
+def test_spike_timestep_fused_syn_compiles_for_v5e(one_chip, use_mxu,
+                                                   block_batch):
+    """K = 8 for the current-based neuron at SHD's 700 inputs, under the
+    name the device trace reads it by."""
+    def window(ext, spk, w, v, active, i):
+        return ops.spike_timestep_fused(
+            ext, spk, w, v, active, i, n_inputs=N_IN_SHD, decay_rate=0.5,
+            threshold_raw=THRESH, syn_decay=SYN, use_mxu=use_mxu,
+            block_batch=block_batch, interpret=False)
+
+    compiled = jax.jit(window).lower(
+        _shape(one_chip, (K, B, N_IN_SHD)), _shape(one_chip, (B, P)),
+        _shape(one_chip, (N_IN_SHD + P, P)), _shape(one_chip, (B, P)),
+        _shape(one_chip, (K, B)), _shape(one_chip, (B, P))).compile()
+    text = compiled.as_text()
+    assert re.search(r"%spike_timestep_fused_syn(\.\d+)? = ", text)
+    assert not re.search(r"%spike_timestep_fused(\.\d+)? = ", text)
 
 
 def test_reference_chunk_step_compiles_for_v5e(one_chip):
