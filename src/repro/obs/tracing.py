@@ -61,7 +61,8 @@ SPAN_KINDS: tuple[str, ...] = (
 
 # The served round's phases, in the order one round opens them. Nesting:
 # snn.pump > {admit, gather, snn.feed > {assemble, dispatch, readback,
-# split}, retire}; feed's four leaves repeat per chunk of a long feed.
+# split}, retire}; assemble, dispatch and readback repeat per chunk of
+# a long feed, split runs once.
 HOT_SPANS: tuple[str, ...] = (
     "snn.pump",           # AsyncSpikeFrontend.pump: one whole round
     "snn.pump.admit",     # expiry, preemption, admission (attach)
@@ -70,7 +71,8 @@ HOT_SPANS: tuple[str, ...] = (
     "snn.feed.assemble",  # one chunk's dense ext and active arrays
     "snn.feed.dispatch",  # host-to-device copies + step_chunk (h2d_bytes)
     "snn.feed.readback",  # the chunk's raster back to the host (d2h_bytes)
-    "snn.feed.split",     # per-stream rasters, concatenation, stats
+    "snn.feed.split",     # one count over all slots, a raster copy per
+                          # stream (streams, chunks)
     "snn.pump.retire",    # detach_many, one zeroing dispatch (zeroed)
 )
 _HOT = frozenset(HOT_SPANS)

@@ -681,17 +681,31 @@ class SpikeServer:
             rasters.append(spikes)
 
         cs = self.chunk_steps
-        with hot_span("snn.feed.split"):
-            for uid, (slot, arr) in chunks.items():
+        with hot_span("snn.feed.split", streams=len(chunks),
+                      chunks=len(rasters)):
+            # One counting pass for every slot: inactive slot-steps report
+            # zero spikes, so whole chunks sum to each stream's own T
+            # steps, and int32 is exact (at most T_max steps a slot).
+            acc = rasters[0].sum(axis=0, dtype=np.int32)
+            for r in rasters[1:]:
+                acc += r.sum(axis=0, dtype=np.int32)
+            slots = [slot for slot, _ in chunks.values()]
+            counts = acc[slots].astype(np.int_)   # numpy's sum of int32
+            totals = counts.sum(axis=1).tolist()
+            for (uid, (slot, arr)), row, total in zip(chunks.items(),
+                                                      counts, totals):
                 T = arr.shape[0]
-                raster = np.concatenate(
-                    [r[:min(cs, T - t0), slot]
-                     for t0, r in zip(range(0, T, cs), rasters)], axis=0)
+                # a copy per stream: no result may pin the round's buffer
+                if len(rasters) == 1:
+                    raster = rasters[0][:T, slot].copy()
+                else:
+                    raster = np.concatenate(
+                        [r[:min(cs, T - t0), slot]
+                         for t0, r in zip(range(0, T, cs), rasters)], axis=0)
                 st = self.streams[uid]
-                st.steps += raster.shape[0]
-                st.spike_count += int(raster.sum())
-                out[uid] = {"spikes": raster,
-                            "counts": raster.sum(axis=0)}
+                st.steps += T
+                st.spike_count += total
+                out[uid] = {"spikes": raster, "counts": row}
         return out
 
     def feed_events(self, inputs: dict, *, out_capacity: int | None = None,

@@ -110,10 +110,11 @@ def test_pump_round_emits_every_hot_span_nested(recorder):
     assert args["snn.feed.dispatch"] == {
         "h2d_bytes": ext.nbytes + active.nbytes}
     assert args["snn.feed.readback"] == {"d2h_bytes": raster.nbytes}
+    assert args["snn.feed.split"] == {"streams": SLOTS, "chunks": 1}
     assert args["snn.pump.retire"] == {"zeroed": SLOTS}
     assert all(not a for n, a in args.items()
                if n not in ("snn.feed.dispatch", "snn.feed.readback",
-                            "snn.pump.retire"))
+                            "snn.feed.split", "snn.pump.retire"))
 
 
 def test_feed_phases_repeat_per_chunk(recorder):
@@ -129,6 +130,22 @@ def test_feed_phases_repeat_per_chunk(recorder):
                      + ["snn.feed.assemble", "snn.feed.dispatch",
                         "snn.feed.readback"] * 3
                      + ["snn.feed.split"])
+
+
+@pytest.mark.parametrize("lengths,streams,chunks", [
+    ((7, 4), 2, 3),
+    ((3, 2), 2, 1),
+    ((0, 5), 1, 2),
+])
+def test_feed_split_counts_streams_and_chunks(recorder, lengths, streams,
+                                              chunks):
+    """The split says how many streams it served (a zero-length chunk is
+    served before it) and over how many read-back chunks."""
+    server = SpikeServer(_engine(), n_slots=SLOTS, chunk_steps=CHUNK)
+    uids = [server.attach() for _ in lengths]
+    server.feed(dict(zip(uids, _rasters(lengths))))
+    split = [a for n, _, a in recorder.spans if n == "snn.feed.split"]
+    assert split == [{"streams": streams, "chunks": chunks}]
 
 
 def _served(lengths):

@@ -269,6 +269,87 @@ def test_zero_length_chunk_is_per_stream_noop(rng):
     assert server.streams[b].steps == 0
 
 
+def _split_per_stream(rasters, streams, chunk_steps, n_phys):
+    """The reference split: each stream's slices concatenated out of the
+    read-back chunk rasters, then summed on their own, stream by stream."""
+    out = {}
+    for uid, (slot, T) in streams.items():
+        if T == 0:
+            out[uid] = {"spikes": np.zeros((0, n_phys), np.int32),
+                        "counts": np.zeros((n_phys,), np.int32),
+                        "steps": 0, "spike_count": 0}
+            continue
+        raster = np.concatenate(
+            [r[:min(chunk_steps, T - t0), slot]
+             for t0, r in zip(range(0, T, chunk_steps), rasters)], axis=0)
+        out[uid] = {"spikes": raster, "counts": raster.sum(axis=0),
+                    "steps": raster.shape[0],
+                    "spike_count": int(raster.sum())}
+    return out
+
+
+@pytest.mark.parametrize("neuron", ["lif", "current-based"])
+@pytest.mark.parametrize("chunk_steps", [32, 8], ids=["one-chunk",
+                                                       "three-chunks"])
+def test_feed_split_matches_per_stream_loop(rng, monkeypatch, neuron,
+                                            chunk_steps):
+    """``feed``'s one counting pass over all slots returns exactly what
+    the per-stream loop returns: rasters, counts and stream stats, values
+    and dtypes, for ragged T on non-contiguous slots; and every returned
+    raster owns its memory, apart from the read-back buffer and from
+    every other stream's raster."""
+    n_in, n_phys = 10, 16
+    S = n_in + n_phys
+    W = (rng.random((S, n_phys)) < 0.4) * rng.integers(-(1 << 15), 1 << 16,
+                                                       (S, n_phys))
+    syn = {"syn_decay": DecaySpec.shift(0.75)} if neuron != "lif" else {}
+    engine = SpikeEngine(jnp.asarray(W, jnp.int32), n_in,
+                         decay=DecaySpec.shift(0.25), threshold_raw=THRESH,
+                         reset_mode="zero", backend="reference", **syn)
+    server = SpikeServer(engine, n_slots=8, chunk_steps=chunk_steps)
+    uids = [server.attach() for _ in range(8)]
+    for i in (0, 3, 5):
+        server.detach(uids[i])
+    live = [uids[i] for i in (7, 2, 4, 1, 6)]   # slots out of order
+
+    read_back = []
+    step_chunk = engine.step_chunk
+
+    def recording(carry, ext, active):
+        carry, spikes = step_chunk(carry, ext, active)
+        read_back.append(np.asarray(spikes))
+        return carry, read_back[-1]
+
+    monkeypatch.setattr(engine, "step_chunk", recording)
+    for lengths in ((0, 3, 8, 13, 24), (24, 13, 0, 8, 3)):
+        read_back.clear()
+        inputs = {uid: _raster(rng, T, n_in)[:, 0]
+                  for uid, T in zip(live, lengths)}
+        before = {uid: (server.streams[uid].steps,
+                        server.streams[uid].spike_count) for uid in live}
+        out = server.feed(inputs)
+        want = _split_per_stream(
+            read_back, {uid: (server.slot_of(uid), x.shape[0])
+                        for uid, x in inputs.items()},
+            chunk_steps, n_phys)
+        assert len(read_back) == -(-max(lengths) // chunk_steps)
+        assert sum(w["spike_count"] for w in want.values()) > 0
+        assert out.keys() == want.keys()
+        for uid, w in want.items():
+            for key in ("spikes", "counts"):
+                assert out[uid][key].dtype == w[key].dtype, (uid, key)
+                assert out[uid][key].shape == w[key].shape, (uid, key)
+                assert out[uid][key].tobytes() == w[key].tobytes(), (uid, key)
+            st = server.streams[uid]
+            assert st.steps - before[uid][0] == w["steps"]
+            assert st.spike_count - before[uid][1] == w["spike_count"]
+            assert type(st.spike_count) is int
+        rasters = [o["spikes"] for o in out.values()]
+        for i, a in enumerate(rasters):
+            assert not any(np.shares_memory(a, r) for r in read_back)
+            assert not any(np.shares_memory(a, b) for b in rasters[i + 1:])
+
+
 def test_auto_uid_skips_caller_chosen_ids(rng):
     """Explicit and auto-generated uids coexist on one server."""
     engine = _engine(rng)
