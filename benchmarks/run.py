@@ -10,13 +10,11 @@ formatted tables. Sections:
   table_iii  — systems comparison (paper Table III)
   speedup    — Cerebra-S vs Cerebra-H cycles + wall time (paper §VII-B)
   kernels    — Pallas kernel micro-benchmarks + event-gating accounting
-  roofline   — 40-cell dry-run roofline table (EXPERIMENTS.md §Roofline)
 """
 
 from __future__ import annotations
 
 import argparse
-import sys
 import time
 
 
@@ -75,11 +73,6 @@ def main() -> None:
         if args.fast:
             grid_args += ["--train-steps", "60", "--eval-n", "256"]
         table_iv_accuracy.main(grid_args)
-
-    if want("roofline"):
-        _section("roofline (from dry-run artifacts)")
-        from benchmarks import roofline
-        roofline.main([])
 
     print(f"\n[benchmarks] done in {time.time() - t0:.0f}s", flush=True)
 

@@ -1,8 +1,7 @@
 """Deterministic, stateless, shardable data pipelines.
 
   mnist  — procedural MNIST (or real IDX files when present)
-  lm     — synthetic Markov/Zipf token streams for the LM archs
   events — synthetic event-camera (DVS-gesture-style) sparse spike clips
 """
 
-from repro.data import events, lm, mnist  # noqa: F401
+from repro.data import events, mnist  # noqa: F401

@@ -1,4 +1,4 @@
 """Distribution: partition rules, mesh-sharded spike engine, straggler
-mitigation, elastic helpers."""
+detection."""
 
 from repro.distributed import partition, spike_mesh, straggler  # noqa: F401

@@ -1,21 +1,17 @@
-"""Logical-axis -> mesh partitioning with divisibility fallbacks.
+"""Logical-axis -> mesh-axis resolution with divisibility fallbacks.
 
-Sharding policy (DESIGN.md §5), in the spirit of the paper's Cerebra-H
-memory organization — weights live distributed, close to compute:
+The spike mesh (:mod:`repro.distributed.spike_mesh`) names the axes of
+its arrays logically — ``neuron``, ``batch``, ``source``, ``time`` — and
+:data:`~repro.distributed.spike_mesh.SNN_RULES` maps ``neuron`` and
+``batch`` onto the mesh axes of the same names. :func:`spec_for` turns a
+logical-axis tuple and an array shape into the ``PartitionSpec`` each
+dispatch uses: the weight image's columns, the carries and the rasters
+shard over ``neuron``; the slots over ``batch``; the source and time axes
+never shard, since every device reads the full all-gathered source vector.
 
-  vocab  -> model          (tensor-parallel unembedding/embedding)
-  embed  -> data           (ZeRO/FSDP: params + optimizer sharded over the
-                            data axis, all-gathered per layer by SPMD)
-  heads  -> model          (Megatron-style attention TP)
-  ffn    -> model          (Megatron-style MLP TP)
-  expert -> None (baseline: TP inside each expert) | model (EP variant)
-  batch  -> (pod, data)
-  cache_seq -> model       (decode context parallelism; kv heads rarely
-                            divide a 16-way axis)
-
-Every rule is subject to a divisibility check against the actual dim; on
-failure the dim replicates (e.g. minicpm3's 40 heads, granite-3's 49155
-vocab). This is what makes ALL 40 (arch x shape) cells lower+compile.
+Every rule is checked against the actual dim: a dim the mesh axis does
+not divide, a size-1 axis, or a mesh axis already taken by an earlier dim
+of the same array replicates instead.
 """
 
 from __future__ import annotations
@@ -23,12 +19,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import PartitionSpec
 
-__all__ = ["PartitionRules", "params_partition", "batch_partition",
-           "cache_partition", "spec_for"]
+__all__ = ["PartitionRules", "spec_for"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,30 +30,6 @@ class PartitionRules:
     """Logical axis name -> mesh axis (or tuple of mesh axes)."""
 
     rules: dict[str, Any] = dataclasses.field(default_factory=dict)
-    batch_axes: tuple[str, ...] = ("data",)
-
-    @classmethod
-    def default(cls, mesh, *, expert_parallel: bool = False,
-                attn_tp: bool = True) -> "PartitionRules":
-        multi_pod = "pod" in mesh.axis_names
-        rules = {
-            "vocab": "model",
-            "embed": "data",
-            # attn_tp=False replicates attention projections — §Perf lever
-            # when n_heads doesn't divide the model axis (llama4: 40 on 16)
-            # and GSPMD's partial-head resharding dominates collectives.
-            "heads": "model" if attn_tp else None,
-            "kv": "model" if attn_tp else None,
-            "ffn": "model" if not expert_parallel else None,
-            # (hypothesis A5 — expert-dim ZeRO over data — REFUTED: GSPMD
-            # re-gathers the full expert stacks per use; see §Perf log)
-            "expert": "model" if expert_parallel else None,
-            "layers": None,
-            "cache_seq": "model",
-            "cache_batch": ("pod", "data") if multi_pod else ("data",),
-        }
-        return cls(rules=rules,
-                   batch_axes=("pod", "data") if multi_pod else ("data",))
 
 
 def _axis_size(mesh, mesh_axes) -> int:
@@ -94,178 +64,3 @@ def spec_for(logical_axes, shape, mesh, rules: PartitionRules
     while out and out[-1] is None:
         out.pop()
     return PartitionSpec(*out)
-
-
-def params_partition(param_shapes, mesh, rules: PartitionRules):
-    """Pytree of ShapeDtypeStruct -> pytree of NamedSharding."""
-    # deferred: models.transformer imports this module for constrain_batch
-    from repro.models.common import axes_of
-
-    def one(path, leaf):
-        key = "/".join(_pstr(p) for p in path)
-        axes = axes_of(key, leaf)
-        return NamedSharding(mesh, spec_for(axes, leaf.shape, mesh, rules))
-
-    flat, treedef = jax.tree_util.tree_flatten_with_path(param_shapes)
-    return jax.tree_util.tree_unflatten(
-        treedef, [one(p, l) for p, l in flat])
-
-
-def _pstr(entry) -> str:
-    if isinstance(entry, jax.tree_util.DictKey):
-        return str(entry.key)
-    if isinstance(entry, jax.tree_util.SequenceKey):
-        return str(entry.idx)
-    return str(entry)
-
-
-# --------------------------------------------------------------------------
-# Activation-sharding context: models call constrain_batch() at the few
-# points where GSPMD propagation is known to drop the batch sharding (the
-# unembed projection's cotangent replicates a (B,S,V) f32 buffer without
-# it). The harness sets the context while tracing; outside any context the
-# helpers are no-ops, so model code stays mesh-agnostic.
-# --------------------------------------------------------------------------
-
-import contextlib as _contextlib
-import contextvars as _contextvars
-
-_ACT_CTX: "_contextvars.ContextVar[tuple | None]" = _contextvars.ContextVar(
-    "repro_activation_sharding", default=None)
-
-
-@_contextlib.contextmanager
-def activation_sharding(batch_axes: tuple[str, ...], batch_size: int,
-                        mesh):
-    size = _axis_size(mesh, tuple(batch_axes))
-    tok = _ACT_CTX.set((tuple(batch_axes), size, batch_size))
-    try:
-        yield
-    finally:
-        _ACT_CTX.reset(tok)
-
-
-def constrain_batch(x, batch_axis: int = 0):
-    """Constrain x's batch dim to the ambient batch mesh axes (no-op
-    outside an activation_sharding context or on non-divisible dims)."""
-    ctx = _ACT_CTX.get()
-    if ctx is None:
-        return x
-    axes, size, _ = ctx
-    if size <= 1 or x.shape[batch_axis] % size != 0:
-        return x
-    spec = [None] * x.ndim
-    spec[batch_axis] = axes if len(axes) > 1 else axes[0]
-    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
-
-
-def constrain_seq(x, seq_axis: int = 1, batch_axis: int | None = 0,
-                  mesh_axis: str = "model"):
-    """Context-parallel constraint: shard x's sequence dim over ``model``.
-
-    §Perf lever for archs whose head count does not divide the model axis
-    (llama4's 40 heads on a 16-way axis): attention math is token-parallel
-    in the query dim, so sharding S instead of heads avoids the partial-
-    head resharding all-reduces GSPMD otherwise inserts. No-op outside an
-    activation context or when S doesn't divide.
-    """
-    ctx = _ACT_CTX.get()
-    if ctx is None:
-        return x
-    axes, bsize, _ = ctx
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty or mesh_axis not in mesh.axis_names:
-        return x
-    msize = mesh.shape[mesh_axis]
-    if msize <= 1 or x.shape[seq_axis] % msize != 0:
-        return x
-    spec = [None] * x.ndim
-    spec[seq_axis] = mesh_axis
-    if (batch_axis is not None and bsize > 1
-            and x.shape[batch_axis] % bsize == 0):
-        spec[batch_axis] = axes if len(axes) > 1 else axes[0]
-    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
-
-
-def opt_partition(opt_shapes, params_shard, mesh):
-    """Adam-style state: {'step', 'm': <params>, 'v': <params>} — moments
-    shard exactly like the params (ZeRO-1 falls out of embed->data)."""
-    replicated = NamedSharding(mesh, PartitionSpec())
-    out = {}
-    for key, sub in opt_shapes.items():
-        if key in ("m", "v", "mu") and sub is not None:
-            out[key] = params_shard
-        else:
-            out[key] = jax.tree.map(lambda _: replicated, sub)
-    return out
-
-
-# --------------------------------------------------------------------------
-# Batch / cache shardings (name-pattern based)
-# --------------------------------------------------------------------------
-
-_BATCH_AXES_BY_KEY: dict[str, tuple[str | None, ...]] = {
-    # (leading axes per rank); "B" = batch, "S" = sequence (replicated for
-    # inputs — sequence parallelism for activations is a §Perf lever)
-    "tokens": ("B", None),
-    "targets": ("B", None),
-    "embeds": ("B", None, None),
-    "enc_embeds": ("B", None, None),
-    "mrope_positions": (None, "B", None),
-    "positions": ("B", None),
-}
-
-
-def batch_partition(batch_shapes, mesh, rules: PartitionRules):
-    def one(path, leaf):
-        key = _pstr(path[-1]) if path else ""
-        axes = _BATCH_AXES_BY_KEY.get(key, (None,) * leaf.ndim)
-        spec = []
-        for ax, dim in zip(axes, leaf.shape):
-            if ax == "B":
-                size = _axis_size(mesh, rules.batch_axes)
-                spec.append(tuple(rules.batch_axes)
-                            if dim % size == 0 and size > 1 else None)
-            else:
-                spec.append(None)
-        while spec and spec[-1] is None:
-            spec.pop()
-        return NamedSharding(mesh, PartitionSpec(*spec))
-
-    flat, treedef = jax.tree_util.tree_flatten_with_path(batch_shapes)
-    return jax.tree_util.tree_unflatten(
-        treedef, [one(p, l) for p, l in flat])
-
-
-_CACHE_AXES_BY_KEY: dict[str, tuple[str | None, ...]] = {
-    # per-layer-stacked cache leaves (leading "layers" dim)
-    "k": ("layers", "cache_batch", "cache_seq", "kv", None),
-    "v": ("layers", "cache_batch", "cache_seq", "kv", None),
-    "slot_pos": ("layers", "cache_seq"),
-    "ckv": ("layers", "cache_batch", "cache_seq", None),
-    "k_rope": ("layers", "cache_batch", "cache_seq", None),
-    "ssm": ("layers", "cache_batch", "ffn", None, None),
-    "conv": ("layers", "cache_batch", None, "ffn"),
-    "state": ("layers", "cache_batch", "heads", None, None),
-    "x_att": ("layers", "cache_batch", "embed"),
-    "x_ffn": ("layers", "cache_batch", "embed"),
-}
-
-
-def cache_partition(cache_shapes, mesh, rules: PartitionRules):
-    """Shardings for (stacked) decode caches by leaf name."""
-
-    def one(path, leaf):
-        key = _pstr(path[-1])
-        axes = _CACHE_AXES_BY_KEY.get(key, ("layers",) + (None,) *
-                                      (leaf.ndim - 1))
-        if leaf.ndim > len(axes):
-            # split-cache layouts prepend group dims: (G[, nloc], ...)
-            axes = (None,) * (leaf.ndim - len(axes)) + tuple(axes)
-        axes = axes[-leaf.ndim:] if leaf.ndim < len(axes) else axes
-        return NamedSharding(mesh, spec_for(axes, leaf.shape, mesh, rules))
-
-    flat, treedef = jax.tree_util.tree_flatten_with_path(cache_shapes)
-    return jax.tree_util.tree_unflatten(
-        treedef, [one(p, l) for p, l in flat])

@@ -57,8 +57,8 @@ __all__ = [
 NEURON_AXIS = "neuron"
 BATCH_AXIS = "batch"
 
-# Logical-axis -> mesh-axis rules for SNN arrays, resolved through the same
-# spec machinery the LM stack uses (divisibility fallbacks included):
+# Logical-axis -> mesh-axis rules for SNN arrays, resolved through
+# partition.spec_for (divisibility fallbacks included):
 #   neuron -> "neuron"  (physical-neuron / cluster-range axis; weight
 #                        columns + carries + rasters)
 #   batch  -> "batch"   (independent streams / examples)
@@ -66,7 +66,6 @@ BATCH_AXIS = "batch"
 # all-gathered source vector, mirroring the NoC broadcast.
 SNN_RULES = PartitionRules(
     rules={"neuron": NEURON_AXIS, "batch": BATCH_AXIS},
-    batch_axes=(BATCH_AXIS,),
 )
 
 

@@ -1,20 +1,20 @@
 """Straggler detection & mitigation hooks.
 
-At pod scale, a single slow host (thermal throttling, ECC retry storms,
-network flaps) stretches every synchronous step. The detector keeps an
-EWMA + variance of per-host step times and flags hosts whose time exceeds
-``mean + threshold_sigma * std`` for ``patience`` consecutive steps.
+A single slow shard (thermal throttling, ECC retry storms, a batch shard
+that holds more live streams than the rest) stretches every synchronous
+step. The detector keeps an EWMA + variance of per-shard step times and
+flags shards whose time exceeds ``mean + threshold_sigma * std`` for
+``patience`` consecutive steps.
 
-Mitigations are pluggable callbacks; built in:
-  * ``rebalance``: shrink the flagged host's data shard (returns a new
-    shard-size vector; the stateless data pipeline makes re-sharding a
-    pure re-parameterization — no data movement),
-  * ``evict``: mark the host for exclusion at the next checkpoint restart
-    (elastic scale-down; checkpoints are mesh-agnostic so restart on N-1
-    hosts is a load with a different mesh).
+Mitigations, both pure functions of the flag mask:
+  * :func:`rebalance_shards`: shrink the flagged shards' share of a batch
+    (a new shard-size vector; no data moves),
+  * :func:`donor_shards`: the unflagged shards, which
+    ``repro.serving.connector.rebalance_streams`` walks live streams onto.
 
 The logic is pure and unit-tested with synthetic timings; the wall-clock
-plumbing lives in training.loop.
+plumbing lives in the serving launcher (``launch/serve_snn.ShardLoadWatch``)
+and reaches the detector through :func:`observe_from_registry`.
 """
 
 from __future__ import annotations

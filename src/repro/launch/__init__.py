@@ -1,7 +1,3 @@
-"""Launchers: mesh construction, dry-run, train/serve drivers.
-
-NOTE: ``repro.launch.dryrun`` sets XLA_FLAGS at import — import it only
-as an entry point (``python -m repro.launch.dryrun``), never from tests.
-"""
-
-from repro.launch import mesh, steps  # noqa: F401
+"""Entry points: ``python -m repro.launch.serve_snn`` (the streaming SNN
+serving launcher) and ``compile_cache`` (the persistent XLA compile cache
+the entry points share)."""

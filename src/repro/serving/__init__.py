@@ -1,37 +1,25 @@
-"""Batched LM serving runtime (the ``serve_step`` the decode shapes lower).
+"""Stateful SNN serving: spike streams over one compiled masked step.
 
-Design mirrors production TPU serving: a static-shape decode loop over a
-fixed batch of sequence slots (XLA-friendly — one compiled program reused
-every step), a length-bucketing scheduler for admission, greedy sampling,
-and per-slot completion masks. The KV cache is the stacked per-layer tree
-from ``model.init_cache`` and shards per ``cache_partition`` on real
-meshes.
+A stream is a resident of the engine, not a request: its membrane state
+(and, for current-based neurons, its synaptic current) stays in a fixed
+slot of one slot-batch carry across chunk steps.
 
-Two layers:
-  * :class:`BatchServer` — prefill a batch of prompts, decode to
-    completion with a single jitted step (the decode_32k / long_500k cells
-    lower exactly this step function).
-  * :class:`Scheduler` — groups pending requests into length buckets so
-    padding waste stays bounded (the admission policy a cluster front-end
-    would run).
+  * :mod:`repro.serving.snn` — :class:`SpikeServer` (fixed-slot admission
+    by :class:`SlotScheduler`, chunked ``feed``/``feed_many``, closed-loop
+    mode, mesh scale-out) and the per-model :class:`ModelStream` views
+    ``AcceleratorSession.serve`` hands out;
+  * :mod:`repro.serving.frontend` — :class:`AsyncSpikeFrontend`, the
+    bounded request queue decoupled from the step loop (deadlines,
+    backpressure, spill-on-evict);
+  * :mod:`repro.serving.qos` — QoS classes and the weighted fair queue
+    the frontend admits by;
+  * :mod:`repro.serving.connector` — the carry snapshot wire format and
+    the stores live migration, rolling redeploy and crash recovery ride.
 
-The SNN analogue — stateful spike streams over one compiled SpikeEngine
-step — lives in :mod:`repro.serving.snn` (:class:`~repro.serving.snn.
-SpikeServer` et al., re-exported here), with the async admission layer
-(bounded request queue decoupled from the step loop) in
-:mod:`repro.serving.frontend` (:class:`~repro.serving.frontend.
-AsyncSpikeFrontend`).
+Every name below is re-exported from those modules.
 """
 
 from __future__ import annotations
-
-import dataclasses
-import time
-from typing import Sequence
-
-import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.serving.connector import (  # noqa: E402  (re-export)
     CarryConnectorBase,
@@ -58,118 +46,8 @@ from repro.serving.snn import (  # noqa: E402  (re-export)
     StreamStats,
 )
 
-__all__ = ["Request", "Completion", "BatchServer", "Scheduler",
-           "SpikeServer", "SlotScheduler", "ModelStream", "StreamStats",
+__all__ = ["SpikeServer", "SlotScheduler", "ModelStream", "StreamStats",
            "AsyncSpikeFrontend", "FrontendConfig", "RequestHandle",
            "QoSClass", "QoSPolicy", "WeightedFairQueue",
            "CarryConnectorBase", "CarrySnapshot", "InMemoryCarryConnector",
            "FileCarryConnector", "migrate_stream", "rebalance_streams"]
-
-
-@dataclasses.dataclass
-class Request:
-    uid: int
-    prompt: np.ndarray           # (S,) int32 token ids
-    max_new_tokens: int = 32
-    eos_id: int | None = None
-
-
-@dataclasses.dataclass
-class Completion:
-    uid: int
-    tokens: np.ndarray           # (<=max_new,) generated ids
-    prompt_len: int
-    latency_s: float
-
-
-class BatchServer:
-    """Fixed-slot batched prefill + decode engine for one model."""
-
-    def __init__(self, model, params, *, max_seq: int, pad_id: int = 0):
-        self.model = model
-        self.params = params
-        self.max_seq = max_seq
-        self.pad_id = pad_id
-        self._decode_fn = jax.jit(self._decode_step)
-        self._prefill_fn = jax.jit(self._prefill,
-                                   static_argnames=("batch", "seq"))
-
-    # -- jitted bodies ----------------------------------------------------
-    def _prefill(self, params, tokens, *, batch: int, seq: int):
-        cache = self.model.init_cache(batch, self.max_seq)
-        logits, cache = self.model.prefill(params, {"tokens": tokens}, cache)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return next_tok[:, None], cache
-
-    def _decode_step(self, params, cache, tokens, pos):
-        logits, cache = self.model.decode_step(params, {"tokens": tokens},
-                                               pos, cache)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return next_tok[:, None], cache
-
-    # -- public -----------------------------------------------------------
-    def serve(self, requests: Sequence[Request]) -> list[Completion]:
-        """Greedy-decode a batch of same-bucket requests."""
-        t0 = time.perf_counter()
-        B = len(requests)
-        prompt_lens = [len(r.prompt) for r in requests]
-        S = max(prompt_lens)
-        toks = np.full((B, S), self.pad_id, np.int32)
-        for i, r in enumerate(requests):
-            toks[i, S - len(r.prompt):] = r.prompt  # left-pad to align end
-        tok, cache = self._prefill_fn(self.params, jnp.asarray(toks),
-                                      batch=B, seq=S)
-        max_new = max(r.max_new_tokens for r in requests)
-        max_new = min(max_new, self.max_seq - S)
-        out = np.zeros((B, max_new), np.int32)
-        done = np.zeros(B, bool)
-        steps = 0
-        for step in range(max_new):
-            out[:, step] = np.asarray(tok[:, 0])
-            for i, r in enumerate(requests):
-                if r.eos_id is not None and out[i, step] == r.eos_id:
-                    done[i] = True
-                if step + 1 >= r.max_new_tokens:
-                    done[i] = True
-            steps += 1
-            if done.all():
-                break
-            tok, cache = self._decode_fn(self.params, cache, tok,
-                                         jnp.int32(S + step))
-        dt = time.perf_counter() - t0
-        comps = []
-        for i, r in enumerate(requests):
-            n = min(r.max_new_tokens, steps)
-            comps.append(Completion(uid=r.uid, tokens=out[i, :n],
-                                    prompt_len=prompt_lens[i], latency_s=dt))
-        return comps
-
-    def throughput_stats(self, comps: list[Completion]) -> dict:
-        toks = sum(len(c.tokens) for c in comps)
-        dt = max(c.latency_s for c in comps)
-        return {"generated_tokens": toks, "wall_s": dt,
-                "tokens_per_s": toks / max(dt, 1e-9)}
-
-
-class Scheduler:
-    """Length-bucketing admission: batches of <= max_batch, prompts padded
-    at most 2x within a bucket (bounded padding waste)."""
-
-    def __init__(self, max_batch: int):
-        self.max_batch = max_batch
-        self.pending: list[Request] = []
-
-    def submit(self, req: Request) -> None:
-        self.pending.append(req)
-
-    def next_batch(self) -> list[Request]:
-        if not self.pending:
-            return []
-        self.pending.sort(key=lambda r: len(r.prompt))
-        anchor = len(self.pending[0].prompt)
-        batch = [r for r in self.pending
-                 if len(r.prompt) <= max(2 * anchor, anchor + 16)]
-        batch = batch[: self.max_batch]
-        taken = {id(r) for r in batch}
-        self.pending = [r for r in self.pending if id(r) not in taken]
-        return batch

@@ -3,9 +3,9 @@
 SNAP-V's accelerator is a *stateful* device: membrane potentials persist
 across timesteps and spike events are consumed as they arrive, not as
 pre-materialized rasters. This module is the host-runtime analogue of that
-contract, built with the same fixed-slot discipline the LM ``BatchServer``
-uses (one jitted step of a pinned batch shape, reused for all traffic —
-the continuous-batching idiom):
+contract, built with a fixed-slot discipline (one jitted step of a
+pinned batch shape, reused for all traffic — the continuous-batching
+idiom):
 
   * :class:`SlotScheduler` — admission of stream ids into a fixed set of
     batch slots: FIFO waiting queue, FIFO slot reuse, no double

@@ -1,3 +1,3 @@
-"""Training substrate: optimizers, schedules, compression, loop."""
+"""Training substrate: optimizers and schedules (``snn/train.py``)."""
 
-from repro.training import compression, loop, optimizers  # noqa: F401
+from repro.training import optimizers  # noqa: F401

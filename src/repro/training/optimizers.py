@@ -6,9 +6,7 @@ All optimizers follow the (init, update) pair convention:
     updates, state = opt.update(grads, state, params)
     params = apply_updates(params, updates)
 
-States are pytrees of arrays with the same tree structure as the params, so
-they shard under pjit exactly like the params do (ZeRO-1 falls out of the
-partition rules in repro.distributed).
+States are pytrees of arrays with the same tree structure as the params.
 """
 
 from __future__ import annotations
@@ -49,7 +47,10 @@ def global_norm(tree) -> jnp.ndarray:
 def clip_by_global_norm(grads, max_norm: float):
     norm = global_norm(grads)
     scale = jnp.minimum(1.0, max_norm / (norm + 1e-12))
-    return jax.tree.map(lambda g: g * scale, grads), norm
+    # under the limit the gradients pass through untouched: g * 1.0 would
+    # flush subnormal entries to zero on backends that flush denormals
+    return jax.tree.map(lambda g: jnp.where(norm > max_norm, g * scale, g),
+                        grads), norm
 
 
 # --------------------------------------------------------------------------

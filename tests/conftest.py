@@ -75,25 +75,45 @@ def rng():
     return np.random.default_rng(0)
 
 
+def assert_slot_state(server, uid, ref, carry=None):
+    """``uid``'s slot holds the one-shot ``run``'s final state (``ref``,
+    batch row 0): ``v``, and ``i`` for current-based neurons. A current
+    dropped on the way can leave every spike in place, so served-path
+    tests check the state too. ``carry``: a parked snapshot's arrays in
+    place of the server's slot."""
+    if carry is None:
+        slot = server.slot_of(uid)
+        carry = {k: np.asarray(x)[slot] for k, x in server.carry.items()}
+    assert carry.keys() == set(server.engine.carry_keys)
+    for k in set(carry) - {"spikes"}:
+        np.testing.assert_array_equal(carry[k],
+                                      np.asarray(ref[f"{k}_final"])[0],
+                                      err_msg=k)
+
+
 def make_random_net(rng, n_in=20, n_neurons=48, density=0.25, out=10,
-                    decay_rate=0.25, reset_mode="zero", scale=0.5):
-    """Random recurrent-ish SNNetwork with an output slice."""
+                    decay_rate=0.25, reset_mode="zero", scale=0.5,
+                    syn_decay_rate=None):
+    """Random recurrent-ish SNNetwork with an output slice (current-based
+    neurons with ``syn_decay_rate``)."""
     from repro.core.lif import LIFParams
     from repro.core.network import SNNetwork
 
     W = ((rng.random((n_in + n_neurons, n_neurons)) < density)
          * rng.normal(0.0, scale, (n_in + n_neurons, n_neurons)))
     params = LIFParams(decay_rate=decay_rate, threshold=1.0,
-                       reset_mode=reset_mode)
+                       reset_mode=reset_mode, syn_decay_rate=syn_decay_rate)
     return SNNetwork(
         n_inputs=n_in, n_neurons=n_neurons, weights=W.astype(np.float32),
         params=params, output_slice=(n_neurons - out, n_neurons))
 
 
-def make_ff_net(rng, sizes=(20, 24, 10), decay_rate=0.25, scale=0.6):
+def make_ff_net(rng, sizes=(20, 24, 10), decay_rate=0.25, scale=0.6,
+                syn_decay_rate=None):
     from repro.core.lif import LIFParams
     from repro.core.network import feedforward
 
     ws = [rng.normal(0.0, scale / np.sqrt(a), (a, b)).astype(np.float32)
           for a, b in zip(sizes[:-1], sizes[1:])]
-    return feedforward(ws, LIFParams(decay_rate=decay_rate))
+    return feedforward(ws, LIFParams(decay_rate=decay_rate,
+                                     syn_decay_rate=syn_decay_rate))

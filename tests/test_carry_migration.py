@@ -23,6 +23,10 @@ the same stream never migrated. Pinned here:
     directory — resumed streams continue bit-clean;
   * restore-side safety: incompatible engines, full servers, and missing
     snapshots are refused BEFORE any server state mutates.
+
+The fast legs run for both neuron models (``neuron``): one-state LIF and
+current-based LIF, whose synaptic current ``i`` must ride every hop
+beside ``v``.
 """
 
 import jax
@@ -30,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import coding
 from repro.core.engine import BACKENDS, GATES, DecaySpec, SpikeEngine
 from repro.core.lif import LIFParams
 from repro.core.network import SNNetwork
@@ -41,19 +44,23 @@ from repro.serving.connector import (FileCarryConnector,
                                      rebalance_streams)
 from repro.serving.snn import SpikeServer
 
+from conftest import assert_slot_state
+
 THRESH = 1 << 16
 RESET_MODES = ("zero", "subtract", "hold")
+NEURONS = ("lif", "cuba")
 
 
 def _engine(rng, *, backend="reference", gate="batch-tile", reset="subtract",
-            K=1, n_in=10, n_phys=16, wmax=1 << 13):
+            K=1, n_in=10, n_phys=16, wmax=1 << 13, neuron="lif"):
     S = n_in + n_phys
     W = ((rng.random((S, n_phys)) < 0.4)
          * rng.integers(-wmax, wmax, (S, n_phys)))
+    syn = DecaySpec.shift(0.75) if neuron == "cuba" else None
     return SpikeEngine(jnp.asarray(W, jnp.int32), n_in,
                        decay=DecaySpec.shift(0.25), threshold_raw=THRESH,
                        reset_mode=reset, backend=backend, gate=gate,
-                       fuse_steps=K)
+                       fuse_steps=K, syn_decay=syn)
 
 
 def _raster(rng, T, n_in, p=0.35):
@@ -65,9 +72,11 @@ def _migrated_vs_reference(engine_a, engine_b, rng, *, T=14, t_mid=6,
                            mesh_b=None):
     """THE contract check: stream T steps with a mid-flight hop from
     server A to server B through a connector; the stitched raster must
-    equal the one-shot never-migrated ``run`` on engine A."""
+    equal the one-shot never-migrated ``run`` on engine A, and so must
+    the slot's final state (``v``, and ``i`` for current-based neurons)."""
     ext = _raster(rng, T, engine_a.n_inputs)
-    want = np.asarray(engine_a.run(ext[:, None, :])["spikes"])[:, 0]
+    ref = engine_a.run(ext[:, None, :])
+    want = np.asarray(ref["spikes"])[:, 0]
 
     conn = connector if connector is not None else InMemoryCarryConnector()
     a = SpikeServer(engine_a, n_slots=3, chunk_steps=chunk_a)
@@ -85,36 +94,41 @@ def _migrated_vs_reference(engine_a, engine_b, rng, *, T=14, t_mid=6,
     assert got.dtype == want.dtype == np.int32
     np.testing.assert_array_equal(got, want)
     assert b.streams[uid].steps == T  # counters rode along
+    assert_slot_state(b, uid, ref)
 
 
 # --------------------------------------------------------------------------
 # cross-server migration: fast legs + full slow sweep
 # --------------------------------------------------------------------------
 
-def test_cross_server_migration_fast(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_cross_server_migration_fast(rng, neuron):
     """Reference engine, ragged chunking on both sides, in-memory hop —
     the always-on leg of the contract."""
-    e = _engine(rng)
+    e = _engine(rng, neuron=neuron)
     _migrated_vs_reference(e, e, rng)
 
 
-def test_cross_server_migration_through_file_fast(rng, tmp_path):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_cross_server_migration_through_file_fast(rng, tmp_path, neuron):
     """Same hop through the file-backed connector: the bytes take the
     disk detour and still land identical."""
-    e = _engine(rng, reset="zero")
+    e = _engine(rng, reset="zero", neuron=neuron)
     _migrated_vs_reference(
         e, e, rng, connector=FileCarryConnector(str(tmp_path / "c")))
 
 
-def test_migration_across_hostings_fast(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_migration_across_hostings_fast(rng, neuron):
     """A carry is portable across backend/gate/fuse re-hostings: park on
     the reference server, resume on a fused per-example pallas server."""
-    src = _engine(rng)
+    src = _engine(rng, neuron=neuron)
     # same weights so the slot params (and the future) agree
     dst = SpikeEngine(src.weights_raw, src.n_inputs,
                       decay=DecaySpec.shift(0.25), threshold_raw=THRESH,
                       reset_mode="subtract", backend="pallas",
-                      gate="per-example", fuse_steps=4)
+                      gate="per-example", fuse_steps=4,
+                      syn_decay=src.syn_decay)
     _migrated_vs_reference(src, dst, rng)
 
 
@@ -149,21 +163,24 @@ def _mesh(neuron, batch):
     return make_spike_mesh(neuron=neuron, batch=batch)
 
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
-def test_migration_into_mesh_server(rng, shape):
+def test_migration_into_mesh_server(rng, shape, neuron):
     """A carry parked on a single-device server resumes bit-clean on a
     mesh-sharded one (and 1x1 exercises the shard_map path everywhere)."""
     mesh = _mesh(*shape)
-    e = _engine(rng, n_in=11, n_phys=24)
+    e = _engine(rng, n_in=11, n_phys=24, neuron=neuron)
     _migrated_vs_reference(e, e, rng, mesh_b=mesh)
 
 
-def test_migration_out_of_mesh_server(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_migration_out_of_mesh_server(rng, neuron):
     """And back: a stream born sharded hops to a plain server."""
     mesh = _mesh(1, 1)
-    e = _engine(rng, reset="hold")
+    e = _engine(rng, reset="hold", neuron=neuron)
     ext = _raster(rng, 12, e.n_inputs)
-    want = np.asarray(e.run(ext[:, None, :])["spikes"])[:, 0]
+    ref = e.run(ext[:, None, :])
+    want = np.asarray(ref["spikes"])[:, 0]
 
     conn = InMemoryCarryConnector()
     a = SpikeServer(e, n_slots=2, chunk_steps=4, mesh=mesh)
@@ -175,18 +192,21 @@ def test_migration_out_of_mesh_server(rng):
     second = b.feed({uid: ext[5:]})[uid]["spikes"]
     np.testing.assert_array_equal(
         np.concatenate([np.asarray(first), np.asarray(second)]), want)
+    assert_slot_state(b, uid, ref)
 
 
 # --------------------------------------------------------------------------
 # intra-server: migrate_stream + straggler rebalance
 # --------------------------------------------------------------------------
 
-def test_migrate_stream_changes_address_not_future(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_migrate_stream_changes_address_not_future(rng, neuron):
     """Mid-stream slot move: the raster continues byte-identically, the
     old slot is powered down, counters survive."""
-    e = _engine(rng)
+    e = _engine(rng, neuron=neuron)
     ext = _raster(rng, 12, e.n_inputs)
-    want = np.asarray(e.run(ext[:, None, :])["spikes"])[:, 0]
+    ref = e.run(ext[:, None, :])
+    want = np.asarray(ref["spikes"])[:, 0]
 
     server = SpikeServer(e, n_slots=4, chunk_steps=4)
     uid = server.attach()
@@ -194,28 +214,35 @@ def test_migrate_stream_changes_address_not_future(rng):
 
     old = migrate_stream(server, uid, slot=3)
     assert (old, server.slot_of(uid)) == (0, 3)
-    assert not np.asarray(server.carry["v"][old]).any()  # zeroed behind
+    for k in server.carry:                                # zeroed behind
+        assert not np.asarray(server.carry[k][old]).any(), k
 
     second = server.feed({uid: ext[7:]})[uid]["spikes"]
     np.testing.assert_array_equal(
         np.concatenate([np.asarray(first), np.asarray(second)]), want)
     assert server.streams[uid].steps == 12
+    assert_slot_state(server, uid, ref)
 
 
-def test_migrate_stream_same_slot_is_noop(rng):
-    server = SpikeServer(_engine(rng), n_slots=2, chunk_steps=4)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_migrate_stream_same_slot_is_noop(rng, neuron):
+    e = _engine(rng, neuron=neuron)
+    server = SpikeServer(e, n_slots=2, chunk_steps=4)
     uid = server.attach()
-    before = np.asarray(server.carry["v"])
+    server.feed({uid: _raster(rng, 5, e.n_inputs)})
+    before = {k: np.asarray(x) for k, x in server.carry.items()}
     assert migrate_stream(server, uid, slot=0) == 0
-    np.testing.assert_array_equal(np.asarray(server.carry["v"]), before)
+    for k, x in server.carry.items():
+        np.testing.assert_array_equal(np.asarray(x), before[k])
 
 
-def test_rebalance_drains_flagged_shards_bit_clean(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_rebalance_drains_flagged_shards_bit_clean(rng, neuron):
     """8 slots / 4 shards (slots_per_shard=2), shard 0 flagged: its
     streams walk onto donor shards' free slots, lowest ids first, and a
     twin server that never rebalanced proves every stream's raster is
     untouched by the move."""
-    e = _engine(rng)
+    e = _engine(rng, neuron=neuron)
     moved = SpikeServer(e, n_slots=8, chunk_steps=4)
     still = SpikeServer(e, n_slots=8, chunk_steps=4)
     uids = ["s0", "s1", "s2"]
@@ -240,6 +267,10 @@ def test_rebalance_drains_flagged_shards_bit_clean(rng):
     for u in uids:
         np.testing.assert_array_equal(np.asarray(got[u]["spikes"]),
                                       np.asarray(want[u]["spikes"]))
+        for k in moved.carry:
+            np.testing.assert_array_equal(
+                np.asarray(moved.carry[k])[moved.slot_of(u)],
+                np.asarray(still.carry[k])[still.slot_of(u)], err_msg=k)
 
 
 def test_rebalance_noop_cases(rng):
@@ -257,21 +288,25 @@ def test_rebalance_noop_cases(rng):
 # session: rolling redeploy parks and restores live streams
 # --------------------------------------------------------------------------
 
-def _net(rng, n_in=6, n_neurons=12, decay_rate=0.25, reset="zero"):
+def _net(rng, n_in=6, n_neurons=12, decay_rate=0.25, reset="zero",
+         neuron="lif"):
     W = ((rng.random((n_in + n_neurons, n_neurons)) < 0.4)
          * rng.normal(0.0, 0.5, (n_in + n_neurons, n_neurons)))
     return SNNetwork(
         n_inputs=n_in, n_neurons=n_neurons, weights=W.astype(np.float32),
         params=LIFParams(decay_rate=decay_rate, threshold=1.0,
-                         reset_mode=reset),
+                         reset_mode=reset,
+                         syn_decay_rate=0.75 if neuron == "cuba" else None),
         output_slice=(n_neurons - 4, n_neurons))
 
 
-def test_session_redeploy_preserves_live_streams(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_session_redeploy_preserves_live_streams(rng, neuron):
     """deploy() mid-stream is a rolling redeploy: the live stream's carry
     rides the session connector across the fused-layout change and the
     spliced outputs equal an uninterrupted single-model run."""
-    netA, netB = _net(rng), _net(rng, n_in=5, n_neurons=10)
+    netA = _net(rng, neuron=neuron)
+    netB = _net(rng, n_in=5, n_neurons=10, neuron=neuron)
     ext = (rng.random((12, 6)) < 0.4).astype(np.int32)
 
     solo = AcceleratorSession()
@@ -300,15 +335,16 @@ def test_session_redeploy_preserves_live_streams(rng):
     assert view2.server.streams[uid].steps == 12
 
 
-def test_session_redeploy_keeps_waiting_streams_waiting(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_session_redeploy_keeps_waiting_streams_waiting(rng, neuron):
     """A stream still queued for a slot has no carry; the redeploy must
     re-queue it (not drop it, not fabricate state)."""
     sess = AcceleratorSession()
-    sess.deploy("A", _net(rng))
+    sess.deploy("A", _net(rng, neuron=neuron))
     view = sess.serve("A", n_slots=1, chunk_steps=4)
     view.attach("holder")
     view.attach("waiter")           # n_slots=1: this one queues
-    sess.deploy("B", _net(rng, n_in=5, n_neurons=10))
+    sess.deploy("B", _net(rng, n_in=5, n_neurons=10, neuron=neuron))
     view2 = sess.serve("A", n_slots=1, chunk_steps=4)
     srv = view2.server
     assert srv.slot_of("holder") == 0
@@ -319,14 +355,15 @@ def test_session_redeploy_keeps_waiting_streams_waiting(rng):
 # crash recovery: file-backed checkpoints outlive the server
 # --------------------------------------------------------------------------
 
-def test_crash_recovery_from_file_checkpoint(rng, tmp_path):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_crash_recovery_from_file_checkpoint(rng, tmp_path, neuron):
     """Kill the server after a checkpoint barrier; a NEW connector
     instance over the same directory rebuilds every stream on a fresh
     server, bit-clean — including counters."""
-    e = _engine(rng, reset="subtract")
+    e = _engine(rng, reset="subtract", neuron=neuron)
     ext = {u: _raster(rng, 15, e.n_inputs) for u in ("x", "y")}
-    want = {u: np.asarray(e.run(r[:, None, :])["spikes"])[:, 0]
-            for u, r in ext.items()}
+    ref = {u: e.run(r[:, None, :]) for u, r in ext.items()}
+    want = {u: np.asarray(r["spikes"])[:, 0] for u, r in ref.items()}
 
     root = str(tmp_path / "wal")
     server = SpikeServer(e, n_slots=3, chunk_steps=5)
@@ -346,14 +383,17 @@ def test_crash_recovery_from_file_checkpoint(rng, tmp_path):
                               np.asarray(second[u]["spikes"])])
         np.testing.assert_array_equal(got, want[u])
         assert recovered.streams[u].steps == steps_before[u] + 7
+        assert_slot_state(recovered, u, ref[u])
 
 
-def test_checkpoint_is_nondestructive(rng, tmp_path):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_checkpoint_is_nondestructive(rng, tmp_path, neuron):
     """checkpoint_streams is a write barrier, not a drain: the source
     server keeps streaming identically afterwards."""
-    e = _engine(rng)
+    e = _engine(rng, neuron=neuron)
     ext = _raster(rng, 10, e.n_inputs)
-    want = np.asarray(e.run(ext[:, None, :])["spikes"])[:, 0]
+    ref = e.run(ext[:, None, :])
+    want = np.asarray(ref["spikes"])[:, 0]
     server = SpikeServer(e, n_slots=2, chunk_steps=4)
     uid = server.attach()
     first = server.feed({uid: ext[:4]})[uid]["spikes"]
@@ -361,11 +401,13 @@ def test_checkpoint_is_nondestructive(rng, tmp_path):
     second = server.feed({uid: ext[4:]})[uid]["spikes"]
     np.testing.assert_array_equal(
         np.concatenate([np.asarray(first), np.asarray(second)]), want)
+    assert_slot_state(server, uid, ref)
 
 
-def test_restore_streams_restores_what_fits(rng, tmp_path):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_restore_streams_restores_what_fits(rng, tmp_path, neuron):
     conn = FileCarryConnector(str(tmp_path / "c"))
-    e = _engine(rng)
+    e = _engine(rng, neuron=neuron)
     src = SpikeServer(e, n_slots=4, chunk_steps=4)
     for u in ("a", "b", "c"):
         src.attach(u)
@@ -383,26 +425,30 @@ def test_restore_streams_restores_what_fits(rng, tmp_path):
 # restore-side safety: refused before any state mutates
 # --------------------------------------------------------------------------
 
-def test_attach_stream_rejects_incompatible_server(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_attach_stream_rejects_incompatible_server(rng, neuron):
     """A snapshot from a 16-neuron subtract engine must not land on a
     32-neuron or zero-reset server — and the refusal leaves the target
     completely untouched."""
-    src = SpikeServer(_engine(rng), n_slots=2, chunk_steps=4)
+    src = SpikeServer(_engine(rng, neuron=neuron), n_slots=2, chunk_steps=4)
     uid = src.attach()
     src.feed({uid: _raster(rng, 4, src.engine.n_inputs)})
     snap = src.snapshot_stream(uid)
 
-    for bad_engine, field in ((_engine(rng, n_phys=32), "n_phys"),
-                              (_engine(rng, reset="zero"), "reset_mode")):
+    for bad_engine, field in (
+            (_engine(rng, n_phys=32, neuron=neuron), "n_phys"),
+            (_engine(rng, reset="zero", neuron=neuron), "reset_mode")):
         dst = SpikeServer(bad_engine, n_slots=2, chunk_steps=4)
         with pytest.raises(ValueError, match=field):
             dst.attach_stream(snap)
         assert not dst.streams and dst.scheduler.free_slots == 2
-        assert not np.asarray(dst.carry["v"]).any()
+        for k in dst.carry:
+            assert not np.asarray(dst.carry[k]).any(), k
 
 
-def test_attach_stream_requires_free_slot(rng):
-    e = _engine(rng)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_attach_stream_requires_free_slot(rng, neuron):
+    e = _engine(rng, neuron=neuron)
     src = SpikeServer(e, n_slots=2, chunk_steps=4)
     uid = src.attach()
     src.feed({uid: _raster(rng, 3, e.n_inputs)})
@@ -433,7 +479,8 @@ def test_snapshot_waiting_stream_raises(rng):
         server.snapshot_stream("waiter")
 
 
-def test_traced_migration_hop_reconstructs_violation_free(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_traced_migration_hop_reconstructs_violation_free(rng, neuron):
     """Lifecycle audit riding the migration contract: both servers share
     one SpanTracer through attach / feed / cross-server hop /
     intra-server migrate_stream / retire, and the timeline
@@ -444,7 +491,7 @@ def test_traced_migration_hop_reconstructs_violation_free(rng):
     from repro.obs.timeline import reconstruct
 
     tracer = SpanTracer()
-    e = _engine(rng)
+    e = _engine(rng, neuron=neuron)
     conn = InMemoryCarryConnector()
     a = SpikeServer(e, n_slots=3, chunk_steps=5, tracer=tracer)
     b = SpikeServer(e, n_slots=4, chunk_steps=3, tracer=tracer)
@@ -456,9 +503,11 @@ def test_traced_migration_hop_reconstructs_violation_free(rng):
     b.attach_stream(conn, uid)                # resumed admit on B
     migrate_stream(b, uid, slot=3)            # address change on B
     second = b.feed({uid: ext[6:]})[uid]["spikes"]
+    ref = e.run(ext[:, None, :])
+    assert_slot_state(b, uid, ref)
     b.detach(uid, reason="done")
 
-    want = np.asarray(e.run(ext[:, None, :])["spikes"])[:, 0]
+    want = np.asarray(ref["spikes"])[:, 0]
     got = np.concatenate([np.asarray(first), np.asarray(second)])
     np.testing.assert_array_equal(got, want)  # audit never bends bytes
 

@@ -2,7 +2,7 @@
 
 import numpy as np
 
-from repro.data import lm, mnist
+from repro.data import mnist
 
 
 def test_render_digits_range_and_determinism():
@@ -51,26 +51,3 @@ def test_load_or_generate_contract():
     np.testing.assert_array_equal(x, x2)
     xt, _ = mnist.load_or_generate("train", 32, seed=0)
     assert not np.array_equal(x, xt)  # splits differ
-
-
-def test_lm_stream_properties():
-    vocab = 101
-    got = list(lm.lm_batches(vocab, 4, 32, 3, seed=2))
-    assert len(got) == 3
-    for _, toks, tgts in got:
-        assert toks.shape == (4, 32) and tgts.shape == (4, 32)
-        assert toks.min() >= 0 and toks.max() < vocab
-        np.testing.assert_array_equal(toks[:, 1:], tgts[:, :-1])
-    # resumability
-    resumed = list(lm.lm_batches(vocab, 4, 32, 3, seed=2, start_step=2))
-    np.testing.assert_array_equal(got[2][1], resumed[0][1])
-
-
-def test_lm_stream_is_learnable():
-    """Second-order structure: the same (prev2, prev) context yields the
-    same 'structured' next token (most of the time)."""
-    stream = lm.TokenStream(97, seed=0, structure=1.0)
-    toks = stream.sample(2, 64, step=0)
-    nxt = stream._hash_next(toks[:, 1:-1].ravel(), toks[:, :-2].ravel())
-    match = (nxt == toks[:, 2:].ravel()).mean()
-    assert match == 1.0  # structure=1.0 -> fully deterministic transitions

@@ -17,6 +17,11 @@ The load-bearing claims pinned here:
     kernel DMAs by (``ops.ext_gate_activity``) count exactly the blocks
     the ``events.trace`` window-OR model counts, and per-step fused
     traffic at dense activity is exactly 1/K of the unfused kernel's.
+
+The identity, masked-chunk and re-hosting legs run for both neuron models
+(``neuron``): one-state LIF, and current-based LIF through the fused
+kernel's two-state variant, whose synaptic current ``i`` rides the window
+beside ``v``.
 """
 
 import jax.numpy as jnp
@@ -33,6 +38,7 @@ from repro.kernels import ops
 THRESH = 1 << 16
 RESET_MODES = ("zero", "subtract", "hold")
 FUSED_BACKENDS = tuple(b for b in BACKENDS if b != "reference")
+NEURONS = ("lif", "cuba")
 
 
 def _weights(rng, n_in, n_phys, density=0.3, wmax=1 << 13):
@@ -47,14 +53,17 @@ def _raster(rng, T, B, S, density=0.3):
 
 
 def _engine(W, n_in, *, backend="reference", gate="batch-tile",
-            reset="zero", K=1):
+            reset="zero", K=1, neuron="lif"):
+    syn = DecaySpec.shift(0.75) if neuron == "cuba" else None
     return SpikeEngine(W, n_in, decay=DecaySpec.shift(0.25),
                        threshold_raw=THRESH, reset_mode=reset,
-                       backend=backend, gate=gate, fuse_steps=K)
+                       backend=backend, gate=gate, fuse_steps=K,
+                       syn_decay=syn)
 
 
 def _assert_run_identical(a, b):
-    for k in ("spikes", "v_final"):
+    assert a.keys() == b.keys()
+    for k in set(a) - {"events"}:
         av, bv = np.asarray(a[k]), np.asarray(b[k])
         assert av.dtype == bv.dtype == np.int32
         np.testing.assert_array_equal(av, bv)
@@ -64,29 +73,33 @@ def _assert_run_identical(a, b):
 # run identity: fused == unfused, fast leg + full slow sweep
 # --------------------------------------------------------------------------
 
-def test_fused_run_identity_fast(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_fused_run_identity_fast(rng, neuron):
     """One combo per fused backend x gate at K=4, T ragged (10 = 2.5
     windows) — the always-on identity check."""
     W = _weights(rng, 37, 48)
     ext = _raster(rng, 10, 3, 37)
-    want = _engine(W, 37).run(ext)
+    want = _engine(W, 37, neuron=neuron).run(ext)
     for backend in FUSED_BACKENDS:
         for gate in GATES:
-            got = _engine(W, 37, backend=backend, gate=gate, K=4).run(ext)
+            got = _engine(W, 37, backend=backend, gate=gate, K=4,
+                          neuron=neuron).run(ext)
             _assert_run_identical(want, got)
 
 
-def test_fused_run_identity_k1_path(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_fused_run_identity_k1_path(rng, neuron):
     """K=1 never routes through the fused kernel but must also match."""
     W = _weights(rng, 20, 40)
     ext = _raster(rng, 5, 2, 20)
-    want = _engine(W, 20).run(ext)
+    want = _engine(W, 20, neuron=neuron).run(ext)
     for backend in FUSED_BACKENDS:
-        _assert_run_identical(want, _engine(W, 20, backend=backend,
-                                            K=1).run(ext))
+        _assert_run_identical(want, _engine(W, 20, backend=backend, K=1,
+                                            neuron=neuron).run(ext))
 
 
-def test_fused_run_identity_edge_shapes(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_fused_run_identity_edge_shapes(rng, neuron):
     """Window edges: T < K (one padded window), T == K, B=1, and a
     source axis wider than one 128-block."""
     cases = [
@@ -98,8 +111,9 @@ def test_fused_run_identity_edge_shapes(rng):
     for T, B, n_in, n_phys, K in cases:
         W = _weights(rng, n_in, n_phys)
         ext = _raster(rng, T, B, n_in)
-        want = _engine(W, n_in).run(ext)
-        got = _engine(W, n_in, backend="pallas", K=K).run(ext)
+        want = _engine(W, n_in, neuron=neuron).run(ext)
+        got = _engine(W, n_in, backend="pallas", K=K,
+                      neuron=neuron).run(ext)
         _assert_run_identical(want, got)
 
 
@@ -122,13 +136,14 @@ def test_fused_run_identity_full_sweep(rng):
 # masked step_chunk: ragged remainders inside and across windows
 # --------------------------------------------------------------------------
 
-def test_fused_step_chunk_masked_identity(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_fused_step_chunk_masked_identity(rng, neuron):
     """Chunks of 5 steps under K=4 (every window ragged or masked):
     active slots advance exactly as the reference chunk, inactive slots
     keep their carry bit-for-bit, chained across chunks."""
     W = _weights(rng, 30, 40)
-    ref = _engine(W, 30)
-    fused = _engine(W, 30, backend="pallas", K=4)
+    ref = _engine(W, 30, neuron=neuron)
+    fused = _engine(W, 30, backend="pallas", K=4, neuron=neuron)
     c1, c2 = ref.init_carry(4), fused.init_carry(4)
     for _ in range(3):
         ext = _raster(rng, 5, 4, 30, 0.35)
@@ -136,7 +151,8 @@ def test_fused_step_chunk_masked_identity(rng):
         c1, s1 = ref.step_chunk(c1, ext, act)
         c2, s2 = fused.step_chunk(c2, ext, act)
         np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
-        for k in ("v", "spikes"):
+        assert c1.keys() == c2.keys() == set(ref.carry_keys)
+        for k in c1:
             np.testing.assert_array_equal(np.asarray(c1[k]),
                                           np.asarray(c2[k]))
 
@@ -166,16 +182,21 @@ def test_fused_step_chunk_masked_sweep(rng):
 # re-hosting carries K: with_gate / with_fuse_steps / to_mesh
 # --------------------------------------------------------------------------
 
-def test_with_fuse_steps_rehosting(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_with_fuse_steps_rehosting(rng, neuron):
     W = _weights(rng, 20, 40)
-    e = _engine(W, 20, backend="pallas", gate="per-example", K=1)
+    e = _engine(W, 20, backend="pallas", gate="per-example", K=1,
+                neuron=neuron)
     assert e.with_fuse_steps(1) is e
     e4 = e.with_fuse_steps(4)
     assert (e4.fuse_steps, e4.gate, e4.backend) == (4, "per-example",
                                                     "pallas")
-    # and the other re-hosts preserve K
+    # and the other re-hosts preserve K, and the neuron model with it
     assert e4.with_gate("batch-tile").fuse_steps == 4
     assert e4.with_gate("per-example") is e4
+    for hosted in (e4, e4.with_gate("batch-tile")):
+        assert hosted.syn_decay == e.syn_decay
+        assert hosted.carry_keys == e.carry_keys
 
 
 def test_fuse_steps_validation(rng):
@@ -186,14 +207,15 @@ def test_fuse_steps_validation(rng):
         mxu_partial_sum_bound(np.asarray(W), fuse_steps=0)
 
 
-def test_mesh_engine_carries_fuse_steps(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_mesh_engine_carries_fuse_steps(rng, neuron):
     """1x1 mesh (always available): to_mesh / with_gate / with_fuse_steps
     keep K, and the sharded fused run stays byte-identical."""
     mesh = make_spike_mesh(neuron=1, batch=1)
     W = _weights(rng, 30, 40)
     ext = _raster(rng, 6, 3, 30)
-    want = _engine(W, 30).run(ext)
-    fused = _engine(W, 30, backend="pallas", K=4)
+    want = _engine(W, 30, neuron=neuron).run(ext)
+    fused = _engine(W, 30, backend="pallas", K=4, neuron=neuron)
     sharded = fused.to_mesh(mesh)
     assert isinstance(sharded, MeshSpikeEngine)
     assert sharded.fuse_steps == 4

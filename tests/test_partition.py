@@ -1,47 +1,19 @@
-"""Partitioner: logical-axis registry coverage + spec validity + roofline
-HLO parsing."""
+"""Partitioner: logical axes resolve to mesh axes with divisibility
+fallbacks and no mesh-axis reuse."""
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-import pytest
 from jax.sharding import PartitionSpec
 
-from repro import configs
-from repro.configs.shapes import SHAPES, Shape
 from repro.distributed import partition as part
-from repro.launch.steps import LMHarness
-from repro.models.common import AXES, axes_of
-from repro.roofline import (
-    collective_bytes_from_hlo, model_flops, roofline_terms,
-)
 
 
 def _mesh():
     return jax.make_mesh((1, 1), ("data", "model"))
 
 
-@pytest.mark.parametrize("arch", configs.list_archs())
-def test_axes_registry_covers_every_param_leaf(arch):
-    """Every parameter leaf must resolve to logical axes of matching rank —
-    a missing AXES entry silently replicates a weight at 512 devices."""
-    mod = configs.get_arch(arch)
-    h = LMHarness(arch, cfg=mod.REDUCED)
-    shapes = h.param_shapes()
-    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
-    for path, leaf in flat:
-        key = "/".join(part._pstr(p) for p in path)
-        axes = axes_of(key, leaf)
-        assert len(axes) == leaf.ndim, (arch, key, leaf.shape, axes)
-        if leaf.ndim >= 2 and min(leaf.shape) >= 8 and "norm" not in key:
-            # big matrices must shard on at least one dim
-            assert any(a is not None for a in axes), (arch, key)
-
-
 def test_spec_for_divisibility_fallback():
     mesh = _mesh()
-    rules = part.PartitionRules(
-        rules={"heads": "model", "embed": "data"}, batch_axes=("data",))
+    rules = part.PartitionRules(rules={"heads": "model", "embed": "data"})
     # size-1 axes -> everything replicates (single device)
     spec = part.spec_for(("embed", "heads"), (64, 64), mesh, rules)
     assert spec == PartitionSpec()
@@ -53,79 +25,3 @@ def test_spec_for_no_axis_reuse():
     # both dims want 'model'; only one may take it (here size 1 -> neither)
     spec = part.spec_for(("a", "b"), (8, 8), mesh, rules)
     assert spec == PartitionSpec()
-
-
-def test_batch_partition_shapes():
-    mesh = _mesh()
-    rules = part.PartitionRules.default(mesh)
-    shapes = {
-        "tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32),
-        "mrope_positions": jax.ShapeDtypeStruct((3, 8, 16), jnp.int32),
-    }
-    sh = part.batch_partition(shapes, mesh, rules)
-    assert set(sh) == {"tokens", "mrope_positions"}
-
-
-@pytest.mark.parametrize("arch", ["granite-3-2b", "zamba2-1.2b", "rwkv6-7b",
-                                  "minicpm3-4b"])
-def test_cache_partition_covers_cache_leaves(arch):
-    mod = configs.get_arch(arch)
-    h = LMHarness(arch, cfg=mod.REDUCED)
-    mesh = _mesh()
-    rules = part.PartitionRules.default(mesh)
-    cache_shapes = jax.eval_shape(lambda: h.model.init_cache(4, 16))
-    sh = part.cache_partition(cache_shapes, mesh, rules)
-    assert (len(jax.tree.leaves(sh, is_leaf=lambda x: hasattr(x, "spec")))
-            == len(jax.tree.leaves(cache_shapes)))
-
-
-# ---------------------------------------------------------------------------
-# Roofline helpers
-# ---------------------------------------------------------------------------
-HLO_SNIPPET = """
-  %ar = f32[1024,256]{1,0} all-reduce(f32[1024,256]{1,0} %x), replica_groups={}
-  %ag.1 = bf16[64,128]{1,0} all-gather(bf16[8,128]{1,0} %y), dimensions={0}
-  %a2a = (f32[16]{0}, f32[16]{0}) all-to-all(f32[16]{0} %a, f32[16]{0} %b)
-  %cp-start = bf16[32]{0} collective-permute-start(bf16[32]{0} %z)
-  %rs = f32[128]{0} reduce-scatter(f32[1024]{0} %w), dimensions={0}
-"""
-
-
-def test_collective_bytes_parsing():
-    out = collective_bytes_from_hlo(HLO_SNIPPET)
-    assert out["counts"] == {"all-reduce": 1, "all-gather": 1,
-                             "all-to-all": 1, "collective-permute": 1,
-                             "reduce-scatter": 1}
-    ar = 1024 * 256 * 4
-    ag = 64 * 128 * 2
-    a2a = 2 * 16 * 4
-    cp = 32 * 2
-    rs = 128 * 4
-    assert out["bytes_by_kind"]["all-reduce"] == ar
-    assert out["total_bytes"] == ar * 2 + ag + a2a + cp + rs
-
-
-def test_roofline_terms_math():
-    cfg = configs.get_arch("granite-3-2b").CONFIG
-    shape = SHAPES["train_4k"]
-    t = roofline_terms(flops_per_device=197e12, bytes_per_device=819e9,
-                       collective_bytes_per_device=50e9, cfg=cfg,
-                       shape=shape, n_chips=256)
-    assert t["compute_s"] == pytest.approx(1.0)
-    assert t["memory_s"] == pytest.approx(1.0)
-    assert t["collective_s"] == pytest.approx(1.0)
-    mf = model_flops(cfg, shape, 256)
-    assert mf == pytest.approx(
-        6.0 * cfg.active_param_count() * 4096 * 256)
-    # decode counts one token per sequence
-    dec = model_flops(cfg, SHAPES["decode_32k"], 256)
-    assert dec == pytest.approx(2.0 * cfg.active_param_count() * 128)
-
-
-def test_dominant_term_selection():
-    cfg = configs.get_arch("granite-3-2b").CONFIG
-    t = roofline_terms(flops_per_device=1e12, bytes_per_device=819e9 * 5,
-                       collective_bytes_per_device=0.0, cfg=cfg,
-                       shape=SHAPES["train_4k"], n_chips=256)
-    assert t["dominant"] == "memory"
-    assert t["bound_s"] == pytest.approx(5.0)

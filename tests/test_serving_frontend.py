@@ -16,6 +16,10 @@ the stream instead of killing it: ``resume()`` must continue it
 byte-identically to a never-spilled run, cancel-while-parked must never
 touch the server, and the determinism property extends over the
 detach/attach (spill/resume) ops.
+
+The contracts that carry, zero or restore a slot's state run for both
+neuron models (``neuron``): one-state LIF and current-based LIF, whose
+synaptic current ``i`` lives in the slot beside ``v``.
 """
 
 import hypothesis
@@ -30,10 +34,11 @@ from repro.serving.frontend import (AsyncSpikeFrontend, FrontendConfig,
                                     latency_percentiles)
 from repro.serving.snn import SpikeServer
 
-from conftest import make_random_net
+from conftest import assert_slot_state, make_random_net
 
 THRESH = 1 << 16
 RESET_MODES = ("zero", "subtract", "hold")
+NEURONS = ("lif", "cuba")
 
 
 class VirtualClock:
@@ -47,13 +52,15 @@ class VirtualClock:
 
 
 def _engine(rng, *, backend="reference", reset="subtract", gate="batch-tile",
-            n_in=10, n_phys=16, wmax=1 << 13):
+            n_in=10, n_phys=16, wmax=1 << 13, neuron="lif"):
     S = n_in + n_phys
     W = ((rng.random((S, n_phys)) < 0.4)
          * rng.integers(-wmax, wmax, (S, n_phys)))
+    syn = DecaySpec.shift(0.75) if neuron == "cuba" else None
     return SpikeEngine(jnp.asarray(W, jnp.int32), n_in,
                        decay=DecaySpec.shift(0.25), threshold_raw=THRESH,
-                       reset_mode=reset, backend=backend, gate=gate)
+                       reset_mode=reset, backend=backend, gate=gate,
+                       syn_decay=syn)
 
 
 def _rasters(rng, lengths, n_in, p=0.35):
@@ -83,13 +90,16 @@ def _assert_async_equals_sync(engine, rng, *, n_slots=2, chunk_steps=3,
         assert "partial" not in h.result()
 
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("reset", RESET_MODES)
-def test_async_bit_identity_reference(rng, reset):
-    _assert_async_equals_sync(_engine(rng, reset=reset), rng)
+def test_async_bit_identity_reference(rng, reset, neuron):
+    _assert_async_equals_sync(_engine(rng, reset=reset, neuron=neuron), rng)
 
 
-def test_async_bit_identity_per_example_gate(rng):
-    _assert_async_equals_sync(_engine(rng, gate="per-example"), rng)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_async_bit_identity_per_example_gate(rng, neuron):
+    _assert_async_equals_sync(
+        _engine(rng, gate="per-example", neuron=neuron), rng)
 
 
 @pytest.mark.slow
@@ -101,10 +111,11 @@ def test_async_bit_identity_sweep(rng, backend, reset, gate):
     _assert_async_equals_sync(engine, rng)
 
 
-def test_async_matches_direct_feed_same_admission_order(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_async_matches_direct_feed_same_admission_order(rng, neuron):
     """The literal acceptance phrasing: replay the REALIZED admission
     order synchronously through ``SpikeServer.feed`` and compare bytes."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     rasters = _rasters(rng, (6, 3, 5), engine.n_inputs)
     server = SpikeServer(engine, n_slots=2, chunk_steps=2)
     fe = AsyncSpikeFrontend(server, queue_capacity=8)
@@ -143,8 +154,9 @@ def test_cancel_while_queued_never_touches_server(rng):
     assert a.state == "done"
 
 
-def test_cancel_mid_stream_keeps_partial_and_zeroes_carry(rng):
-    engine = _engine(rng)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_cancel_mid_stream_keeps_partial_and_zeroes_carry(rng, neuron):
+    engine = _engine(rng, neuron=neuron)
     server = SpikeServer(engine, n_slots=1, chunk_steps=2)
     fe = AsyncSpikeFrontend(server, queue_capacity=2)
     raster = _rasters(rng, (8,), engine.n_inputs)[0]
@@ -157,15 +169,17 @@ def test_cancel_mid_stream_keeps_partial_and_zeroes_carry(rng):
     want = np.asarray(engine.run(raster[:2, None, :])["spikes"])[:, 0]
     np.testing.assert_array_equal(res["spikes"], want)
     # eviction semantics: the freed slot is power-on clean
-    assert int(np.abs(np.asarray(server.carry["v"])).sum()) == 0
-    assert int(np.asarray(server.carry["spikes"]).sum()) == 0
+    assert set(server.carry) == set(engine.carry_keys)
+    for k in server.carry:
+        assert int(np.abs(np.asarray(server.carry[k])).sum()) == 0, k
 
 
-def test_deadline_expiry_queued_vs_mid_stream(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_deadline_expiry_queued_vs_mid_stream(rng, neuron):
     """A queued request past its deadline is refused; a running one is
     evicted with the slot carry zeroed like any eviction, and the next
     occupant powers up from clean state (byte-identical to a fresh run)."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     server = SpikeServer(engine, n_slots=1, chunk_steps=2)
     clock = VirtualClock()
     fe = AsyncSpikeFrontend(server, queue_capacity=4, clock=clock)
@@ -187,14 +201,15 @@ def test_deadline_expiry_queued_vs_mid_stream(rng):
     np.testing.assert_array_equal(c.result()["spikes"], want)
 
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("n_done", [1, 3, 4])
-def test_pump_round_zeroes_its_retirees_in_one_dispatch(rng, n_done):
+def test_pump_round_zeroes_its_retirees_in_one_dispatch(rng, n_done, neuron):
     """A round that retires n_done of 4 streams zeroes their slots with
     ONE dispatch (the registry's reset counters say so), and the queued
     requests that take those slots power up from zero."""
     from repro.obs import MetricsRegistry
 
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     reg = MetricsRegistry()
     server = SpikeServer(engine, n_slots=4, chunk_steps=2, metrics=reg)
     fe = AsyncSpikeFrontend(server, queue_capacity=8)
@@ -248,8 +263,9 @@ def test_backpressure_drop_oldest(rng):
     assert counts["dropped"] == 1 and counts["done"] == 1
 
 
-def test_backpressure_block_pumps_until_space(rng):
-    engine = _engine(rng)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_backpressure_block_pumps_until_space(rng, neuron):
+    engine = _engine(rng, neuron=neuron)
     server = SpikeServer(engine, n_slots=1, chunk_steps=4)
     fe = AsyncSpikeFrontend(server, queue_capacity=1, backpressure="block")
     ra, rb = _rasters(rng, (4, 4), engine.n_inputs)
@@ -312,8 +328,9 @@ def _run_scenario(engine, lengths, cancel_at, n_slots, chunk_steps,
     return trace, states, bytes_out
 
 
-def test_admission_determinism_deterministic_companion(rng):
-    engine = _engine(rng)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_admission_determinism_deterministic_companion(rng, neuron):
+    engine = _engine(rng, neuron=neuron)
     kw = dict(lengths=(5, 3, 7, 2, 6), cancel_at={2}, n_slots=2,
               chunk_steps=3, capacity=3, policy="drop-oldest")
     assert (_run_scenario(engine, **kw) == _run_scenario(engine, **kw))
@@ -346,10 +363,11 @@ def test_admission_determinism_property(seed, n_slots, chunk_steps,
 # Spill-on-evict: deadline expiry parks the carry, resume continues it
 # --------------------------------------------------------------------------
 
-def _spill_frontend(rng, *, n_slots=1, chunk_steps=2, capacity=4):
+def _spill_frontend(rng, *, n_slots=1, chunk_steps=2, capacity=4,
+                    neuron="lif"):
     from repro.serving.connector import InMemoryCarryConnector
 
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     server = SpikeServer(engine, n_slots=n_slots, chunk_steps=chunk_steps)
     clock = VirtualClock()
     conn = InMemoryCarryConnector()
@@ -358,11 +376,12 @@ def _spill_frontend(rng, *, n_slots=1, chunk_steps=2, capacity=4):
     return engine, server, clock, conn, fe
 
 
-def test_spill_resume_bit_clean(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_spill_resume_bit_clean(rng, neuron):
     """The spill contract: a mid-stream deadline eviction with a
     connector parks the carry; resume() finishes the stream and the FULL
     raster is byte-identical to a never-spilled run — no 'partial'."""
-    engine, server, clock, conn, fe = _spill_frontend(rng)
+    engine, server, clock, conn, fe = _spill_frontend(rng, neuron=neuron)
     raster = _rasters(rng, (10,), engine.n_inputs)[0]
     want = np.asarray(engine.run(raster[:, None, :])["spikes"])[:, 0]
 
@@ -374,9 +393,14 @@ def test_spill_resume_bit_clean(rng):
     assert h.state == "parked" and not h.done
     assert h.result() is None      # parked is NOT terminal
     assert len(conn) == 1 and server.scheduler.free_slots == 1
+    # the parked carry is every state after the 2 served steps
+    assert_slot_state(server, None, engine.run(raster[:2, None, :]),
+                      carry=conn.select(conn.stream_ids()[0]).arrays)
 
     assert fe.resume(h) is True
     assert h.state == "queued"
+    fe.pump()                      # resumed: 2 more steps from that carry
+    assert_slot_state(server, h._req.uid, engine.run(raster[:4, None, :]))
     fe.drain()
     assert h.state == "done"
     res = h.result()
@@ -388,11 +412,12 @@ def test_spill_resume_bit_clean(rng):
     assert c["expired_running"] == 0  # documented keys are always present
 
 
-def test_spill_interleaves_with_other_traffic(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_spill_interleaves_with_other_traffic(rng, neuron):
     """Another request runs in the spilled stream's slot between spill
     and resume — the resumed stream must still come back bit-clean (its
     state lived in the connector, not the slot)."""
-    engine, server, clock, conn, fe = _spill_frontend(rng)
+    engine, server, clock, conn, fe = _spill_frontend(rng, neuron=neuron)
     ra, rb = _rasters(rng, (8, 4), engine.n_inputs)
     want_a = np.asarray(engine.run(ra[:, None, :])["spikes"])[:, 0]
 
@@ -428,11 +453,13 @@ def test_cancel_while_parked_never_touches_server(rng):
     assert h.cancel() is False
 
 
-def test_parked_request_requeued_past_deadline_returns_to_parked(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_parked_request_requeued_past_deadline_returns_to_parked(rng,
+                                                                 neuron):
     """resume() arms a fresh deadline; if THAT passes while the request
     is still queued, it falls back to 'parked' (carry stays in the
     connector, no leak) and a later resume still finishes bit-clean."""
-    engine, server, clock, conn, fe = _spill_frontend(rng)
+    engine, server, clock, conn, fe = _spill_frontend(rng, neuron=neuron)
     raster = _rasters(rng, (8,), engine.n_inputs)[0]
     want = np.asarray(engine.run(raster[:, None, :])["spikes"])[:, 0]
 
@@ -461,8 +488,10 @@ def test_parked_request_requeued_past_deadline_returns_to_parked(rng):
     np.testing.assert_array_equal(h2.result()["spikes"], want)
 
 
-def test_resume_under_reject_backpressure_stays_parked(rng):
-    engine, server, clock, conn, fe = _spill_frontend(rng, capacity=1)
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_resume_under_reject_backpressure_stays_parked(rng, neuron):
+    engine, server, clock, conn, fe = _spill_frontend(rng, capacity=1,
+                                                      neuron=neuron)
     h = fe.submit(_rasters(rng, (8,), engine.n_inputs)[0], deadline_ms=500)
     fe.pump()
     clock.t = 1.0
@@ -480,7 +509,8 @@ def test_resume_under_reject_backpressure_stays_parked(rng):
     assert h.state == "done"
 
 
-def test_determinism_extends_over_spill_resume_ops(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_determinism_extends_over_spill_resume_ops(rng, neuron):
     """The determinism contract extended over detach/attach: with spill
     and resume in the op sequence, replaying it reproduces identical
     states, counts, and output bytes."""
@@ -488,7 +518,7 @@ def test_determinism_extends_over_spill_resume_ops(rng):
         from repro.serving.connector import InMemoryCarryConnector
 
         r = np.random.default_rng(13)
-        engine = _engine(np.random.default_rng(5))
+        engine = _engine(np.random.default_rng(5), neuron=neuron)
         server = SpikeServer(engine, n_slots=2, chunk_steps=2)
         clock = VirtualClock()
         fe = AsyncSpikeFrontend(server, queue_capacity=6, clock=clock,
@@ -520,7 +550,7 @@ def test_determinism_extends_over_spill_resume_ops(rng):
     assert counts["done"] == 5              # and everyone finished
     # every raster byte-identical to its never-spilled run
     r = np.random.default_rng(13)
-    engine = _engine(np.random.default_rng(5))
+    engine = _engine(np.random.default_rng(5), neuron=neuron)
     for raster, got in zip(_rasters(r, (9, 7, 8, 3, 6), engine.n_inputs),
                            outs):
         want = np.asarray(engine.run(raster[:, None, :])["spikes"])[:, 0]
@@ -531,10 +561,11 @@ def test_determinism_extends_over_spill_resume_ops(rng):
 # AER requests + session wiring
 # --------------------------------------------------------------------------
 
-def test_submit_events_round_trip(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_submit_events_round_trip(rng, neuron):
     from repro.events.aer import dense_to_aer
 
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     server = SpikeServer(engine, n_slots=2, chunk_steps=3)
     fe = AsyncSpikeFrontend(server)
     raster = _rasters(rng, (6,), engine.n_inputs)[0]
@@ -551,14 +582,17 @@ def test_submit_events_round_trip(rng):
     assert got_events.shape[1] == 3
 
 
-def test_session_serve_frontend_shared_and_bit_identical(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_session_serve_frontend_shared_and_bit_identical(rng, neuron):
     """Co-resident views share ONE frontend queue, and async view results
     are byte-identical to synchronous view feeds of the same rasters."""
+    syn = 0.75 if neuron == "cuba" else None
+
     def build():
         sess = AcceleratorSession()
         r = np.random.default_rng(3)
-        sess.deploy("a", make_random_net(r))
-        sess.deploy("b", make_random_net(r))
+        sess.deploy("a", make_random_net(r, syn_decay_rate=syn))
+        sess.deploy("b", make_random_net(r, syn_decay_rate=syn))
         return sess
 
     cfg = FrontendConfig(queue_capacity=8)
@@ -688,7 +722,8 @@ def test_metrics_shape_on_all_expired_run(rng):
     assert per["total"]["p50"] is None
 
 
-def test_traced_spill_flow_reconstructs_violation_free(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_traced_spill_flow_reconstructs_violation_free(rng, neuron):
     """Lifecycle audit over the representative front-door flow: server
     and frontend share one SpanTracer through submit / cancel-queued /
     queued-expiry / mid-stream spill / resume / drain, and the timeline
@@ -699,7 +734,7 @@ def test_traced_spill_flow_reconstructs_violation_free(rng):
     from repro.obs.timeline import reconstruct
     from repro.serving.connector import InMemoryCarryConnector
 
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     clock = VirtualClock()
     tracer = SpanTracer(clock=clock)
     server = SpikeServer(engine, n_slots=1, chunk_steps=2, tracer=tracer)

@@ -7,6 +7,10 @@ raster — streaming must be a pure re-chunking of the batch semantics,
 never a different numerical path. Plus the stream-lifecycle contract:
 attach/evict/re-attach churn in some slots leaves co-resident slots'
 state bit-for-bit untouched.
+
+The parity and lifecycle cases run for both neuron models (``neuron``):
+one-state LIF and current-based LIF, whose synaptic current ``i`` the
+slot carries, zeroes and feeds back beside ``v``.
 """
 
 import jax.numpy as jnp
@@ -20,18 +24,22 @@ from repro.core.network import SNNetwork
 from repro.core.session import AcceleratorSession
 from repro.serving.snn import SpikeServer
 
+from conftest import assert_slot_state
+
 THRESH = 1 << 16
 RESET_MODES = ("zero", "subtract", "hold")
+NEURONS = ("lif", "cuba")
 
 
 def _engine(rng, *, backend="reference", n_in=10, n_phys=16,
-            reset="subtract", decay=None, wmax=1 << 13):
+            reset="subtract", decay=None, wmax=1 << 13, neuron="lif"):
     S = n_in + n_phys
     W = (rng.random((S, n_phys)) < 0.4) * rng.integers(-wmax, wmax, (S, n_phys))
+    syn = DecaySpec.shift(0.75) if neuron == "cuba" else None
     return SpikeEngine(jnp.asarray(W, jnp.int32), n_in,
                        decay=decay or DecaySpec.shift(0.25),
                        threshold_raw=THRESH, reset_mode=reset,
-                       backend=backend)
+                       backend=backend, syn_decay=syn)
 
 
 def _raster(rng, T, n_in, p=0.35):
@@ -52,29 +60,33 @@ def _assert_stream_equals_batch(engine, rng, *, sizes=(2, 3, 1, 3),
                                 chunk_steps=3, n_slots=3):
     T = sum(sizes)
     raster = _raster(rng, T, engine.n_inputs)
-    want = np.asarray(engine.run(raster)["spikes"])[:, 0]
+    ref = engine.run(raster)
+    want = np.asarray(ref["spikes"])[:, 0]
     server = SpikeServer(engine, n_slots=n_slots, chunk_steps=chunk_steps)
     uid = server.attach()
     got = _feed_ragged(server, uid, raster[:, 0], sizes)
     assert got.dtype == want.dtype == np.int32  # byte-for-byte, not just ==
     np.testing.assert_array_equal(got, want)
+    assert_slot_state(server, uid, ref)
 
 
 # --------------------------------------------------------------------------
 # Parity: fast leg (reference backend; every reset mode; ragged chunking)
 # --------------------------------------------------------------------------
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("reset", RESET_MODES)
-def test_feed_chunked_parity_reference(rng, reset):
-    engine = _engine(rng, reset=reset)
+def test_feed_chunked_parity_reference(rng, reset, neuron):
+    engine = _engine(rng, reset=reset, neuron=neuron)
     _assert_stream_equals_batch(engine, rng)
 
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("sizes", [(9,), (1,) * 9, (4, 5), (1, 6, 2)])
-def test_feed_ragged_boundaries(rng, sizes):
+def test_feed_ragged_boundaries(rng, sizes, neuron):
     """Chunk boundaries anywhere — including chunk > chunk_steps (internal
     re-chunking) and T=1 dribble — never change a bit."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     _assert_stream_equals_batch(engine, rng, sizes=sizes)
 
 
@@ -101,10 +113,11 @@ def test_feed_parity_sweep(rng, backend, reset):
 # Lifecycle: churn isolation, eviction zeroing, admission queue
 # --------------------------------------------------------------------------
 
-def test_interleaved_streams_match_solo(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_interleaved_streams_match_solo(rng, neuron):
     """Two streams fed interleaved, ragged, and staggered: each equals its
     solo batch run (slots are independent lanes)."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     ra, rb = _raster(rng, 11, 10), _raster(rng, 11, 10, p=0.5)
     server = SpikeServer(engine, n_slots=4, chunk_steps=3)
     a, b = server.attach(), server.attach()
@@ -115,19 +128,22 @@ def test_interleaved_streams_match_solo(rng):
     ga.append(o[a]["spikes"]); gb.append(o[b]["spikes"])
     o = server.feed({b: rb[7:11, 0], a: ra[5:11, 0]})
     ga.append(o[a]["spikes"]); gb.append(o[b]["spikes"])
-    np.testing.assert_array_equal(np.concatenate(ga, 0),
-                                  np.asarray(engine.run(ra)["spikes"])[:, 0])
-    np.testing.assert_array_equal(np.concatenate(gb, 0),
-                                  np.asarray(engine.run(rb)["spikes"])[:, 0])
+    for uid, got, raster in ((a, ga, ra), (b, gb, rb)):
+        ref = engine.run(raster)
+        np.testing.assert_array_equal(np.concatenate(got, 0),
+                                      np.asarray(ref["spikes"])[:, 0])
+        assert_slot_state(server, uid, ref)
 
 
-def test_churn_leaves_coresident_slots_untouched(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_churn_leaves_coresident_slots_untouched(rng, neuron):
     """attach/evict/re-attach churn around a long-lived stream must not
     perturb it by a single bit."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     T = 12
     keeper_r = _raster(rng, T, 10)
-    want = np.asarray(engine.run(keeper_r)["spikes"])[:, 0]
+    ref = engine.run(keeper_r)
+    want = np.asarray(ref["spikes"])[:, 0]
     server = SpikeServer(engine, n_slots=3, chunk_steps=4)
     keeper = server.attach()
     got = []
@@ -140,20 +156,23 @@ def test_churn_leaves_coresident_slots_untouched(rng):
         got.append(server.feed({keeper: keeper_r[t:t + 1, 0]})[keeper]["spikes"])
         server.detach(trans)
     np.testing.assert_array_equal(np.concatenate(got, 0), want)
+    assert_slot_state(server, keeper, ref)
 
 
-def test_eviction_zeroes_carry_and_reattach_is_fresh(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_eviction_zeroes_carry_and_reattach_is_fresh(rng, neuron):
     """Detach zeroes the slot; the next occupant of the SAME slot powers
     up from the unified initial state (bit-identical to a fresh server)."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     raster = _raster(rng, 9, 10)
     want = np.asarray(engine.run(raster)["spikes"])[:, 0]
     server = SpikeServer(engine, n_slots=1, chunk_steps=4)
     a = server.attach()
     server.feed({a: (rng.random((7, 10)) < 0.5).astype(np.int32)})
     server.detach(a)
-    np.testing.assert_array_equal(np.asarray(server.carry["v"]), 0)
-    np.testing.assert_array_equal(np.asarray(server.carry["spikes"]), 0)
+    assert set(server.carry) == set(engine.carry_keys)
+    for k in server.carry:
+        np.testing.assert_array_equal(np.asarray(server.carry[k]), 0)
     b = server.attach()
     assert server.slot_of(b) == 0  # same physical slot, recycled
     got = _feed_ragged(server, b, raster[:, 0], (4, 5))
@@ -199,8 +218,10 @@ def _busy_server(engine, rng):
     return server
 
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("case", list(DETACH_MANY_CASES))
-def test_detach_many_equals_sequential_detach(rng, monkeypatch, case):
+def test_detach_many_equals_sequential_detach(rng, monkeypatch, case,
+                                              neuron):
     """One detach_many is a detach loop with one zeroing dispatch: the
     same carry bytes, slots, FIFO admissions and stats; freed slots read
     zero, the rest are untouched, and the streams admitted into freed
@@ -210,7 +231,7 @@ def test_detach_many_equals_sequential_detach(rng, monkeypatch, case):
     uids = DETACH_MANY_CASES[case]
     # an unknown uid raises where a detach loop would: after those before it
     done = uids[:uids.index("zz")] if "zz" in uids else uids
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     seq, one = _busy_server(engine, np.random.default_rng(7)), \
         _busy_server(engine, np.random.default_rng(7))
     before = {k: np.asarray(x) for k, x in one.carry.items()}
@@ -231,7 +252,8 @@ def test_detach_many_equals_sequential_detach(rng, monkeypatch, case):
     assert one.scheduler.waiting == seq.scheduler.waiting
     assert one.scheduler.free_slot_ids == seq.scheduler.free_slot_ids
     kept = [s for s in range(4) if s not in freed]
-    for k in ("v", "spikes"):
+    assert set(one.carry) == set(engine.carry_keys)
+    for k in one.carry:
         got = np.asarray(one.carry[k])
         assert got.dtype == before[k].dtype == np.int32
         assert got.tobytes() == np.asarray(seq.carry[k]).tobytes()
@@ -249,12 +271,14 @@ def test_detach_many_equals_sequential_detach(rng, monkeypatch, case):
         np.testing.assert_array_equal(out[uid]["spikes"], want)
 
 
-def test_zero_length_chunk_is_per_stream_noop(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_zero_length_chunk_is_per_stream_noop(rng, neuron):
     """T=0 chunks (an idle stream this round) return an empty raster and
     leave the carry untouched — mixed calls still serve the live streams."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     raster = _raster(rng, 8, 10)
-    want = np.asarray(engine.run(raster)["spikes"])[:, 0]
+    ref = engine.run(raster)
+    want = np.asarray(ref["spikes"])[:, 0]
     server = SpikeServer(engine, n_slots=2, chunk_steps=4)
     a, b = server.attach(), server.attach()
     empty = np.zeros((0, 10), np.int32)
@@ -267,6 +291,9 @@ def test_zero_length_chunk_is_per_stream_noop(rng):
         assert o[b]["spikes"].shape == (0, 16)
     np.testing.assert_array_equal(np.concatenate(got, 0), want)
     assert server.streams[b].steps == 0
+    assert_slot_state(server, a, ref)
+    for k in server.carry:          # the idle stream's slot never moved
+        assert not np.asarray(server.carry[k])[server.slot_of(b)].any(), k
 
 
 def _split_per_stream(rasters, streams, chunk_steps, n_phys):
@@ -361,10 +388,11 @@ def test_auto_uid_skips_caller_chosen_ids(rng):
     assert len({0, 2, auto1, auto2}) == 4
 
 
-def test_closed_loop_replay_matches_batch(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_closed_loop_replay_matches_batch(rng, neuron):
     """Closed-loop stepping with a controller that replays a fixed raster
     is the identity case: byte-identical to the batch scan."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     raster = _raster(rng, 8, 10)
     want = np.asarray(engine.run(raster)["spikes"])[:, 0]
     server = SpikeServer(engine, n_slots=2, chunk_steps=4)
@@ -401,21 +429,24 @@ def test_closed_loop_feedback_depends_on_output(rng):
 # Session entry: co-resident models stream together over the fused engine
 # --------------------------------------------------------------------------
 
-def _net(rng, n_in=6, n_neurons=12, decay_rate=0.25, reset="zero"):
+def _net(rng, n_in=6, n_neurons=12, decay_rate=0.25, reset="zero",
+         neuron="lif"):
     W = ((rng.random((n_in + n_neurons, n_neurons)) < 0.4)
          * rng.normal(0.0, 0.5, (n_in + n_neurons, n_neurons)))
     return SNNetwork(
         n_inputs=n_in, n_neurons=n_neurons, weights=W.astype(np.float32),
         params=LIFParams(decay_rate=decay_rate, threshold=1.0,
-                         reset_mode=reset),
+                         reset_mode=reset,
+                         syn_decay_rate=0.75 if neuron == "cuba" else None),
         output_slice=(n_neurons - 4, n_neurons))
 
 
-def test_session_serve_matches_batch_run(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_session_serve_matches_batch_run(rng, neuron):
     """session.serve streaming output == session.run (same key, same
     encoder) for a resident model — counts and predictions identical."""
     sess = AcceleratorSession()
-    sess.deploy("m", _net(rng))
+    sess.deploy("m", _net(rng, neuron=neuron))
     import jax
     key = jax.random.key(7)
     intensities = rng.random((1, 6)).astype(np.float32)
@@ -434,10 +465,12 @@ def test_session_serve_matches_batch_run(rng):
     np.testing.assert_array_equal(spikes, np.asarray(want["spikes"])[:, 0])
 
 
-def test_coresident_models_share_one_server(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_coresident_models_share_one_server(rng, neuron):
     """Models with one LIF config stream through ONE fused-engine server;
     each stream's decode equals its solo deployment, concurrently."""
-    netA, netB = _net(rng), _net(rng, n_in=5, n_neurons=10)
+    netA = _net(rng, neuron=neuron)
+    netB = _net(rng, n_in=5, n_neurons=10, neuron=neuron)
     sess = AcceleratorSession()
     sess.deploy("A", netA)
     sess.deploy("B", netB)
@@ -530,10 +563,11 @@ def test_stale_view_raises_after_deploy(rng):
     fresh.feed(uid2, np.zeros((2, 6), np.int32))
 
 
-def test_feed_many_single_dispatch_matches_per_stream(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_feed_many_single_dispatch_matches_per_stream(rng, neuron):
     """Batched feed_many over several of a model's streams equals the
     per-stream feed results (one slot-batch dispatch, same bits)."""
-    net = _net(rng)
+    net = _net(rng, neuron=neuron)
     sess_a = AcceleratorSession()
     sess_a.deploy("m", net)
     sess_b = AcceleratorSession()
@@ -552,11 +586,12 @@ def test_feed_many_single_dispatch_matches_per_stream(rng):
                                   solo[b1]["output_counts"])
 
 
-def test_model_stream_closed_loop_replay(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_model_stream_closed_loop_replay(rng, neuron):
     """ModelStream.run_closed_loop (session-level closed loop): replaying
     a fixed encoder stream equals the batch run of the same raster."""
     sess = AcceleratorSession()
-    model = sess.deploy("m", _net(rng))
+    model = sess.deploy("m", _net(rng, neuron=neuron))
     stream = sess.serve("m", n_slots=2, chunk_steps=4)
     uid = stream.attach()
     raster = (rng.random((6, 6)) < 0.4).astype(np.int32)
@@ -590,16 +625,18 @@ def test_step_chunk_shape_validation(rng):
                           np.zeros((3, 3), np.int32))
 
 
-def test_step_chunk_all_active_equals_run(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_step_chunk_all_active_equals_run(rng, neuron):
     """active=None (or all-ones) is exactly the batch scan."""
-    engine = _engine(rng)
+    engine = _engine(rng, neuron=neuron)
     ext = (rng.random((6, 4, 10)) < 0.4).astype(np.int32)
     want = engine.run(ext)
     carry, spikes = engine.step_chunk(engine.init_carry(4), ext)
     np.testing.assert_array_equal(np.asarray(spikes),
                                   np.asarray(want["spikes"]))
-    np.testing.assert_array_equal(np.asarray(carry["v"]),
-                                  np.asarray(want["v_final"]))
+    for k in set(carry) - {"spikes"}:
+        np.testing.assert_array_equal(np.asarray(carry[k]),
+                                      np.asarray(want[f"{k}_final"]))
 
 
 def test_step_chunk_jit_cache_reused(rng):

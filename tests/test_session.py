@@ -1,22 +1,33 @@
 """Multi-model co-residency (paper §V-D): address-space isolation AND
-true fusion — run_all advances all resident models in one engine scan."""
+true fusion — run_all advances all resident models in one engine scan.
+
+The fusion and isolation cases run for both neuron models (``neuron``):
+one-state LIF and current-based LIF (``syn_decay_rate``)."""
 
 import jax
 import numpy as np
 import pytest
 
-from repro.core import cerebra_h
 from repro.core.engine import SpikeEngine
 from repro.core.session import AcceleratorSession
 
 from conftest import make_ff_net
 
+NEURONS = ("lif", "cuba")
 
-def test_co_residency_isolation(rng):
+
+def _syn(neuron):
+    """The current-based networks' synaptic decay rate (None: LIF)."""
+    return 0.75 if neuron == "cuba" else None
+
+
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_co_residency_isolation(rng, neuron):
     """A model's outputs are identical whether it runs alone or alongside
     other resident models — disjoint clusters + rows = no interference."""
-    netA = make_ff_net(rng, sizes=(12, 40, 10))
-    netB = make_ff_net(rng, sizes=(8, 30, 5), scale=0.9)
+    syn = _syn(neuron)
+    netA = make_ff_net(rng, sizes=(12, 40, 10), syn_decay_rate=syn)
+    netB = make_ff_net(rng, sizes=(8, 30, 5), scale=0.9, syn_decay_rate=syn)
     key = jax.random.key(0)
     xA = rng.random((6, 12)).astype(np.float32)
     xB = rng.random((6, 8)).astype(np.float32)
@@ -38,13 +49,16 @@ def test_co_residency_isolation(rng):
         np.asarray(outs["A"]["output_counts"]))
 
 
-def test_run_all_is_one_fused_scan(rng, monkeypatch):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_run_all_is_one_fused_scan(rng, monkeypatch, neuron):
     """N co-resident models with a shared LIF config advance in EXACTLY one
     SpikeEngine scan — no per-model Python loop over run()."""
+    syn = _syn(neuron)
     sess = AcceleratorSession()
-    sess.deploy("A", make_ff_net(rng, sizes=(12, 40, 10)))
-    sess.deploy("B", make_ff_net(rng, sizes=(8, 30, 5), scale=0.9))
-    sess.deploy("C", make_ff_net(rng, sizes=(6, 20, 4)))
+    sess.deploy("A", make_ff_net(rng, sizes=(12, 40, 10), syn_decay_rate=syn))
+    sess.deploy("B", make_ff_net(rng, sizes=(8, 30, 5), scale=0.9,
+                                 syn_decay_rate=syn))
+    sess.deploy("C", make_ff_net(rng, sizes=(6, 20, 4), syn_decay_rate=syn))
 
     scans = []
     orig_run = SpikeEngine.run
@@ -64,13 +78,17 @@ def test_run_all_is_one_fused_scan(rng, monkeypatch):
     assert set(outs) == {"A", "B", "C"}
     # the fused engine covers the concatenated external sources
     assert scans[0].n_inputs == 12 + 8 + 6
+    assert scans[0].has_current == (syn is not None)
 
 
-def test_run_all_isolation_bit_exact_per_model(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_run_all_isolation_bit_exact_per_model(rng, neuron):
     """Every co-resident model (not just the first) decodes identically to
     its solo deployment at the same placement."""
-    nets = {"A": make_ff_net(rng, sizes=(12, 40, 10)),
-            "B": make_ff_net(rng, sizes=(8, 30, 5), scale=0.9)}
+    syn = _syn(neuron)
+    nets = {"A": make_ff_net(rng, sizes=(12, 40, 10), syn_decay_rate=syn),
+            "B": make_ff_net(rng, sizes=(8, 30, 5), scale=0.9,
+                             syn_decay_rate=syn)}
     key = jax.random.key(3)
     xs = {"A": rng.random((5, 12)).astype(np.float32),
           "B": rng.random((5, 8)).astype(np.float32)}
@@ -94,12 +112,16 @@ def test_run_all_isolation_bit_exact_per_model(rng):
                                       np.asarray(outs["B"][k]))
 
 
-def test_run_all_mixed_lif_configs_still_fused_per_group(rng, monkeypatch):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_run_all_mixed_lif_configs_still_fused_per_group(rng, monkeypatch,
+                                                         neuron):
     """Models with different LIF configs form separate fused groups (the
     hardware's per-configuration register banks) — still no per-model
     loop, and outputs still match solo deployment."""
-    netA = make_ff_net(rng, sizes=(10, 30, 6))
-    netB = make_ff_net(rng, sizes=(8, 20, 4), decay_rate=0.5)
+    syn = _syn(neuron)
+    netA = make_ff_net(rng, sizes=(10, 30, 6), syn_decay_rate=syn)
+    netB = make_ff_net(rng, sizes=(8, 20, 4), decay_rate=0.5,
+                       syn_decay_rate=syn)
     sess = AcceleratorSession()
     sess.deploy("A", netA)
     sess.deploy("B", netB)

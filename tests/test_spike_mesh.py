@@ -10,6 +10,10 @@ fused multi-model ``run_all``, and streaming ``feed()`` through a
 sharded ``SpikeServer``. On a single-device run (the plain tier-1 leg)
 the multi-device cases skip and the degenerate 1x1-mesh cases still
 exercise the shard_map path end to end.
+
+The served-path parity cases run for both neuron models (``neuron``):
+one-state LIF and current-based LIF, whose synaptic current ``i`` shards
+like ``v``.
 """
 
 import os
@@ -29,6 +33,7 @@ from conftest import make_random_net
 
 THRESH = 1 << 16
 RESET_MODES = ("zero", "subtract", "hold")
+NEURONS = ("lif", "cuba")
 
 # deliberately ragged: neither n_phys nor B divides a 2-way mesh axis
 RAGGED_SHAPES = [
@@ -50,13 +55,14 @@ def _mesh(neuron, batch):
 
 def _engine_pair(rng, *, backend="reference", reset="subtract", decay=None,
                  B=4, n_in=37, n_phys=48, mesh=None, density=0.3,
-                 wmax=1 << 13):
+                 wmax=1 << 13, neuron="lif"):
     S = n_in + n_phys
     W = jnp.asarray(
         (rng.random((S, n_phys)) < density)
         * rng.integers(-wmax, wmax, (S, n_phys)), jnp.int32)
     kw = dict(decay=decay or DecaySpec.shift(0.25), threshold_raw=THRESH,
-              reset_mode=reset, backend=backend)
+              reset_mode=reset, backend=backend,
+              syn_decay=DecaySpec.shift(0.75) if neuron == "cuba" else None)
     single = SpikeEngine(W, n_in, **kw)
     sharded = MeshSpikeEngine(W, n_in, mesh=mesh, **kw)
     return single, sharded
@@ -65,7 +71,8 @@ def _engine_pair(rng, *, backend="reference", reset="subtract", decay=None,
 def _assert_run_parity(single, sharded, ext):
     a = single.run(ext)
     b = sharded.run(ext)
-    for k in ("spikes", "v_final"):
+    assert a.keys() == b.keys()
+    for k in a:
         av, bv = np.asarray(a[k]), np.asarray(b[k])
         assert av.dtype == bv.dtype == np.int32
         np.testing.assert_array_equal(av, bv)
@@ -90,15 +97,17 @@ def test_mesh_engine_requires_snn_axes(rng):
         _engine_pair(rng, mesh=wrong)
 
 
-def test_to_mesh_is_drop_in(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_to_mesh_is_drop_in(rng, neuron):
     """`engine.to_mesh(mesh)` re-hosts the same program: same config, a
     MeshSpikeEngine, and bit-identical outputs."""
     mesh = make_spike_mesh(neuron=1, batch=1)
-    single, _ = _engine_pair(rng, mesh=mesh)
+    single, _ = _engine_pair(rng, mesh=mesh, neuron=neuron)
     hosted = single.to_mesh(mesh)
     assert isinstance(hosted, MeshSpikeEngine)
     assert hosted.reset_mode == single.reset_mode
     assert hosted.n_phys == single.n_phys
+    assert hosted.carry_keys == single.carry_keys
     ext = (np.random.default_rng(1).random((5, 3, single.n_inputs))
            < 0.35).astype(np.int32)
     _assert_run_parity(single, hosted, ext)
@@ -118,37 +127,43 @@ def test_server_mesh_kwarg_rehosts_engine(rng):
 # Degenerate 1x1 mesh: the shard_map path runs in every environment
 # --------------------------------------------------------------------------
 
-def test_degenerate_mesh_run_parity(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_degenerate_mesh_run_parity(rng, neuron):
     mesh = make_spike_mesh(neuron=1, batch=1)
-    single, sharded = _engine_pair(rng, mesh=mesh)
+    single, sharded = _engine_pair(rng, mesh=mesh, neuron=neuron)
     ext = (rng.random((6, 5, single.n_inputs)) < 0.35).astype(np.int32)
     _assert_run_parity(single, sharded, ext)
 
 
-def test_degenerate_mesh_single_step_parity(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_degenerate_mesh_single_step_parity(rng, neuron):
     """`step` on the mesh engine routes through the sharded path and
     matches the single-device step bit-for-bit."""
     mesh = make_spike_mesh(neuron=1, batch=1)
-    single, sharded = _engine_pair(rng, mesh=mesh)
+    single, sharded = _engine_pair(rng, mesh=mesh, neuron=neuron)
     carry = single.init_carry(3)
     ext_t = (rng.random((3, single.n_inputs)) < 0.4).astype(np.int32)
     c1, s1 = single.step(carry, jnp.asarray(ext_t))
     c2, s2 = sharded.step(carry, jnp.asarray(ext_t))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
-    for k in ("v", "spikes"):
+    assert c1.keys() == c2.keys() == set(single.carry_keys)
+    for k in c1:
         np.testing.assert_array_equal(np.asarray(c1[k]), np.asarray(c2[k]))
 
 
-def test_degenerate_mesh_chunk_parity(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_degenerate_mesh_chunk_parity(rng, neuron):
     mesh = make_spike_mesh(neuron=1, batch=1)
-    single, sharded = _engine_pair(rng, mesh=mesh, reset="zero")
+    single, sharded = _engine_pair(rng, mesh=mesh, reset="zero",
+                                   neuron=neuron)
     carry = single.init_carry(3)
     ext = (rng.random((4, 3, single.n_inputs)) < 0.35).astype(np.int32)
     act = (rng.random((4, 3)) < 0.6).astype(np.int32)
     c1, s1 = single.step_chunk(carry, ext, act)
     c2, s2 = sharded.step_chunk(carry, ext, act)
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
-    for k in ("v", "spikes"):
+    assert c1.keys() == c2.keys() == set(single.carry_keys)
+    for k in c1:
         np.testing.assert_array_equal(np.asarray(c1[k]), np.asarray(c2[k]))
 
 
@@ -215,12 +230,14 @@ def test_mesh_parity_wide_mesh_uses_all_devices(rng):
 # step_chunk masked-slot semantics on the mesh
 # --------------------------------------------------------------------------
 
-def test_mesh_step_chunk_masked_slots(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_mesh_step_chunk_masked_slots(rng, neuron):
     """Inactive slots keep their carry bit-for-bit across a sharded chunk
     step; active slots advance exactly as the single-device chunk does —
     including carries chained across successive chunks."""
     mesh = _mesh(2, 2)
-    single, sharded = _engine_pair(rng, reset="zero", B=5, mesh=mesh)
+    single, sharded = _engine_pair(rng, reset="zero", B=5, mesh=mesh,
+                                   neuron=neuron)
     c1 = single.init_carry(5)
     c2 = sharded.init_carry(5)
     for _ in range(3):
@@ -229,7 +246,8 @@ def test_mesh_step_chunk_masked_slots(rng):
         c1, s1 = single.step_chunk(c1, ext, act)
         c2, s2 = sharded.step_chunk(c2, ext, act)
         np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
-        for k in ("v", "spikes"):
+        assert c1.keys() == c2.keys() == set(single.carry_keys)
+        for k in c1:
             np.testing.assert_array_equal(np.asarray(c1[k]),
                                           np.asarray(c2[k]))
 
@@ -280,12 +298,14 @@ def test_session_run_all_sharded_parity(rng):
                                           np.asarray(rb[name][k]))
 
 
-def test_session_streaming_churn_sharded_parity(rng):
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_session_streaming_churn_sharded_parity(rng, neuron):
     """Attach/feed/detach churn across co-resident models' streams on a
     sharded session server matches the single-device server exactly."""
     mesh = _mesh(2, 2)
-    nets = [make_random_net(rng),
-            make_random_net(rng, n_in=12, n_neurons=32)]
+    syn = 0.75 if neuron == "cuba" else None
+    nets = [make_random_net(rng, syn_decay_rate=syn),
+            make_random_net(rng, n_in=12, n_neurons=32, syn_decay_rate=syn)]
     sessions = [AcceleratorSession(), AcceleratorSession(mesh=mesh)]
     for sess in sessions:
         sess.deploy("a", nets[0])
@@ -315,13 +335,13 @@ def test_session_streaming_churn_sharded_parity(rng):
                                           np.asarray(o_mesh[k]))
 
 
-def _async_frontend_parity(rng, mesh):
+def _async_frontend_parity(rng, mesh, neuron):
     """Requests served through an AsyncSpikeFrontend over a SHARDED
     server are byte-identical to the single-device engine's one-shot
     run — the async front door composes with the mesh unchanged."""
     from repro.serving.frontend import AsyncSpikeFrontend
 
-    single, sharded = _engine_pair(rng, mesh=mesh)
+    single, sharded = _engine_pair(rng, mesh=mesh, neuron=neuron)
     rasters = [(rng.random((T, single.n_inputs)) < 0.35).astype(np.int32)
                for T in (7, 4, 9, 2)]
     server = SpikeServer(sharded, n_slots=2, chunk_steps=3)
@@ -333,21 +353,24 @@ def _async_frontend_parity(rng, mesh):
         np.testing.assert_array_equal(h.result()["spikes"], want)
 
 
-def test_async_frontend_degenerate_mesh_parity(rng):
-    _async_frontend_parity(rng, make_spike_mesh(neuron=1, batch=1))
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_async_frontend_degenerate_mesh_parity(rng, neuron):
+    _async_frontend_parity(rng, make_spike_mesh(neuron=1, batch=1), neuron)
 
 
-def test_async_frontend_sharded_parity(rng):
-    _async_frontend_parity(rng, _mesh(2, 2))
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_async_frontend_sharded_parity(rng, neuron):
+    _async_frontend_parity(rng, _mesh(2, 2), neuron)
 
 
+@pytest.mark.parametrize("neuron", NEURONS)
 @pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
-def test_mesh_server_detach_keeps_carry_sharding(rng, shape):
+def test_mesh_server_detach_keeps_carry_sharding(rng, shape, neuron):
     """Evicting from a sharded server zeroes the freed slots in place:
     the carry keeps its sharding and its bytes match the single-device
     server's, and the next occupants power up from zero."""
     mesh = _mesh(*shape)
-    single, sharded = _engine_pair(rng, mesh=mesh)
+    single, sharded = _engine_pair(rng, mesh=mesh, neuron=neuron)
     noise = {u: (rng.random((4, single.n_inputs)) < 0.4).astype(np.int32)
              for u in range(4)}
     fresh = (rng.random((5, single.n_inputs)) < 0.35).astype(np.int32)
@@ -370,7 +393,8 @@ def test_mesh_server_detach_keeps_carry_sharding(rng, shape):
         out = server.feed({u: fresh for u in ("x", "y", "z")})
         for u in ("x", "y", "z"):
             np.testing.assert_array_equal(out[u]["spikes"], want)
-    for k in ("v", "spikes"):
+    assert carries[0].keys() == carries[1].keys() == set(single.carry_keys)
+    for k in carries[0]:
         assert carries[0][k].tobytes() == carries[1][k].tobytes()
 
 

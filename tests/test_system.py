@@ -1,22 +1,13 @@
-"""End-to-end system tests: the full SoC flow (encode -> accelerate ->
-decode) and an LM training loop with fault injection on the REDUCED arch."""
-
-import dataclasses
+"""End-to-end system test: the full SoC flow (encode -> accelerate ->
+decode)."""
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from repro import configs
-from repro.configs.shapes import Shape
-from repro.core import coding
 from repro.core.session import AcceleratorSession
-from repro.data import lm, mnist
-from repro.launch.steps import LMHarness
+from repro.data import mnist
 from repro.snn.model import SNNModelConfig
 from repro.snn.train import TrainConfig, train
-from repro.training.loop import LoopConfig, run_loop
 
 
 def test_soc_closed_loop(rng):
@@ -39,76 +30,3 @@ def test_soc_closed_loop(rng):
     out = sess.run("digits", x, 20, jax.random.key(0))
     acc = float((np.asarray(out["predictions"]) == y).mean())
     assert acc > 0.3  # far above 10% chance through the full HW path
-
-
-def test_lm_train_loop_with_preemption(tmp_path, rng):
-    """REDUCED granite-3-2b: run_loop + AdamW + checkpoint + preemption
-    restart reproduces the uninterrupted loss trajectory."""
-    mod = configs.get_arch("granite-3-2b")
-    cfg = dataclasses.replace(mod.REDUCED, n_layers=2)
-    h = LMHarness("granite-3-2b", cfg=cfg)
-    model, opt = h.model, h.opt
-    params = model.init(jax.random.key(0))
-    state0 = {"params": params, "opt": opt.init(params),
-              "step": np.asarray(0)}
-
-    @jax.jit
-    def step_impl(params, opt_state, batch):
-        (loss, _), grads = jax.value_and_grad(
-            model.loss, has_aux=True)(params, batch)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        from repro.training.optimizers import apply_updates
-        return apply_updates(params, updates), opt_state, loss
-
-    def step_fn(state, batch):
-        p, o, loss = step_impl(state["params"], state["opt"], batch)
-        return dict(state, params=p, opt=o), {"loss": loss}
-
-    stream = lm.TokenStream(cfg.vocab_size, seed=0)
-
-    def batch_fn(step):
-        toks = stream.sample(4, 16, step)
-        return {"tokens": jnp.asarray(toks[:, :-1], jnp.int32),
-                "targets": jnp.asarray(toks[:, 1:], jnp.int32)}
-
-    ref = run_loop(LoopConfig(total_steps=8, log_every=0),
-                   jax.tree.map(lambda x: x, state0), step_fn, batch_fn)
-
-    with pytest.raises(RuntimeError):
-        run_loop(LoopConfig(total_steps=8, checkpoint_dir=str(tmp_path),
-                            checkpoint_every=3, log_every=0, fail_at_step=5),
-                 jax.tree.map(lambda x: x, state0), step_fn, batch_fn)
-    out = run_loop(LoopConfig(total_steps=8, checkpoint_dir=str(tmp_path),
-                              checkpoint_every=3, log_every=0),
-                   jax.tree.map(lambda x: x, state0), step_fn, batch_fn)
-    for a, b in zip(jax.tree.leaves(ref["params"]),
-                    jax.tree.leaves(out["params"])):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32), atol=1e-5)
-
-
-def test_lm_loss_decreases_on_structured_stream(rng):
-    """A few dozen steps on the Markov stream must reduce loss — the data
-    pipeline is learnable and gradients flow end to end."""
-    mod = configs.get_arch("granite-3-2b")
-    cfg = dataclasses.replace(mod.REDUCED, n_layers=2, vocab_size=128)
-    h = LMHarness("granite-3-2b", cfg=cfg)
-    model = h.model
-    from repro.training import optimizers
-    opt = optimizers.adamw(3e-3)
-    params = model.init(jax.random.key(1))
-    opt_state = opt.init(params)
-
-    @jax.jit
-    def step(params, opt_state, batch):
-        (loss, _), grads = jax.value_and_grad(
-            model.loss, has_aux=True)(params, batch)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return optimizers.apply_updates(params, updates), opt_state, loss
-
-    losses = []
-    for s, toks, tgts in lm.lm_batches(cfg.vocab_size, 8, 32, 64, seed=5):
-        batch = {"tokens": jnp.asarray(toks), "targets": jnp.asarray(tgts)}
-        params, opt_state, loss = step(params, opt_state, batch)
-        losses.append(float(loss))
-    assert np.mean(losses[-5:]) < np.mean(losses[:5]) - 0.3

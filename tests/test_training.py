@@ -1,7 +1,4 @@
-"""Training substrate: optimizers, checkpoint/restart, loop resumability,
-gradient compression, straggler detection."""
-
-import os
+"""Training substrate: optimizers, schedules, straggler detection."""
 
 import jax
 import jax.numpy as jnp
@@ -10,11 +7,8 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro import checkpoint as ckpt
 from repro.distributed.straggler import StragglerDetector, rebalance_shards
 from repro.training import optimizers
-from repro.training.compression import Int8Compressor, TopKCompressor
-from repro.training.loop import LoopConfig, run_loop
 
 
 # --------------------------------------------------------------------------
@@ -68,156 +62,6 @@ def test_warmup_cosine_schedule():
     assert float(fn(jnp.asarray(10))) == pytest.approx(1.0, abs=0.02)
     assert float(fn(jnp.asarray(100))) == pytest.approx(0.0, abs=1e-6)
     assert float(fn(jnp.asarray(5))) == pytest.approx(0.5, abs=1e-6)
-
-
-# --------------------------------------------------------------------------
-# checkpointing
-# --------------------------------------------------------------------------
-def _tree(rng):
-    return {
-        "params": {"w": rng.normal(size=(4, 3)).astype(np.float32),
-                   "b": rng.normal(size=(3,)).astype(np.float32)},
-        "opt": {"m": [rng.normal(size=(2,)).astype(np.float32)]},
-        "step": np.asarray(7),
-    }
-
-
-def test_checkpoint_roundtrip(tmp_path, rng):
-    tree = _tree(rng)
-    ckpt.save(str(tmp_path), 7, tree, metadata={"note": "hi"})
-    restored, meta = ckpt.load(str(tmp_path), like=tree)
-    assert meta["note"] == "hi"
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(restored)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_checkpoint_bf16_roundtrip(tmp_path, rng):
-    """ml_dtypes (bf16) leaves must survive the npz store (raw-view path)."""
-    tree = {"w": jnp.asarray(rng.normal(size=(16, 8)), jnp.bfloat16),
-            "v": jnp.asarray(rng.normal(size=(4,)), jnp.float32)}
-    host = jax.tree.map(lambda x: np.asarray(x), tree)
-    ckpt.save(str(tmp_path), 0, host)
-    restored, _ = ckpt.load(str(tmp_path), like=host)
-    assert restored["w"].dtype == host["w"].dtype
-    np.testing.assert_array_equal(restored["w"].view(np.uint16),
-                                  host["w"].view(np.uint16))
-
-
-def test_checkpoint_keep_k_gc(tmp_path, rng):
-    tree = _tree(rng)
-    for s in range(6):
-        ckpt.save(str(tmp_path), s, tree, keep=3)
-    assert ckpt.all_steps(str(tmp_path)) == [3, 4, 5]
-    assert ckpt.latest_step(str(tmp_path)) == 5
-
-
-def test_checkpoint_corruption_detected(tmp_path, rng):
-    tree = _tree(rng)
-    path = ckpt.save(str(tmp_path), 1, tree)
-    arrays = os.path.join(path, "arrays.npz")
-    raw = bytearray(open(arrays, "rb").read())
-    raw[len(raw) // 2] ^= 0xFF
-    open(arrays, "wb").write(bytes(raw))
-    with pytest.raises(ckpt.CheckpointError, match="CRC"):
-        ckpt.load(str(tmp_path), 1, like=tree)
-
-
-def test_checkpoint_missing_key_detected(tmp_path, rng):
-    tree = _tree(rng)
-    ckpt.save(str(tmp_path), 1, tree)
-    bigger = dict(tree, extra=np.zeros(3))
-    with pytest.raises(ckpt.CheckpointError, match="missing"):
-        ckpt.load(str(tmp_path), 1, like=bigger)
-
-
-def test_async_checkpointer(tmp_path, rng):
-    tree = _tree(rng)
-    saver = ckpt.AsyncCheckpointer(str(tmp_path), keep=2)
-    for s in (1, 2, 3):
-        saver.save(s, tree)
-    saver.wait()
-    assert ckpt.all_steps(str(tmp_path)) == [2, 3]
-
-
-def test_checkpoint_elastic_reshard(tmp_path, rng):
-    """Checkpoints are mesh-agnostic: load with an explicit sharding tree
-    (single-device here; the contract is the device_put re-layout path)."""
-    from jax.sharding import NamedSharding, PartitionSpec
-    tree = {"w": rng.normal(size=(8, 4)).astype(np.float32)}
-    ckpt.save(str(tmp_path), 0, tree)
-    mesh = jax.make_mesh((1,), ("data",))
-    sh = {"w": NamedSharding(mesh, PartitionSpec("data"))}
-    restored, _ = ckpt.load(str(tmp_path), like=tree, sharding_tree=sh)
-    assert restored["w"].sharding == sh["w"]
-    np.testing.assert_array_equal(np.asarray(restored["w"]), tree["w"])
-
-
-# --------------------------------------------------------------------------
-# fault-tolerant loop: preemption -> restart produces the SAME trajectory
-# --------------------------------------------------------------------------
-def _loop_pieces():
-    def step_fn(state, batch):
-        w = state["w"] - 0.1 * (state["w"] - batch)
-        return dict(state, w=w), {"loss": float(jnp.sum((w - batch) ** 2))}
-
-    def batch_fn(step):
-        return jnp.asarray(float(step % 5))
-
-    return step_fn, batch_fn
-
-
-def test_preemption_resume_exact(tmp_path):
-    step_fn, batch_fn = _loop_pieces()
-    init = {"w": jnp.asarray(10.0), "step": 0}
-
-    # uninterrupted run
-    ref = run_loop(LoopConfig(total_steps=20, log_every=0),
-                   dict(init), step_fn, batch_fn)
-
-    # interrupted at step 13, checkpointing every 5
-    cfg = LoopConfig(total_steps=20, checkpoint_dir=str(tmp_path),
-                     checkpoint_every=5, log_every=0, fail_at_step=13)
-    with pytest.raises(RuntimeError, match="preemption"):
-        run_loop(cfg, dict(init), step_fn, batch_fn)
-    # restart: resumes from step 10 checkpoint automatically
-    cfg2 = LoopConfig(total_steps=20, checkpoint_dir=str(tmp_path),
-                      checkpoint_every=5, log_every=0)
-    out = run_loop(cfg2, dict(init), step_fn, batch_fn)
-    assert out["step"] == ref["step"] == 20
-    np.testing.assert_allclose(float(out["w"]), float(ref["w"]), rtol=1e-6)
-
-
-# --------------------------------------------------------------------------
-# gradient compression
-# --------------------------------------------------------------------------
-@given(st.integers(0, 1000))
-@settings(max_examples=25, deadline=None)
-def test_topk_error_feedback_conserves_signal(seed):
-    rng = np.random.default_rng(seed)
-    comp = TopKCompressor(fraction=0.25)
-    g = {"a": jnp.asarray(rng.normal(size=(8, 8)), jnp.float32),
-         "b": jnp.asarray(rng.normal(size=(5,)), jnp.float32)}
-    err = comp.init_error(g)
-    sparse, new_err = comp.compress(g, err)
-    dec = comp.decompress(sparse, jax.tree.map(lambda x: x.shape, g))
-    # decompressed + residual == original + old error (nothing lost)
-    for k in g:
-        np.testing.assert_allclose(
-            np.asarray(dec[k]) + np.asarray(new_err[k]),
-            np.asarray(g[k]) + np.asarray(err[k]), atol=1e-6)
-    assert comp.wire_bytes(sparse) < sum(
-        x.size * 4 for x in jax.tree.leaves(g))
-
-
-def test_int8_quantization_error_bounded(rng):
-    comp = Int8Compressor()
-    g = {"w": jnp.asarray(rng.normal(size=(64, 64)), jnp.float32)}
-    c = comp.compress(g, jax.random.key(0))
-    dec = comp.decompress(c)
-    scale = float(c["w"]["scale"])
-    err = np.abs(np.asarray(dec["w"]) - np.asarray(g["w"]))
-    assert err.max() <= scale * 1.01  # stochastic rounding: <= 1 LSB
-    assert comp.wire_bytes(c) < g["w"].size * 4 // 3
 
 
 # --------------------------------------------------------------------------
