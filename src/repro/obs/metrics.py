@@ -102,6 +102,10 @@ METRIC_SPECS: dict[str, MetricSpec] = _specs(
                "slot steps are not counted."),
     MetricSpec("snn_server_chunks_total", "counter",
                "step_chunk dispatches issued by SpikeServer.feed."),
+    MetricSpec("snn_server_chunks_ahead_total", "counter",
+               "step_chunk dispatches SpikeServer.feed issued while the "
+               "same feed's previous chunk was still unread (over "
+               "chunks_total: the share the one-deep pipeline engaged)."),
     MetricSpec("snn_server_spikes_total", "counter",
                "Output spikes emitted across all streams."),
     MetricSpec("snn_server_source_events_total", "counter",

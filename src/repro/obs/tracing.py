@@ -62,14 +62,16 @@ SPAN_KINDS: tuple[str, ...] = (
 # The served round's phases, in the order one round opens them. Nesting:
 # snn.pump > {admit, gather, snn.feed > {assemble, dispatch, readback,
 # split}, retire}; assemble, dispatch and readback repeat per chunk of
-# a long feed, split runs once.
+# a long feed, split runs once. A long feed runs one chunk deep: chunk
+# n+1's assemble and dispatch come before chunk n's readback.
 HOT_SPANS: tuple[str, ...] = (
     "snn.pump",           # AsyncSpikeFrontend.pump: one whole round
     "snn.pump.admit",     # expiry, preemption, admission (attach)
     "snn.pump.gather",    # each running request's next chunk, embedded
     "snn.feed",           # SpikeServer.feed: one whole call
     "snn.feed.assemble",  # one chunk's dense ext and active arrays
-    "snn.feed.dispatch",  # host-to-device copies + step_chunk (h2d_bytes)
+    "snn.feed.dispatch",  # host-to-device copies + step_chunk (h2d_bytes;
+                          # ahead=1 where the previous chunk is unread)
     "snn.feed.readback",  # the chunk's raster back to the host (d2h_bytes)
     "snn.feed.split",     # one count over all slots, a raster copy per
                           # stream (streams, chunks)

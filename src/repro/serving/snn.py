@@ -192,7 +192,9 @@ class SpikeServer:
     The server pins the slot-batch shape ``(chunk_steps, n_slots)``: every
     :meth:`feed` call is processed as full chunks of ``chunk_steps``
     timesteps padded with inactive steps, so ONE XLA program (per engine)
-    serves arbitrary ragged traffic. Slot carries persist across calls;
+    serves arbitrary ragged traffic. A feed of several chunks runs one
+    chunk deep: chunk n+1 is dispatched before chunk n is read back, so
+    its kernel overlaps that read-back. Slot carries persist across calls;
     :meth:`detach` zeroes the evicted slot so re-attachment starts from
     the unified power-on state (V = 0, no prior spikes);
     :meth:`detach_many` zeroes every slot one call frees in a single
@@ -652,33 +654,18 @@ class SpikeServer:
             return out
 
         T_max = max(arr.shape[0] for _, arr in chunks.values())
-        n_in = self.engine.n_inputs
         rasters = []                                  # (T, n_slots, n_phys)
-        obs = self.metrics is not None or self.tracer is not None
+        # One chunk deep: chunk n+1 is dispatched, its carry chained on
+        # the device, before chunk n is read back, so its kernel runs
+        # while chunk n's raster comes home. At most two are unread.
+        pending = None
         for t0 in range(0, T_max, self.chunk_steps):
-            with hot_span("snn.feed.assemble"):
-                ext = np.zeros((self.chunk_steps, self.n_slots, n_in),
-                               np.int32)
-                active = np.zeros((self.chunk_steps, self.n_slots), np.int32)
-                for uid, (slot, arr) in chunks.items():
-                    n = min(self.chunk_steps, arr.shape[0] - t0)
-                    if n <= 0:
-                        continue
-                    ext[:n, slot] = arr[t0:t0 + n]
-                    active[:n, slot] = 1
-            t_chunk = self._obs_clock()() if obs else 0.0
-            with hot_span("snn.feed.dispatch",
-                          h2d_bytes=ext.nbytes + active.nbytes):
-                self.carry, spikes = self.engine.step_chunk(
-                    self.carry, jnp.asarray(ext), jnp.asarray(active))
-            with hot_span("snn.feed.readback", d2h_bytes=spikes.nbytes):
-                spikes = np.asarray(spikes)
-            self.total_steps += int(active.sum())
-            # telemetry stays outside the phases: its cost is its own
-            if obs:
-                self._obs_feed_chunk(t_chunk, active, spikes, ext,
-                                     chunks, t0)
-            rasters.append(spikes)
+            ahead = pending is not None
+            dispatched = self._dispatch_chunk(chunks, t0, ahead)
+            if ahead:
+                rasters.append(self._read_back(chunks, *pending))
+            pending = dispatched
+        rasters.append(self._read_back(chunks, *pending))
 
         cs = self.chunk_steps
         with hot_span("snn.feed.split", streams=len(chunks),
@@ -707,6 +694,44 @@ class SpikeServer:
                 st.spike_count += total
                 out[uid] = {"spikes": raster, "counts": row}
         return out
+
+    def _dispatch_chunk(self, chunks: dict, t0: int, ahead: bool) -> tuple:
+        """Assemble and dispatch the chunk of steps ``t0 ..`` of every
+        stream, and start its raster's copy to the host. ``ahead``: the
+        feed's previous chunk is still unread."""
+        n_in = self.engine.n_inputs
+        with hot_span("snn.feed.assemble"):
+            ext = np.zeros((self.chunk_steps, self.n_slots, n_in), np.int32)
+            active = np.zeros((self.chunk_steps, self.n_slots), np.int32)
+            for uid, (slot, arr) in chunks.items():
+                n = min(self.chunk_steps, arr.shape[0] - t0)
+                if n <= 0:
+                    continue
+                ext[:n, slot] = arr[t0:t0 + n]
+                active[:n, slot] = 1
+        obs = self.metrics is not None or self.tracer is not None
+        t_chunk = self._obs_clock()() if obs else None
+        # ``ahead`` appears only where set: a lone chunk's span is as before
+        with hot_span("snn.feed.dispatch",
+                      h2d_bytes=ext.nbytes + active.nbytes,
+                      **({"ahead": 1} if ahead else {})):
+            self.carry, spikes = self.engine.step_chunk(
+                self.carry, jnp.asarray(ext), jnp.asarray(active))
+            spikes.copy_to_host_async()
+        if ahead and self.metrics is not None:
+            self.metrics.counter("snn_server_chunks_ahead_total").inc()
+        return spikes, ext, active, t0, t_chunk
+
+    def _read_back(self, chunks: dict, spikes, ext: np.ndarray,
+                   active: np.ndarray, t0: int, t_chunk) -> np.ndarray:
+        """Wait for a dispatched chunk's raster and account for it."""
+        with hot_span("snn.feed.readback", d2h_bytes=spikes.nbytes):
+            spikes = np.asarray(spikes)
+        self.total_steps += int(active.sum())
+        # telemetry stays outside the phases: its cost is its own
+        if t_chunk is not None:
+            self._obs_feed_chunk(t_chunk, active, spikes, ext, chunks, t0)
+        return spikes
 
     def feed_events(self, inputs: dict, *, out_capacity: int | None = None,
                     out_policy: str = "error") -> dict:

@@ -119,17 +119,22 @@ def test_pump_round_emits_every_hot_span_nested(recorder):
 
 def test_feed_phases_repeat_per_chunk(recorder):
     """A feed longer than one chunk assembles, dispatches and reads back
-    once per chunk, then splits once."""
+    once per chunk, one chunk deep (chunk n+1 dispatched, marked
+    ``ahead``, before chunk n is read back), then splits once."""
     server = SpikeServer(_engine(), n_slots=SLOTS, chunk_steps=CHUNK)
     uids = [server.attach(), server.attach()]
     raster_a, raster_b = _rasters([7, 4])
     out = server.feed({uids[0]: raster_a, uids[1]: raster_b})
     assert out[uids[0]]["spikes"].shape == (7, N_PHYS)
     names = [n for n, _, _ in recorder.spans]
-    assert names == (["snn.feed"]
+    assert names == (["snn.feed", "snn.feed.assemble", "snn.feed.dispatch"]
                      + ["snn.feed.assemble", "snn.feed.dispatch",
-                        "snn.feed.readback"] * 3
-                     + ["snn.feed.split"])
+                        "snn.feed.readback"] * 2
+                     + ["snn.feed.readback", "snn.feed.split"])
+    h2d = (CHUNK * SLOTS * N_IN + CHUNK * SLOTS) * 4
+    dispatches = [a for n, _, a in recorder.spans if n == "snn.feed.dispatch"]
+    assert dispatches == [{"h2d_bytes": h2d}] + [
+        {"h2d_bytes": h2d, "ahead": 1}] * 2
 
 
 @pytest.mark.parametrize("lengths,streams,chunks", [

@@ -345,7 +345,7 @@ def test_feed_split_matches_per_stream_loop(rng, monkeypatch, neuron,
     def recording(carry, ext, active):
         carry, spikes = step_chunk(carry, ext, active)
         read_back.append(np.asarray(spikes))
-        return carry, read_back[-1]
+        return carry, spikes
 
     monkeypatch.setattr(engine, "step_chunk", recording)
     for lengths in ((0, 3, 8, 13, 24), (24, 13, 0, 8, 3)):
@@ -375,6 +375,201 @@ def test_feed_split_matches_per_stream_loop(rng, monkeypatch, neuron,
         for i, a in enumerate(rasters):
             assert not any(np.shares_memory(a, r) for r in read_back)
             assert not any(np.shares_memory(a, b) for b in rasters[i + 1:])
+
+
+# --------------------------------------------------------------------------
+# The one-deep chunk pipeline: a feed of several chunks dispatches chunk
+# n+1 before it reads chunk n back
+# --------------------------------------------------------------------------
+
+PIPE_CHUNK = 8
+# chunk_steps 8: one, one, two, two and three chunks; 3, 11 and 21 end
+# mid-chunk
+PIPE_LENGTHS = (3, 8, 11, 16, 21)
+
+
+def _pipe_traffic(rng, lengths=PIPE_LENGTHS, n_in=10):
+    return [_raster(rng, T, n_in)[:, 0] for T in lengths]
+
+
+def _serve_pipe(engine, rasters, *, whole, mesh=None, metrics=None):
+    """Serve ``rasters`` on fresh slots, in one feed (``whole``) or one
+    chunk of steps per feed call; each stream's raster and counts."""
+    server = SpikeServer(engine, n_slots=len(rasters) + 1,
+                         chunk_steps=PIPE_CHUNK, mesh=mesh, metrics=metrics)
+    uids = [server.attach() for _ in rasters]
+    if whole:
+        out = server.feed(dict(zip(uids, rasters)))
+        return server, {u: (out[u]["spikes"], out[u]["counts"])
+                        for u in uids}
+    parts = {u: [] for u in uids}
+    for t0 in range(0, max(len(r) for r in rasters), PIPE_CHUNK):
+        out = server.feed({u: r[t0:t0 + PIPE_CHUNK]
+                           for u, r in zip(uids, rasters) if len(r) > t0})
+        for u, o in out.items():
+            parts[u].append((o["spikes"], o["counts"]))
+    return server, {u: (np.concatenate([sp for sp, _ in p]),
+                        sum(c for _, c in p)) for u, p in parts.items()}
+
+
+def _assert_pipe_equivalent(engine, rng, mesh=None):
+    rasters = _pipe_traffic(rng)
+    whole, got = _serve_pipe(engine, rasters, whole=True, mesh=mesh)
+    per_call, want = _serve_pipe(engine, rasters, whole=False, mesh=mesh)
+    assert whole.total_steps == per_call.total_steps == sum(PIPE_LENGTHS)
+    for (uid, (spikes, counts)), raster in zip(got.items(), rasters):
+        w_spikes, w_counts = want[uid]
+        for a, b in ((spikes, w_spikes), (counts, w_counts)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            assert a.tobytes() == b.tobytes()
+        assert spikes.dtype == np.int32 and spikes.any()
+        ref = engine.run(raster[:, None, :])
+        assert spikes.tobytes() == np.asarray(ref["spikes"])[:, 0].tobytes()
+        assert_slot_state(whole, uid, ref)
+        assert_slot_state(per_call, uid, ref)
+        st, w_st = whole.streams[uid], per_call.streams[uid]
+        assert (st.steps, st.spike_count) == (w_st.steps, w_st.spike_count)
+    for k in whole.carry:
+        assert (np.asarray(whole.carry[k]).tobytes()
+                == np.asarray(per_call.carry[k]).tobytes()), k
+
+
+@pytest.mark.parametrize("neuron", NEURONS)
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_pipelined_feed_equals_chunk_per_call(rng, backend, neuron):
+    """Ragged streams of one, two and three chunks, some ending
+    mid-chunk, fed in one call: byte-identical rasters, counts, stream
+    stats and final carries to the same traffic fed one chunk per call,
+    and to the one-shot reference run of each stream."""
+    engine = _engine(rng, backend=backend, neuron=neuron, wmax=1 << 16)
+    _assert_pipe_equivalent(engine, rng)
+
+
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_pipelined_feed_equals_chunk_per_call_mesh(rng, neuron):
+    """The mesh server runs the same pipelined loop (degenerate 1x1 mesh,
+    so the sharded path runs on one device)."""
+    from repro.distributed.spike_mesh import make_spike_mesh
+
+    engine = _engine(rng, neuron=neuron, wmax=1 << 16)
+    _assert_pipe_equivalent(engine, rng,
+                            mesh=make_spike_mesh(neuron=1, batch=1))
+
+
+class _Unread:
+    """A dispatched raster that logs when the host reads it back."""
+
+    def __init__(self, spikes, i, log):
+        self._spikes, self._i, self._log = spikes, i, log
+        self.nbytes = spikes.nbytes
+
+    def copy_to_host_async(self):
+        self._spikes.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self._log.append(("read", self._i))
+        return np.asarray(self._spikes)
+
+
+def _logged(engine, monkeypatch):
+    log = []
+    step_chunk = engine.step_chunk
+
+    def dispatch(carry, ext, active):
+        carry, spikes = step_chunk(carry, ext, active)
+        i = sum(1 for op, _ in log if op == "dispatch")
+        log.append(("dispatch", i))
+        return carry, _Unread(spikes, i, log)
+
+    monkeypatch.setattr(engine, "step_chunk", dispatch)
+    return log
+
+
+@pytest.mark.parametrize("T_max,n_chunks", [(1, 1), (8, 1), (9, 2),
+                                            (24, 3), (25, 4)])
+def test_feed_dispatches_next_chunk_before_reading_back(rng, monkeypatch,
+                                                        T_max, n_chunks):
+    """Chunk n+1 is dispatched before chunk n is read back, chunks are
+    read in order, never more than two rasters are unread, and the
+    counter of chunks dispatched ahead reads n_chunks - 1."""
+    from repro.obs import MetricsRegistry
+
+    engine = _engine(rng)
+    reg = MetricsRegistry()
+    server = SpikeServer(engine, n_slots=3, chunk_steps=PIPE_CHUNK,
+                         metrics=reg)
+    a, b = server.attach(), server.attach()
+    log = _logged(engine, monkeypatch)
+    out = server.feed({a: _raster(rng, T_max, 10)[:, 0],
+                       b: _raster(rng, max(1, T_max - 9), 10)[:, 0]})
+    assert out[a]["spikes"].shape == (T_max, 16)
+    want = [("dispatch", 0)]
+    for i in range(1, n_chunks):
+        want += [("dispatch", i), ("read", i - 1)]
+    assert log == want + [("read", n_chunks - 1)]
+    unread = peak = 0
+    for op, _ in log:
+        unread += 1 if op == "dispatch" else -1
+        peak = max(peak, unread)
+    assert peak == min(2, n_chunks)
+    c = reg.counter
+    assert c("snn_server_chunks_total").value == n_chunks
+    assert c("snn_server_chunks_ahead_total").value == n_chunks - 1
+
+
+def test_pump_round_dispatches_nothing_ahead(rng, monkeypatch):
+    """A front-door round feeds one chunk: no chunk runs ahead, in a
+    round that admits and in one that continues its requests."""
+    from repro.obs import MetricsRegistry
+    from repro.serving.frontend import AsyncSpikeFrontend
+
+    engine = _engine(rng)
+    reg = MetricsRegistry()
+    server = SpikeServer(engine, n_slots=2, chunk_steps=PIPE_CHUNK,
+                         metrics=reg)
+    fe = AsyncSpikeFrontend(server, queue_capacity=4, metrics=reg)
+    handles = [fe.submit(_raster(rng, T, 10)[:, 0]) for T in (20, 9, 3)]
+    log = _logged(engine, monkeypatch)
+    fe.drain()
+    for h, T in zip(handles, (20, 9, 3)):
+        assert h.result()["spikes"].shape == (T, 16)
+    rounds = reg.counter("snn_frontend_rounds_total").value
+    assert rounds >= 3
+    assert log == [(op, i) for i in range(rounds)
+                   for op in ("dispatch", "read")]
+    assert reg.counter("snn_server_chunks_total").value == rounds
+    assert reg.counter("snn_server_chunks_ahead_total").value == 0
+
+
+@pytest.mark.parametrize("neuron", NEURONS)
+def test_pipelined_feed_telemetry_equals_chunk_per_call(rng, neuron):
+    """With metrics on, a feed of three chunks counts the same SOPs,
+    spikes, steps, chunks and every other measured-work counter as the
+    same traffic fed one chunk per call; only the ahead counter differs,
+    and it appears in the live exposition."""
+    from repro.obs import MetricsRegistry
+
+    engine = _engine(rng, neuron=neuron, wmax=1 << 16)
+    rasters = _pipe_traffic(rng, lengths=(24, 13, 5))
+    regs = {whole: MetricsRegistry() for whole in (True, False)}
+    for whole, reg in regs.items():
+        _serve_pipe(engine, rasters, whole=whole, metrics=reg)
+    snaps = {whole: reg.snapshot() for whole, reg in regs.items()}
+    for name in ("snn_server_sops_total", "snn_server_spikes_total"):
+        assert snaps[True][name]["samples"][0]["value"] > 0, name
+    for name, spec in snaps[True].items():
+        if spec["type"] != "counter" or name.startswith("snn_frontend"):
+            continue
+        if name == "snn_server_chunks_ahead_total":
+            continue
+        assert snaps[True][name] == snaps[False][name], name
+    c = {w: reg.counter for w, reg in regs.items()}
+    assert c[True]("snn_server_chunks_total").value == 3
+    assert c[True]("snn_server_chunks_ahead_total").value == 2
+    assert c[False]("snn_server_chunks_ahead_total").value == 0
+    text = regs[True].to_prometheus()
+    assert "# TYPE snn_server_chunks_ahead_total counter" in text
+    assert "\nsnn_server_chunks_ahead_total 2" in text
 
 
 def test_auto_uid_skips_caller_chosen_ids(rng):
